@@ -148,7 +148,7 @@ PYEOF
 echo "=== stats_smoke exit=$? $(date +%H:%M:%S)" >> "$S"
 # scenario-fleet smoke (docs/16-Scenario-Fleets.md): an 8-lane PHOLD
 # fleet vs the same 8 scenarios run sequentially, compile included on
-# both sides in a fresh cache dir — every measured lane (lane 0
+# both sides with the persistent cache off — every measured lane (lane 0
 # included) must be bit-identical to its solo run, and the sequential-
 # vs-fleet wall-clock ratio prints to the stamp log. Exit 1 on an
 # identity failure or a budget-truncated sequential side.
@@ -161,7 +161,7 @@ run fleet_smoke 900 --fleet-smoke JAX_PLATFORMS=cpu BENCH_BUDGET_S=840
 # (c) the /metrics scrape passes tools/check_openmetrics and carries the
 # serve families, (d) SIGTERM with 2 undispatched requests queued ->
 # graceful drain, exit 0, queue persisted as re-submittable JSON. The
-# warm/cold ratio itself is bench.py --serve-smoke (BENCH_r09.json).
+# warm/cold ratio itself is bench.py --serve-smoke.
 echo "=== serve_smoke start $(date +%H:%M:%S)" >> "$S"
 echo "{\"stage\": \"serve_smoke\"}" >> "$R"
 timeout 900 env JAX_PLATFORMS=cpu python - >> "$R" 2>> "$S" <<'PYEOF'

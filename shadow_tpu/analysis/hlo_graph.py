@@ -18,7 +18,7 @@ graph:
   private helpers never count against a budget.
 - `bytes_of_type("tensor<8x32xi64>")` for the liveness estimator.
 
-The grammar is the subset jax 0.4.x actually prints (verified against
+The grammar is the subset the installed jax actually prints (verified against
 full engine lowerings of every model config); unrecognized lines are
 skipped, never fatal — an auditor must degrade to "saw less", not
 crash the lint gate. Loose op fragments outside any `func.func` (used
@@ -100,6 +100,9 @@ def _split_commas(s: str) -> list[str]:
 
 
 # ---------------------------------------------------------------- model
+
+
+SHARDY_MARKERS = ("sdy.manual_computation", "sdy.sharding_constraint")
 
 
 @dataclasses.dataclass
@@ -227,6 +230,15 @@ class Module:
                        for op in self.find_ops(
                            "custom_call", reachable_only=reachable_only)
                        if op.custom_target})
+
+    def sharding_markers(self, *,
+                         reachable_only: bool = True) -> list[str]:
+        """Unique Shardy sharding ops, sorted: what the installed jax
+        prints for a shard_map (`sdy.manual_computation`) or a sharding
+        constraint (`sdy.sharding_constraint`) where older releases
+        printed GSPMD `custom_call @Sharding` markers."""
+        return sorted({op.name for op in self.ops(
+            reachable_only=reachable_only) if op.name in SHARDY_MARKERS})
 
     def ops_with_path(self) -> Iterator[tuple[Op, str]]:
         """(op, region path) over reachable funcs. The path names every

@@ -63,9 +63,9 @@ way those disciplines have been (or nearly were) broken:
   own convention: immediately rebind the carry
   (``state = step(state, stop)``) — rebinding clears the tracking.
 - SL108 collective call inside a ``while_loop``/``cond`` predicate —
-  jax 0.4.x's experimental shard_map under ``check_rep=False``
-  miscompiles collectives lowered into loop/branch predicates: device
-  0's carried state leaks to every shard (the PR-1 pmap-fallback bug;
+  an older jax's experimental shard_map under ``check_rep=False``
+  miscompiled collectives lowered into loop/branch predicates: device
+  0's carried state leaked to every shard (the PR-1 pmap-fallback bug;
   docs/12-Sharding.md post-mortem). The engine computes every such
   flag in the loop BODY and threads it through the carry
   (``core.engine._drain_flag``); this rule pins that structurally.
@@ -245,7 +245,7 @@ _PRNG_CONSUMERS_SKIP = {
 _PRNG_NAMESPACES = {"srng", "random", "jr", "rng"}
 
 # SL108: collective primitives whose lowering into a while_loop cond or
-# a lax.cond predicate triggers the 0.4.x experimental-shard_map
+# a lax.cond predicate triggered an older jax's experimental-shard_map
 # check_rep=False miscompile (predicate re-evaluated per shard off
 # device 0's carry), plus the engine's in-package reduction wrappers
 # built directly on them — a `self._gany(...)` in a predicate is the
@@ -811,9 +811,9 @@ class _Linter(ast.NodeVisitor):
         self._emit(
             "SL108", node,
             f"collective `{_unparse(node.func)}` lowers into a "
-            f"while/cond predicate — 0.4.x experimental shard_map "
-            f"(check_rep=False) leaks device 0's carry to every shard "
-            f"there; compute the flag in the loop body and carry it "
+            f"while/cond predicate — a predicate evaluated per shard "
+            f"off device 0's carry is the miscompile this rule guards "
+            f"against; compute the flag in the loop body and carry it "
             f"(core.engine._drain_flag)")
 
     def _check_pred_collective(self, node: ast.Call, base: str) -> None:

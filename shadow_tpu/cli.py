@@ -30,6 +30,7 @@ from shadow_tpu.config import parse_config
 from shadow_tpu.core.timebase import MILLISECOND, SECOND
 from shadow_tpu.examples import example_config
 from shadow_tpu.sim import build_simulation
+from shadow_tpu.utils.compile_cache import enable_compile_cache
 
 
 def make_parser() -> argparse.ArgumentParser:
@@ -675,6 +676,7 @@ def _run_serve(args) -> int:
 
 def main(argv=None) -> int:
     args = make_parser().parse_args(argv)
+    enable_compile_cache()  # run and serve alike
     if args.show_build_info:
         print(f"shadow_tpu {__version__} (jax {jax.__version__}, "
               f"backend {jax.default_backend()})")

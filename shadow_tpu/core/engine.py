@@ -340,9 +340,10 @@ class EngineConfig:
     burst: tuple | None = None
     # Queue-merge kernel for queue_push (core.events): "xla" (default)
     # lowers the densify + rotate + merge as plain XLA ops; "pallas"
-    # fuses them into one Pallas kernel call (core.merge_pallas,
-    # interpret-mode off-TPU). The two are bit-identical by construction
-    # and pinned so by tests/test_kernel_equivalence.py.
+    # fuses them into one Pallas kernel call (core.merge_pallas), which
+    # runs only interpreted on the CPU: Engine refuses it on any other
+    # backend. The two are bit-identical by construction and pinned so
+    # by tests/test_kernel_equivalence.py.
     kernel: str = "xla"
     # Frontier run batching: the THIRD drain contract, between the fully
     # chained path and the commutative batch_handler path. When > 0 (and
@@ -513,6 +514,13 @@ class Engine:
         exact chained order (the explicit in-host ordering fold) — they
         just never amortize. None (the default) allows every kind.
         Ignored when cfg.frontier == 0."""
+        if cfg.kernel == "pallas" and jax.devices()[0].platform != "cpu":
+            from shadow_tpu.core.merge_pallas import MOSAIC_REFUSAL
+
+            raise ValueError(
+                f"kernel='pallas' runs only interpreted on the CPU, not "
+                f"on {jax.devices()[0].platform!r}: {MOSAIC_REFUSAL}. "
+                "Use kernel='xla' (ROADMAP C2)")
         self.cfg = cfg
         self.handlers = tuple(handlers)
         self.network = network
@@ -581,9 +589,18 @@ class Engine:
 
     # -- collectives (identity when unsharded) ------------------------------
     def _gmin(self, x):
-        if self.cfg.axis_name is not None:
-            return jax.lax.pmin(x, self.cfg.axis_name)
-        return x
+        """Global min over the mesh. The TPU compiler lowers a 64-bit
+        all-reduce only as a sum ("Supported lowering only of Sum all
+        reduce", compiled for a described v5e:2x2, PR 21), so the i64
+        barrier gathers every shard's value as two u32 words and reduces
+        locally: one all_gather of 8 bytes per shard, bit-identical to
+        a pmin."""
+        ax = self.cfg.axis_name
+        if ax is None:
+            return x
+        words = jax.lax.all_gather(
+            jax.lax.bitcast_convert_type(x, jnp.uint32), ax)
+        return jnp.min(jax.lax.bitcast_convert_type(words, x.dtype), axis=0)
 
     def _gany(self, x: jax.Array) -> jax.Array:
         if self.cfg.axis_name is not None:
@@ -599,9 +616,9 @@ class Engine:
         """True while any host (globally) still has an executable event
         below the window barrier. Computed in loop BODIES and threaded
         through the carry — never evaluated inside a while_loop cond —
-        so the lowered predicate contains no collective (the 0.4.37
-        experimental-shard_map miscompile leaks device 0's carry when a
-        collective sits inside a cond; see docs/12-Sharding.md)."""
+        so the lowered predicate contains no collective (an older
+        jax's shard_map leaked device 0's carry when a collective sat
+        inside a cond; see docs/12-Sharding.md)."""
         nxt = q.min_time()
         if self._cpu_enabled:
             nxt = jnp.maximum(nxt, cpu_free)
@@ -1051,8 +1068,9 @@ class Engine:
         def outer_cond(carry):
             # carried flag: the psum/any deciding another sweep runs at
             # the END of the body (`_drain_flag`), never in this cond —
-            # collective-free predicates are what keep the sharded
-            # lowering correct on jax 0.4.37 (see docs/12-Sharding.md)
+            # collective-free predicates keep the sharded lowering
+            # independent of how a predicate is replicated per shard
+            # (see docs/12-Sharding.md)
             return carry[0]
 
         def outer_body(carry):
@@ -1466,7 +1484,7 @@ class Engine:
             # host's next executable instant is its earliest event or,
             # if later, when its virtual CPU frees up (cpu.c semantics).
             # The psum lives in the body, never in this predicate — the
-            # structural rule that keeps 0.4.37 shard_map correct
+            # structural rule SL108 pins (docs/12-Sharding.md)
             return carry[0]
 
         def outer_body(carry):

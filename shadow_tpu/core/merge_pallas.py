@@ -15,13 +15,15 @@ The arithmetic is element-for-element the same as the XLA path, so the
 two kernels are bit-identical on every input (pinned by
 tests/test_kernel_equivalence.py, including spill-ring eviction order).
 
-Off-TPU the kernel runs under ``interpret=True``, which executes the
-same jnp ops eagerly inside the jitted program — the CPU tier-1 suite
-and ``JAX_PLATFORMS=cpu`` benches exercise the identical code path with
-no TPU present. (vmap over `pl.load` is unsupported on this jax
-pin, so all gathers are value-level fancy indexing after full-ref
-loads — which is also what a TPU lowering wants: one VMEM load per
-operand, vector gathers after.)
+On the CPU the kernel runs under ``interpret=True``, which executes the
+same jnp ops inside the jitted program, so the CPU suite exercises the
+identical arithmetic. On any other backend it does not run at all: the
+TPU's Mosaic compiler refuses this kernel (`MOSAIC_REFUSAL`), and
+`Engine` refuses ``kernel="pallas"`` off the CPU when it is built
+rather than falling back to the interpreter without a word
+(tests/test_tpu_compile.py pins the refusal against a described v5e).
+Whether to rewrite it on i32 key pairs with a grid over hosts, or to
+delete it, is ROADMAP C2.
 """
 
 from __future__ import annotations
@@ -34,6 +36,15 @@ import jax.numpy as jnp
 from shadow_tpu.core.timebase import TIME_INVALID
 
 _I64MAX = jnp.iinfo(jnp.int64).max
+
+# Why the TPU compiler refuses the kernel, as Mosaic reports it when
+# compiling for v5e: i64 queue keys ("64-bit types are not supported")
+# and the value-level row gathers ("Only 2D gather is supported").
+MOSAIC_REFUSAL = (
+    "Mosaic refuses the fused merge kernel on the TPU: its queue keys "
+    "are 64-bit types, which Mosaic does not support, and its row "
+    "gathers are not 2-D gathers, the only gather Mosaic supports"
+)
 
 
 def merge_body(qt, qss, qpay, st, sss, bpay, starts, cnt):
@@ -133,15 +144,17 @@ def _build_call(h, hc, w, m, nw, interpret):
     )
 
 
-def fused_merge(qt, qss, qpay, st, sss, bpay, starts, cnt):
+def fused_merge(qt, qss, qpay, st, sss, bpay, starts, cnt, *,
+                interpret: bool | None = None):
     """One fused densify + rotate + merge pass over the hot columns.
 
     Returns (mt, mss, mpay) merged rows of width hc + w, exactly what
     `lax.sort` over [resident | block] with key (time, srcseq) yields.
-    Interpret mode is selected automatically off-TPU.
+    `interpret` defaults to True only when the first device is a CPU.
     """
     h, hc = qt.shape
     w = bpay.shape[1]
-    interpret = jax.default_backend() != "tpu"
+    if interpret is None:
+        interpret = jax.devices()[0].platform == "cpu"
     call = _build_call(h, hc, w, st.shape[0], qpay.shape[-1], interpret)
     return call(qt, qss, qpay, st, sss, bpay, starts, cnt)

@@ -24,8 +24,11 @@ and lane rolls can never collide however many draws a handler makes.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _KS_PARITY = 0x1BD11BDA  # threefry key-schedule parity constant
 # domain tags (counter-word c1) for the derivation kinds
@@ -164,10 +167,34 @@ def randint(key: jax.Array, minval: int, maxval: int,
             + (_bits(key, c1=jnp.uint32(_DOM_RINT)) % span).astype(dtype))
 
 
-def exponential(key: jax.Array) -> jax.Array:
-    """f32 unit-rate exponential."""
-    u = uniform(key)
-    return -jnp.log1p(-u)
+# ln(1 + i/2^10) for i in 0..2^10 in 2^-24 fixed point: the table
+# `exponential_ns` interpolates, computed once on the host in float64.
+_LN_TABLE = np.round(
+    np.log1p(np.arange((1 << 10) + 1) / (1 << 10)) * (1 << 24)
+).astype(np.int64)
+_LN2_FIX = round(math.log(2) * (1 << 24))
+
+
+def exponential_ns(key: jax.Array, mean_ns: int) -> jax.Array:
+    """i64 exponential draw with mean `mean_ns` nanoseconds.
+
+    Integer arithmetic only, so every backend gives the same bits: a
+    float `log1p` differs in its last place between the TPU and the CPU,
+    and scaling by `mean_ns` turns that place into different
+    nanoseconds (the PHOLD trajectories diverged, chip_smoke, PR 21).
+    With u = k/2^24 the same draw `uniform` makes, -ln(1-u) =
+    ln 2^24 - ln x for x = 2^24 - k in [1, 2^24]; ln x is e*ln 2 plus a
+    table-interpolated ln of the mantissa in [1, 2), all in 2^-24 fixed
+    point (error below 2e-7, about one f32 place)."""
+    k = _bits(key, c1=jnp.uint32(_DOM_UNIF)) >> 8
+    x = jnp.uint32(1 << 24) - k
+    e = (31 - jax.lax.clz(x)).astype(jnp.int64)  # floor(log2 x), 0..24
+    frac = (x.astype(jnp.int64) << (24 - e)) - (1 << 24)  # [0, 2^24)
+    i, r = frac >> 14, frac & ((1 << 14) - 1)
+    table = jnp.asarray(_LN_TABLE)
+    lo = table[i]
+    ln_x = e * _LN2_FIX + lo + (((table[i + 1] - lo) * r) >> 14)
+    return (jnp.int64(mean_ns) * (24 * _LN2_FIX - ln_x)) >> 24
 
 
 def bernoulli(key: jax.Array, p) -> jax.Array:

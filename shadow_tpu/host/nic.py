@@ -35,6 +35,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from shadow_tpu.core.timebase import MILLISECOND, SECOND
 
@@ -48,10 +49,11 @@ HEADER_UDP = 42
 HEADER_TCP = 66
 
 
-def kib_per_sec_to_bytes_per_ns(kib: jax.Array) -> jax.Array:
+def kib_per_sec_to_bytes_per_ns(kib) -> np.ndarray:
     """Bandwidth conversion; GraphML bandwidths are KiB/s
-    (docs/3.2-Network-Config.md)."""
-    return kib.astype(jnp.float64) * 1024.0 / SECOND
+    (docs/3.2-Network-Config.md). Host-side float64: a build-time
+    constant must not depend on the device that builds it."""
+    return np.asarray(kib, np.float64) * 1024.0 / SECOND
 
 
 @jax.tree_util.register_dataclass
@@ -70,9 +72,13 @@ class NIC:
     @staticmethod
     def create(bandwidth_kib, burst_bytes: int = 16 * 1024,
                buf_bytes=0) -> "NIC":
-        rate = kib_per_sec_to_bytes_per_ns(jnp.asarray(bandwidth_kib))
-        rate = jnp.maximum(rate, 1e-12).astype(jnp.float32)
-        burst = (burst_bytes / rate.astype(jnp.float64)).astype(jnp.int64)
+        # computed on the host: the TPU's emulated f64 division gave
+        # burst_ns a different last place than the CPU's (chip_smoke
+        # tor76, PR 21)
+        rate = np.maximum(kib_per_sec_to_bytes_per_ns(bandwidth_kib),
+                          1e-12).astype(np.float32)
+        burst = (burst_bytes / rate.astype(np.float64)).astype(np.int64)
+        rate, burst = jnp.asarray(rate), jnp.asarray(burst)
         z = jnp.zeros_like(burst)
         return NIC(
             free_at=z, rate=rate, burst_ns=burst, pkts=z, wire=z,

@@ -82,10 +82,7 @@ def _make_draw(n_hosts_global, mean_delay_ns, hot_hosts, hot_weight):
             # plain-PHOLD trajectories are unchanged
             peer_hot = srng.randint(srng.fold_in(kp, 1), 0, hot_hosts)
             peer = jnp.where(hot, peer_hot, peer)
-        delay = (
-            srng.exponential(kd) * mean_delay_ns
-        ).astype(jnp.int64)
-        return peer, delay
+        return peer, srng.exponential_ns(kd, mean_delay_ns)
 
     return draw
 

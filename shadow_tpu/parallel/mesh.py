@@ -28,49 +28,15 @@ HOSTS_AXIS = "hosts"
 DCN_AXIS = "dcn"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """`jax.shard_map` across jax versions: older releases only expose
-    `jax.experimental.shard_map.shard_map`, whose replication-check knob
-    is spelled `check_rep` rather than `check_vma`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def probe_spmd() -> str:
-    """Which shard_map this jax ships: "shard_map" (public `jax.shard_map`)
-    or "shard_map_exp" (`jax.experimental.shard_map`, every release back
-    to 0.4.x). Both are safe for this engine: the experimental one's
-    check_rep=False miscompile only fires when a collective sits inside a
-    while/cond predicate, and core.engine carries every such flag through
-    the loop body instead (the SL108 rule pins this structurally). The
-    probe exists so path selection and error messages can name what the
-    running jax actually supports."""
-    if hasattr(jax, "shard_map"):
-        return "shard_map"
-    try:
-        from jax.experimental.shard_map import shard_map as _sm  # noqa: F401
-        return "shard_map_exp"
-    except ImportError:  # pragma: no cover - ancient jax
-        return "pmap"
-
-
 def select_spmd(spmd: str = "auto") -> str:
     """Resolve an --spmd request to the executed path: "shard_map",
     "constraint" (jit + explicit shardings, GSPMD partitioning), or
-    "pmap" (the legacy 1-D fallback). "auto" takes shard_map whenever
-    the probe finds one (public or experimental) and only falls back to
-    pmap on a jax with neither."""
+    "pmap" (the legacy 1-D path). "auto" is shard_map."""
     if spmd not in ("auto", "shard_map", "constraint", "pmap"):
         raise ValueError(
             f"spmd must be auto|shard_map|constraint|pmap, got {spmd!r}"
         )
-    if spmd == "auto":
-        return "shard_map" if probe_spmd() != "pmap" else "pmap"
-    return spmd
+    return "shard_map" if spmd == "auto" else spmd
 
 
 def make_mesh(n_devices: int | None = None, axis: str = HOSTS_AXIS,
@@ -130,13 +96,9 @@ def state_specs(st, n_hosts_local: int, axis: str = HOSTS_AXIS):
 
 
 def pmap_call(fn, mesh: Mesh, specs, per: int, axes):
-    """Run `fn(state, stop, host0)` data-parallel via `jax.pmap`.
-
-    Fallback for jax versions without `jax.shard_map`: their experimental
-    shard_map miscompiles this engine under check_rep=False (collectives
-    inside while/cond conds leak device 0's carried state to every shard
-    — observed as hosts on shard > 0 recording wrong peer gids), while
-    the mature pmap path compiles the identical program correctly.
+    """Run `fn(state, stop, host0)` data-parallel via `jax.pmap` — the
+    legacy 1-D path, kept for soak comparison until ROADMAP C3 settles
+    it.
 
     `specs` is the state's PartitionSpec pytree: leaves sharded on the
     mesh axis reshape [S*d0, ...] <-> [S, d0, ...] around the pmap
@@ -148,8 +110,7 @@ def pmap_call(fn, mesh: Mesh, specs, per: int, axes):
     if not isinstance(axes, str):
         raise NotImplementedError(
             "the pmap fallback is single-axis only: a multi-slice "
-            "(dcn x hosts) mesh must run through the SPMD paths — this "
-            f"jax's capability probe says {probe_spmd()!r}, so build "
+            "(dcn x hosts) mesh must run through the SPMD paths — build "
             "with spmd='auto' (selects "
             f"{select_spmd('auto')!r}) or spmd='constraint' instead of "
             "spmd='pmap'"
@@ -197,10 +158,8 @@ def build_sharded(eng, init_fn, mesh: Mesh, n_hosts_local: int,
     init() -> sharded EngineState; run(st, stop) / step_window(st, stop).
 
     `spmd` picks the execution path (see `select_spmd`): "auto" resolves
-    to shard_map — public or experimental, both safe now that the engine
-    carries every loop flag through the body (no collective ever sits in
-    a lowered predicate) — and "pmap" keeps the legacy 1-D fallback
-    alive for soak comparison.
+    to shard_map, and "pmap" keeps the legacy 1-D path alive for soak
+    comparison.
     """
     path = select_spmd(spmd)
     if path == "constraint":
@@ -217,7 +176,7 @@ def build_sharded(eng, init_fn, mesh: Mesh, n_hosts_local: int,
     specs = state_specs(template, n_hosts_local, axis)
 
     init = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda: init_fn(_host0()),
             mesh=mesh,
             in_specs=(),
@@ -234,7 +193,7 @@ def build_sharded(eng, init_fn, mesh: Mesh, n_hosts_local: int,
         # call. The managed path (sim.Simulation) donates — it tracks
         # state ownership and can prove the input buffer is dead.
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda s, t: fn(s, t, _host0()),
                 mesh=mesh,
                 in_specs=(specs, P()),

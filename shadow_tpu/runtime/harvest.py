@@ -20,7 +20,7 @@ applies every reduction (sums, means) device-side, resets the trace
 ring inside the same program, and returns the untouched simulation
 state alongside a dict of small device arrays. The state input is
 DONATED (single-device builds), so the pass-through costs no copies;
-jit outputs never alias each other on the supported jax pins, so the
+jit outputs never alias each other, so the
 bundle stays fetchable after `state'` is donated into the *next*
 segment — which is exactly the depth-1 dispatch-ahead the CLI loop
 runs: dispatch segment k+1, then consume heartbeat k's fetched bundle

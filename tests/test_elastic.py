@@ -661,7 +661,6 @@ def _cli_env(**extra):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(REPO, ".jax_cache_cpu")
     env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
     env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
     env.update(extra)

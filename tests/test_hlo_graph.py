@@ -181,10 +181,12 @@ def test_roundtrip_sharded_phold_gspmd():
     except RuntimeError as e:
         pytest.skip(str(e))
     m = G.parse_module(H.lower_text(run, state, stop))
+    # Shardy sharding markers present (jax 0.9 prints no GSPMD
+    # custom_call @Sharding)...
+    assert "sdy.manual_computation" in m.sharding_markers()
     targets = set(m.custom_call_targets())
-    assert "Sharding" in targets  # GSPMD markers present...
     allow = set(H.CONTRACTS["phold_sharded"].custom_call_allow)
-    assert targets <= allow  # ...and all on the allowlist
+    assert targets <= allow  # ...and any custom_call on the allowlist
     hist = m.histogram()
     # the sharded contract, structurally: counts come from the
     # reachable graph (shmap_body and its callees), not regex text

@@ -16,8 +16,9 @@ spill-ring contents including eviction order. This file pins that:
   plumbing costs nothing when off.
 
 Everything runs on CPU (interpret mode executes the same jnp ops inside
-the jitted program); on a TPU backend the same tests exercise the real
-Pallas lowering.
+the jitted program). Off the CPU the engine refuses `kernel="pallas"`
+when it is built (pinned below); tests/test_tpu_compile.py pins the TPU
+compiler's own refusal of the kernel.
 """
 
 import dataclasses
@@ -129,3 +130,20 @@ def test_kernel_knob_default_is_zero_cost():
     text_d = lower_text(eng_d.run, init_d(), stop)
     text_x = lower_text(eng_x.run, init_x(), stop)
     assert text_d == text_x
+
+
+def test_pallas_kernel_refused_off_cpu(monkeypatch):
+    """No silent interpret mode on a chip: an Engine built with
+    kernel="pallas" when the first device is not a CPU raises, naming
+    the compiler's reasons."""
+
+    class _Tpu:
+        platform = "tpu"
+
+    real = jax.devices
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Tpu()] if not a
+                        else real(*a))
+    with pytest.raises(ValueError, match="64-bit types") as ei:
+        phold.build(8, kernel="pallas")
+    assert "2-D" in str(ei.value) and "kernel='xla'" in str(ei.value)
+    phold.build(8)  # the default kernel builds anywhere

@@ -312,9 +312,8 @@ def test_cli_validate_flag_passes_clean_run(tmp_path):
 def _cli_env():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    # share the suite's persistent compile cache so the subprocess pays
-    # ~no XLA compile time after the first ever run on this machine
-    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(REPO, ".jax_cache_cpu")
+    # the suite's compile cache rides in via JAX_COMPILATION_CACHE_DIR,
+    # which tests/conftest.py sets unless the caller already has
     env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
     env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
     return env
