@@ -362,13 +362,12 @@ def _make_observability(cfg, sim, args, trace=None, metrics=None):
 
 def _make_profiler(args):
     """WindowProfiler when --profile, else None — plus a phase context
-    factory that degrades to a no-op so call sites stay unconditional."""
-    import contextlib
+    factory for the call sites: the profiler's timed phase, or without
+    --profile only the phase's span on the XLA profiler's timeline."""
+    from shadow_tpu.obs.profiler import WindowProfiler, host_phase
 
     if not args.profile:
-        return None, (lambda _name: contextlib.nullcontext())
-    from shadow_tpu.obs import WindowProfiler
-
+        return None, host_phase
     prof = WindowProfiler()
     return prof, prof.phase
 

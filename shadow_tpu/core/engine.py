@@ -60,6 +60,7 @@ from shadow_tpu.core.events import (
     pack_srcseq,
     queue_push,
 )
+from shadow_tpu.core.phases import phase
 from shadow_tpu.core.timebase import TIME_INVALID
 
 # Burst-fold length-word layout: low bits payload total, high bits the
@@ -588,6 +589,7 @@ class Engine:
             self._xchg_r = 0
 
     # -- collectives (identity when unsharded) ------------------------------
+    @phase("barrier")
     def _gmin(self, x):
         """Global min over the mesh. The TPU compiler lowers a 64-bit
         all-reduce only as a sum ("Supported lowering only of Sum all
@@ -602,16 +604,19 @@ class Engine:
             jax.lax.bitcast_convert_type(x, jnp.uint32), ax)
         return jnp.min(jax.lax.bitcast_convert_type(words, x.dtype), axis=0)
 
+    @phase("barrier")
     def _gany(self, x: jax.Array) -> jax.Array:
         if self.cfg.axis_name is not None:
             return jax.lax.psum(x.astype(jnp.int32), self.cfg.axis_name) > 0
         return x
 
+    @phase("barrier")
     def _gsum(self, x: jax.Array) -> jax.Array:
         if self.cfg.axis_name is not None:
             return jax.lax.psum(x, self.cfg.axis_name)
         return x
 
+    @phase("barrier")
     def _drain_flag(self, q: EventQueue, cpu_free, window_end) -> jax.Array:
         """True while any host (globally) still has an executable event
         below the window barrier. Computed in loop BODIES and threaded
@@ -624,6 +629,7 @@ class Engine:
             nxt = jnp.maximum(nxt, cpu_free)
         return self._gany(jnp.any(nxt < window_end))
 
+    @phase("deliver")
     def _xchg_deliver(self, q: EventQueue, xchg, host0):
         """Merge the in-flight exchange buffer into the local queue and
         return it emptied. The guard predicate is shard-local and both
@@ -644,6 +650,7 @@ class Engine:
             self.cfg.n_shards, self._xchg_r, self.cfg.n_args
         )
 
+    @phase("exchange")
     def _exchange_push(self, q: EventQueue, xchg, ev: Events,
                        mask: jax.Array, host0):
         """Push a flat routed batch, delivering cross-shard events by
@@ -860,6 +867,7 @@ class Engine:
         return jnp.any(onehot & al_sh[:, None, :], axis=-1)
 
     # -- shared emit routing -------------------------------------------------
+    @phase("route")
     def _route(self, emit: Emit, base_time, gids, window_end, rkeys, emask,
                seq):
         """Route an [N, K] emit batch: local tasks keep their time;
@@ -959,8 +967,6 @@ class Engine:
         cfg = self.cfg
         h, k = cfg.n_hosts, cfg.max_emit
 
-        hkeys, rkeys = srng.event_keys(self._base_key, gids, exec_cnt)
-
         def per_host(hs, e, key):
             branches = tuple(
                 (lambda fn: lambda: _pad(fn(hs, e, key), k))(fn) for fn in self.handlers
@@ -973,81 +979,86 @@ class Engine:
             idx = jnp.clip(e.kind, 0, len(branches) - 1)
             return jax.lax.switch(idx, branches)
 
-        hosts2, emit = jax.vmap(per_host)(hosts, ev, hkeys)
-        hosts = _select_rows(active, hosts2, hosts)
-        emask = emit.mask & active[:, None]
+        with phase("handler"):
+            hkeys, rkeys = srng.event_keys(self._base_key, gids, exec_cnt)
+            hosts2, emit = jax.vmap(per_host)(hosts, ev, hkeys)
+            hosts = _select_rows(active, hosts2, hosts)
+            emask = emit.mask & active[:, None]
 
-        # per-source sequence numbers, dense over the masked emits so the
-        # numbering is independent of K padding (event.c:110-153 tie-break)
-        inc = emask.astype(jnp.int32)
-        within = jnp.cumsum(inc, axis=1) - inc
-        seq = src_seq[:, None] + within
-        src_seq = src_seq + jnp.sum(inc, axis=1, dtype=jnp.int32)
-
-        out, final_mask, dropped, fdropped, _t, _is_local = self._route(
-            emit, ev.time, gids, window_end, rkeys, emask, seq
-        )
-
-        if self._stats and splane is not None:
-            # every delivered emit executes at its routed time _t, so
-            # _t - now IS exec-minus-enqueue sim time; the non-local
-            # subset is the send->exec network latency
-            delta = _t - ev.time[:, None]
-            splane = splane.observe("wait", delta, final_mask)
-            splane = splane.observe("net", delta, final_mask & ~_is_local)
-
-        if self._trace and trace is not None:
-            from shadow_tpu.obs.trace import (
-                OP_DROP, OP_EXEC, OP_FDROP, OP_SEND, trace_append,
+        with phase("route"):
+            # per-source sequence numbers, dense over the masked emits so
+            # the numbering is independent of K padding (event.c:110-153
+            # tie-break)
+            inc = emask.astype(jnp.int32)
+            within = jnp.cumsum(inc, axis=1) - inc
+            seq = src_seq[:, None] + within
+            src_seq = src_seq + jnp.sum(inc, axis=1, dtype=jnp.int32)
+            out, final_mask, dropped, fdropped, _t, _is_local = self._route(
+                emit, ev.time, gids, window_end, rkeys, emask, seq
             )
 
-            la = cfg.trace_len_arg
-            # one width-(1+K) append: the executed event (op EXEC, on the
-            # executing host's row) + its non-local emits (op SEND, or
-            # DROP/FDROP with the loss attribution, on the source row at
-            # emission time — the matching EXEC on the destination row is
-            # the arrival, and (src, seq) ties the pair into a flow)
-            op_send = jnp.where(
-                dropped, OP_DROP,
-                jnp.where(fdropped, OP_FDROP, OP_SEND),
-            ).astype(jnp.int32)
-            col = lambda a: a[:, None]
-            trace = trace_append(
-                trace, cfg.trace,
-                time=jnp.concatenate(
-                    [col(ev.time), jnp.broadcast_to(col(ev.time), (h, k))], 1
-                ),
-                src=jnp.concatenate([col(ev.src), out.src], 1),
-                dst=jnp.concatenate([col(ev.dst), out.dst], 1),
-                kind=jnp.concatenate([col(ev.kind), out.kind], 1),
-                plen=jnp.concatenate(
-                    [ev.args[:, la:la + 1], out.args[:, :, la]], 1
-                ),
-                seq=jnp.concatenate([col(ev.seq), out.seq], 1),
-                op=jnp.concatenate(
-                    [jnp.full((h, 1), OP_EXEC, jnp.int32), op_send], 1
-                ),
-                mask=jnp.concatenate(
-                    [col(active), emask & ~_is_local], 1
-                ),
-            )
+        with phase("observe"):
+            if self._stats and splane is not None:
+                # every delivered emit executes at its routed time _t, so
+                # _t - now IS exec-minus-enqueue sim time; the non-local
+                # subset is the send->exec network latency
+                delta = _t - ev.time[:, None]
+                splane = splane.observe("wait", delta, final_mask)
+                splane = splane.observe("net", delta, final_mask & ~_is_local)
 
-        exec_cnt = exec_cnt + active.astype(jnp.int32)
-        stats = dataclasses.replace(
-            stats,
-            n_executed=stats.n_executed + active,
-            n_emitted=stats.n_emitted + jnp.sum(inc, axis=1, dtype=jnp.int64),
-            n_net_dropped=stats.n_net_dropped + jnp.sum(dropped, axis=1, dtype=jnp.int64),
-            n_fault_dropped=stats.n_fault_dropped
-            + jnp.sum(fdropped, axis=1, dtype=jnp.int64),
-            n_by_kind=stats.n_by_kind + (
-                jax.nn.one_hot(
-                    jnp.clip(ev.kind, 0, len(self.handlers) - 1),
-                    len(self.handlers), dtype=jnp.int64,
+            if self._trace and trace is not None:
+                from shadow_tpu.obs.trace import (
+                    OP_DROP, OP_EXEC, OP_FDROP, OP_SEND, trace_append,
                 )
-                * active[:, None]
-            ),
-        )
+
+                la = cfg.trace_len_arg
+                # one width-(1+K) append: the executed event (op EXEC, on the
+                # executing host's row) + its non-local emits (op SEND, or
+                # DROP/FDROP with the loss attribution, on the source row at
+                # emission time — the matching EXEC on the destination row is
+                # the arrival, and (src, seq) ties the pair into a flow)
+                op_send = jnp.where(
+                    dropped, OP_DROP,
+                    jnp.where(fdropped, OP_FDROP, OP_SEND),
+                ).astype(jnp.int32)
+                col = lambda a: a[:, None]
+                trace = trace_append(
+                    trace, cfg.trace,
+                    time=jnp.concatenate(
+                        [col(ev.time), jnp.broadcast_to(col(ev.time), (h, k))], 1
+                    ),
+                    src=jnp.concatenate([col(ev.src), out.src], 1),
+                    dst=jnp.concatenate([col(ev.dst), out.dst], 1),
+                    kind=jnp.concatenate([col(ev.kind), out.kind], 1),
+                    plen=jnp.concatenate(
+                        [ev.args[:, la:la + 1], out.args[:, :, la]], 1
+                    ),
+                    seq=jnp.concatenate([col(ev.seq), out.seq], 1),
+                    op=jnp.concatenate(
+                        [jnp.full((h, 1), OP_EXEC, jnp.int32), op_send], 1
+                    ),
+                    mask=jnp.concatenate(
+                        [col(active), emask & ~_is_local], 1
+                    ),
+                )
+
+        with phase("handler"):
+            exec_cnt = exec_cnt + active.astype(jnp.int32)
+            stats = dataclasses.replace(
+                stats,
+                n_executed=stats.n_executed + active,
+                n_emitted=stats.n_emitted + jnp.sum(inc, axis=1, dtype=jnp.int64),
+                n_net_dropped=stats.n_net_dropped + jnp.sum(dropped, axis=1, dtype=jnp.int64),
+                n_fault_dropped=stats.n_fault_dropped
+                + jnp.sum(fdropped, axis=1, dtype=jnp.int64),
+                n_by_kind=stats.n_by_kind + (
+                    jax.nn.one_hot(
+                        jnp.clip(ev.kind, 0, len(self.handlers) - 1),
+                        len(self.handlers), dtype=jnp.int64,
+                    )
+                    * active[:, None]
+                ),
+            )
         return (hosts, src_seq, exec_cnt, stats, out, final_mask, trace,
                 splane)
 
@@ -1080,165 +1091,175 @@ class Engine:
             # frontier: the gap since the sending sweep's push contains
             # no queue operation, so deferred delivery is bit-identical
             q, xchg = self._xchg_deliver(q, xchg, host0)
-            bt = q.time[:, :b]
-            # a host whose virtual CPU is busy past the barrier runs
-            # nothing this window (whole-frontier granularity)
-            bvalid = bt < window_end  # a prefix: rows are key-sorted
-            if self._cpu_enabled:
-                bvalid = bvalid & (cpu_free[:, None] < window_end)
-            if self._stats and splane is not None:
-                # queue fill at pop, pre-clear (chained-drain semantics:
-                # hosts popping at least one event this sweep)
-                splane = splane.observe(
-                    "qfill",
-                    jnp.sum(q.time != TIME_INVALID, axis=1,
-                            dtype=jnp.int64),
-                    jnp.any(bvalid, axis=1),
-                )
-            # crashed hosts consume (quarantine) their frontier without
-            # executing it: rows still clear below, handlers see
-            # TIME_INVALID
-            if self._f_crash:
-                run = bvalid & self._alive_at(
-                    al_sh, jnp.where(bvalid, bt, 0)
-                )
-            else:
-                run = bvalid
-            evs = Events(
-                time=jnp.where(run, bt, TIME_INVALID),
-                dst=jnp.broadcast_to(gids[:, None], (h, b)),
-                src=q.src[:, :b],
-                seq=q.seq[:, :b],
-                kind=q.kind[:, :b],
-                args=q.args[:, :b],
-            )
-            cnts = exec_cnt[:, None] + jnp.arange(b, dtype=jnp.int32)[None, :]
-            hk, rk = srng.event_keys(
-                self._base_key,
-                jnp.broadcast_to(gids[:, None], (h, b)).reshape(-1),
-                cnts.reshape(-1),
-            )
-            hk = hk.reshape((h, b, 2))
-
-            hosts2, emit = jax.vmap(self.batch_handler)(hosts, evs, hk)
-            # n_exec counts the CLEARED prefix (and RNG positions) —
-            # quarantined events consume both; n_run counts executions
-            n_exec = jnp.sum(bvalid, axis=1, dtype=jnp.int32)
-            n_run = jnp.sum(run, axis=1, dtype=jnp.int32)
-            hosts = _select_rows(n_run > 0, hosts2, hosts)
-            emask = emit.mask & run[:, :, None]
-
-            # dense per-source sequence numbers across the [B, K] lanes
-            inc = emask.astype(jnp.int32).reshape(h, b * k)
-            within = jnp.cumsum(inc, axis=1) - inc
-            seq = (src_seq[:, None] + within).reshape(h, b, k)
-            src_seq = src_seq + jnp.sum(inc, axis=1, dtype=jnp.int32)
-
-            flat = lambda a: a.reshape((h * b,) + a.shape[2:])
-            em_flat = jax.tree.map(flat, emit)
-            out, final_mask, dropped, fdropped, _t, _loc = self._route(
-                em_flat,
-                evs.time.reshape(-1),
-                jnp.broadcast_to(gids[:, None], (h, b)).reshape(-1),
-                window_end,
-                rk,
-                flat(emask),
-                flat(seq),
-            )
-
-            if self._stats and splane is not None:
-                delta = (_t - evs.time.reshape(-1)[:, None]).reshape(
-                    h, b * k
-                )
-                fm = final_mask.reshape(h, b * k)
-                splane = splane.observe("wait", delta, fm)
-                splane = splane.observe(
-                    "net", delta, fm & ~_loc.reshape(h, b * k)
-                )
-
-            if self._trace and trace is not None:
-                from shadow_tpu.obs.trace import (
-                    OP_DROP, OP_EXEC, OP_FDROP, OP_SEND, trace_append,
-                )
-
-                la = cfg.trace_len_arg
-                # one width-(B + B*K) append per sweep: the executed
-                # frontier (EXEC rows) + every non-local emit
-                # (SEND/DROP/FDROP rows) — same semantics as the chained
-                # path's per-step append in _execute_step
-                wide = lambda a: a.reshape(h, b * k)  # [H*B, K] -> [H, BK]
-                op_send = jnp.where(
-                    dropped, OP_DROP,
-                    jnp.where(fdropped, OP_FDROP, OP_SEND),
-                ).astype(jnp.int32)
-                send_t = jnp.broadcast_to(
-                    evs.time[:, :, None], (h, b, k)
-                ).reshape(h, b * k)
-                trace = trace_append(
-                    trace, cfg.trace,
-                    time=jnp.concatenate([evs.time, send_t], 1),
-                    src=jnp.concatenate([evs.src, wide(out.src)], 1),
-                    dst=jnp.concatenate([evs.dst, wide(out.dst)], 1),
-                    kind=jnp.concatenate([evs.kind, wide(out.kind)], 1),
-                    plen=jnp.concatenate(
-                        [evs.args[:, :, la],
-                         out.args[:, :, la].reshape(h, b * k)], 1
-                    ),
-                    seq=jnp.concatenate([evs.seq, wide(out.seq)], 1),
-                    op=jnp.concatenate(
-                        [jnp.full((h, b), OP_EXEC, jnp.int32),
-                         wide(op_send)], 1
-                    ),
-                    mask=jnp.concatenate(
-                        [run, wide(flat(emask) & ~_loc)], 1
-                    ),
-                )
-
-            exec_cnt = exec_cnt + n_exec
-            stats2 = dataclasses.replace(
-                stats,
-                n_executed=stats.n_executed + n_run,
-                n_emitted=stats.n_emitted
-                + jnp.sum(inc, axis=1, dtype=jnp.int64),
-                n_net_dropped=stats.n_net_dropped
-                + jnp.sum(
-                    dropped.reshape(h, b * k), axis=1, dtype=jnp.int64
-                ),
-                n_fault_dropped=stats.n_fault_dropped
-                + jnp.sum(
-                    fdropped.reshape(h, b * k), axis=1, dtype=jnp.int64
-                ),
-                n_quarantined=stats.n_quarantined
-                + jnp.sum(bvalid & ~run, axis=1, dtype=jnp.int64),
-                n_by_kind=stats.n_by_kind + jnp.sum(
-                    jax.nn.one_hot(
-                        jnp.clip(evs.kind, 0, len(self.handlers) - 1),
-                        len(self.handlers), dtype=jnp.int64,
+            with phase("select"):
+                bt = q.time[:, :b]
+                # a host whose virtual CPU is busy past the barrier runs
+                # nothing this window (whole-frontier granularity)
+                bvalid = bt < window_end  # a prefix: rows are key-sorted
+                if self._cpu_enabled:
+                    bvalid = bvalid & (cpu_free[:, None] < window_end)
+            with phase("observe"):
+                if self._stats and splane is not None:
+                    # queue fill at pop, pre-clear (chained-drain semantics:
+                    # hosts popping at least one event this sweep)
+                    splane = splane.observe(
+                        "qfill",
+                        jnp.sum(q.time != TIME_INVALID, axis=1,
+                                dtype=jnp.int64),
+                        jnp.any(bvalid, axis=1),
                     )
-                    * run[:, :, None],
-                    axis=1,
-                ),
-            )
-            if self._cpu_enabled:
-                # virtual-CPU charge: the frontier's summed per-kind
-                # costs advance cpu_free past its last executed event.
-                # One-hot select, not take_along_axis: a computed-index
-                # gather here measured ~20% of the whole sweep on TPU
-                ev_cost = _kind_cost(cpu_cost, evs.kind)
-                total_cost = jnp.sum(
-                    jnp.where(run, ev_cost, 0), axis=1
+            with phase("select"):
+                # crashed hosts consume (quarantine) their frontier without
+                # executing it: rows still clear below, handlers see
+                # TIME_INVALID
+                if self._f_crash:
+                    run = bvalid & self._alive_at(
+                        al_sh, jnp.where(bvalid, bt, 0)
+                    )
+                else:
+                    run = bvalid
+            with phase("pop"):
+                evs = Events(
+                    time=jnp.where(run, bt, TIME_INVALID),
+                    dst=jnp.broadcast_to(gids[:, None], (h, b)),
+                    src=q.src[:, :b],
+                    seq=q.seq[:, :b],
+                    kind=q.kind[:, :b],
+                    args=q.args[:, :b],
                 )
-                t_last = jnp.max(jnp.where(run, bt, 0), axis=1)
-                cpu_free = jnp.where(
-                    total_cost > 0,
-                    jnp.maximum(cpu_free, t_last) + total_cost,
-                    cpu_free,
+            with phase("handler"):
+                cnts = exec_cnt[:, None] + jnp.arange(b, dtype=jnp.int32)[None, :]
+                hk, rk = srng.event_keys(
+                    self._base_key,
+                    jnp.broadcast_to(gids[:, None], (h, b)).reshape(-1),
+                    cnts.reshape(-1),
+                )
+                hk = hk.reshape((h, b, 2))
+
+                hosts2, emit = jax.vmap(self.batch_handler)(hosts, evs, hk)
+                # n_exec counts the CLEARED prefix (and RNG positions) —
+                # quarantined events consume both; n_run counts executions
+                n_exec = jnp.sum(bvalid, axis=1, dtype=jnp.int32)
+                n_run = jnp.sum(run, axis=1, dtype=jnp.int32)
+                hosts = _select_rows(n_run > 0, hosts2, hosts)
+                emask = emit.mask & run[:, :, None]
+
+            with phase("route"):
+                # dense per-source sequence numbers across the [B, K] lanes
+                inc = emask.astype(jnp.int32).reshape(h, b * k)
+                within = jnp.cumsum(inc, axis=1) - inc
+                seq = (src_seq[:, None] + within).reshape(h, b, k)
+                src_seq = src_seq + jnp.sum(inc, axis=1, dtype=jnp.int32)
+
+                flat = lambda a: a.reshape((h * b,) + a.shape[2:])
+                em_flat = jax.tree.map(flat, emit)
+                out, final_mask, dropped, fdropped, _t, _loc = self._route(
+                    em_flat,
+                    evs.time.reshape(-1),
+                    jnp.broadcast_to(gids[:, None], (h, b)).reshape(-1),
+                    window_end,
+                    rk,
+                    flat(emask),
+                    flat(seq),
                 )
 
-            cleared = jnp.arange(c, dtype=jnp.int32)[None, :] < n_exec[:, None]
-            q = dataclasses.replace(
-                q, time=jnp.where(cleared, TIME_INVALID, q.time)
-            )
+            with phase("observe"):
+                if self._stats and splane is not None:
+                    delta = (_t - evs.time.reshape(-1)[:, None]).reshape(
+                        h, b * k
+                    )
+                    fm = final_mask.reshape(h, b * k)
+                    splane = splane.observe("wait", delta, fm)
+                    splane = splane.observe(
+                        "net", delta, fm & ~_loc.reshape(h, b * k)
+                    )
+
+                if self._trace and trace is not None:
+                    from shadow_tpu.obs.trace import (
+                        OP_DROP, OP_EXEC, OP_FDROP, OP_SEND, trace_append,
+                    )
+
+                    la = cfg.trace_len_arg
+                    # one width-(B + B*K) append per sweep: the executed
+                    # frontier (EXEC rows) + every non-local emit
+                    # (SEND/DROP/FDROP rows) — same semantics as the chained
+                    # path's per-step append in _execute_step
+                    wide = lambda a: a.reshape(h, b * k)  # [H*B, K] -> [H, BK]
+                    op_send = jnp.where(
+                        dropped, OP_DROP,
+                        jnp.where(fdropped, OP_FDROP, OP_SEND),
+                    ).astype(jnp.int32)
+                    send_t = jnp.broadcast_to(
+                        evs.time[:, :, None], (h, b, k)
+                    ).reshape(h, b * k)
+                    trace = trace_append(
+                        trace, cfg.trace,
+                        time=jnp.concatenate([evs.time, send_t], 1),
+                        src=jnp.concatenate([evs.src, wide(out.src)], 1),
+                        dst=jnp.concatenate([evs.dst, wide(out.dst)], 1),
+                        kind=jnp.concatenate([evs.kind, wide(out.kind)], 1),
+                        plen=jnp.concatenate(
+                            [evs.args[:, :, la],
+                             out.args[:, :, la].reshape(h, b * k)], 1
+                        ),
+                        seq=jnp.concatenate([evs.seq, wide(out.seq)], 1),
+                        op=jnp.concatenate(
+                            [jnp.full((h, b), OP_EXEC, jnp.int32),
+                             wide(op_send)], 1
+                        ),
+                        mask=jnp.concatenate(
+                            [run, wide(flat(emask) & ~_loc)], 1
+                        ),
+                    )
+
+            with phase("handler"):
+                exec_cnt = exec_cnt + n_exec
+                stats2 = dataclasses.replace(
+                    stats,
+                    n_executed=stats.n_executed + n_run,
+                    n_emitted=stats.n_emitted
+                    + jnp.sum(inc, axis=1, dtype=jnp.int64),
+                    n_net_dropped=stats.n_net_dropped
+                    + jnp.sum(
+                        dropped.reshape(h, b * k), axis=1, dtype=jnp.int64
+                    ),
+                    n_fault_dropped=stats.n_fault_dropped
+                    + jnp.sum(
+                        fdropped.reshape(h, b * k), axis=1, dtype=jnp.int64
+                    ),
+                    n_quarantined=stats.n_quarantined
+                    + jnp.sum(bvalid & ~run, axis=1, dtype=jnp.int64),
+                    n_by_kind=stats.n_by_kind + jnp.sum(
+                        jax.nn.one_hot(
+                            jnp.clip(evs.kind, 0, len(self.handlers) - 1),
+                            len(self.handlers), dtype=jnp.int64,
+                        )
+                        * run[:, :, None],
+                        axis=1,
+                    ),
+                )
+            with phase("append"):
+                if self._cpu_enabled:
+                    # virtual-CPU charge: the frontier's summed per-kind
+                    # costs advance cpu_free past its last executed event.
+                    # One-hot select, not take_along_axis: a computed-index
+                    # gather here measured ~20% of the whole sweep on TPU
+                    ev_cost = _kind_cost(cpu_cost, evs.kind)
+                    total_cost = jnp.sum(
+                        jnp.where(run, ev_cost, 0), axis=1
+                    )
+                    t_last = jnp.max(jnp.where(run, bt, 0), axis=1)
+                    cpu_free = jnp.where(
+                        total_cost > 0,
+                        jnp.maximum(cpu_free, t_last) + total_cost,
+                        cpu_free,
+                    )
+
+            with phase("pop"):
+                cleared = jnp.arange(c, dtype=jnp.int32)[None, :] < n_exec[:, None]
+                q = dataclasses.replace(
+                    q, time=jnp.where(cleared, TIME_INVALID, q.time)
+                )
             q, xchg, xr, nc = self._exchange_push(
                 q, xchg, out.flatten(), final_mask.reshape(-1), host0
             )
@@ -1379,6 +1400,7 @@ class Engine:
         )
 
     @staticmethod
+    @phase("select")
     def _stage_min(stage: Events):
         """Per host, the minimum-(time, src, seq) staged event.
 
@@ -1420,6 +1442,7 @@ class Engine:
         return ev, mss, onehot, valid_cnt
 
     @staticmethod
+    @phase("append")
     def _stage_append(stage: Events, out: Events):
         """Append a routed [H, K] emit batch into each host's free staging
         slots by RANK MATCHING: the j-th valid emit lands in the j-th
@@ -1495,63 +1518,65 @@ class Engine:
             # the deferred merge is bit-identical to an immediate one
             q, xchg = self._xchg_deliver(q, xchg, host0)
 
-            # 1. move the frontier into staging: queue rows are sorted by
-            # (time, src, seq) with empties last (events.py invariant), so
-            # each host's b earliest below-barrier events are its first b
-            # columns, and clearing them is a prefix compare — no scatter.
-            bvalid = q.time[:, :b] < window_end  # a prefix of each row
-            ndump = jnp.sum(bvalid, axis=1, dtype=jnp.int32)
-            if self._stats and splane is not None:
-                # queue fill at pop: how full each popping host's queue
-                # is the moment its frontier dumps (pre-clear)
-                splane = splane.observe(
-                    "qfill",
-                    jnp.sum(q.time != TIME_INVALID, axis=1,
-                            dtype=jnp.int64),
-                    ndump > 0,
+            with phase("pop"):
+                # 1. move the frontier into staging: queue rows are sorted by
+                # (time, src, seq) with empties last (events.py invariant), so
+                # each host's b earliest below-barrier events are its first b
+                # columns, and clearing them is a prefix compare — no scatter.
+                bvalid = q.time[:, :b] < window_end  # a prefix of each row
+                ndump = jnp.sum(bvalid, axis=1, dtype=jnp.int32)
+                with phase("observe"):
+                    if self._stats and splane is not None:
+                        # queue fill at pop: how full each popping host's queue
+                        # is the moment its frontier dumps (pre-clear)
+                        splane = splane.observe(
+                            "qfill",
+                            jnp.sum(q.time != TIME_INVALID, axis=1,
+                                    dtype=jnp.int64),
+                            ndump > 0,
+                        )
+                pad = ((0, 0), (0, sw - b))
+                stage = Events(
+                    time=jnp.pad(
+                        jnp.where(bvalid, q.time[:, :b], TIME_INVALID),
+                        pad, constant_values=TIME_INVALID,
+                    ),
+                    dst=jnp.pad(jnp.broadcast_to(gids[:, None], (h, b)), pad),
+                    src=jnp.pad(q.src[:, :b], pad),
+                    seq=jnp.pad(q.seq[:, :b], pad),
+                    kind=jnp.pad(q.kind[:, :b], pad),
+                    args=jnp.pad(q.args[:, :b], (*pad, (0, 0))),
                 )
-            pad = ((0, 0), (0, sw - b))
-            stage = Events(
-                time=jnp.pad(
-                    jnp.where(bvalid, q.time[:, :b], TIME_INVALID),
-                    pad, constant_values=TIME_INVALID,
-                ),
-                dst=jnp.pad(jnp.broadcast_to(gids[:, None], (h, b)), pad),
-                src=jnp.pad(q.src[:, :b], pad),
-                seq=jnp.pad(q.seq[:, :b], pad),
-                kind=jnp.pad(q.kind[:, :b], pad),
-                args=jnp.pad(q.args[:, :b], (*pad, (0, 0))),
-            )
-            cleared = jnp.arange(c, dtype=jnp.int32)[None, :] < ndump[:, None]
-            q = dataclasses.replace(
-                q, time=jnp.where(cleared, TIME_INVALID, q.time)
-            )
-            if cfg.burst is not None:
-                # the dump is each host's earliest-b prefix, so every
-                # staged event precedes the queue head: folding inside
-                # it can never violate the head guard below
-                stage = self._burst_fold(stage)
+                cleared = jnp.arange(c, dtype=jnp.int32)[None, :] < ndump[:, None]
+                q = dataclasses.replace(
+                    q, time=jnp.where(cleared, TIME_INVALID, q.time)
+                )
+                if cfg.burst is not None:
+                    # the dump is each host's earliest-b prefix, so every
+                    # staged event precedes the queue head: folding inside
+                    # it can never violate the head guard below
+                    stage = self._burst_fold(stage)
 
-            # queue-head guard: the first UN-dumped event's key, per host
-            # (rows keep a sorted tail after the prefix clear, so it sits
-            # at column ndump; i64max when the row is exhausted). A staged
-            # event may only execute while its key precedes this — an
-            # event beyond the b-column dump could still be due first, and
-            # executing around it would break the (time, src, seq) total
-            # order. The queue is untouched mid-sweep, so this is constant
-            # per sweep.
-            i64max = jnp.iinfo(jnp.int64).max
-            headsel = (
-                jnp.arange(c, dtype=jnp.int32)[None, :] == ndump[:, None]
-            )
-            qh_t = jnp.min(jnp.where(headsel, q.time, i64max), axis=1)
-            qh_ss = jnp.min(
-                jnp.where(
-                    headsel & (q.time != TIME_INVALID),
-                    pack_srcseq(q.src, q.seq), i64max,
-                ),
-                axis=1,
-            )
+                # queue-head guard: the first UN-dumped event's key, per host
+                # (rows keep a sorted tail after the prefix clear, so it sits
+                # at column ndump; i64max when the row is exhausted). A staged
+                # event may only execute while its key precedes this — an
+                # event beyond the b-column dump could still be due first, and
+                # executing around it would break the (time, src, seq) total
+                # order. The queue is untouched mid-sweep, so this is constant
+                # per sweep.
+                i64max = jnp.iinfo(jnp.int64).max
+                headsel = (
+                    jnp.arange(c, dtype=jnp.int32)[None, :] == ndump[:, None]
+                )
+                qh_t = jnp.min(jnp.where(headsel, q.time, i64max), axis=1)
+                qh_ss = jnp.min(
+                    jnp.where(
+                        headsel & (q.time != TIME_INVALID),
+                        pack_srcseq(q.src, q.seq), i64max,
+                    ),
+                    axis=1,
+                )
 
             def precede_q(ev_t, ev_ss):
                 return (ev_t < qh_t) | ((ev_t == qh_t) & (ev_ss < qh_ss))
@@ -1563,12 +1588,13 @@ class Engine:
                 precomputed _stage_min result — it is carried through the
                 loop so each iteration pays the [H, S] min-key selection
                 exactly once."""
-                ev, mss, _oh, cnt = sm
-                mt = ev.time
-                eff = jnp.maximum(mt, cpu_free) if self._cpu_enabled else mt
-                return jnp.any(
-                    (eff < window_end) & precede_q(mt, mss) & (cnt + k <= sw)
-                )
+                with phase("select"):
+                    ev, mss, _oh, cnt = sm
+                    mt = ev.time
+                    eff = jnp.maximum(mt, cpu_free) if self._cpu_enabled else mt
+                    return jnp.any(
+                        (eff < window_end) & precede_q(mt, mss) & (cnt + k <= sw)
+                    )
 
             # 2. chained execution: per iteration every host runs its
             # minimum staged event; emits append back into staging, so
@@ -1581,71 +1607,73 @@ class Engine:
             def inner_body(ic):
                 (_, sm, stage, hosts, src_seq, exec_cnt, stats, cpu_free,
                  trace, splane) = ic
-                ev, mss, onehot, cnt = sm
-                ev_t = ev.time
-                eff_t = (
-                    jnp.maximum(ev_t, cpu_free) if self._cpu_enabled else ev_t
-                )
-                active = (
-                    (ev_t != TIME_INVALID)
-                    & (eff_t < window_end)
-                    & precede_q(ev_t, mss)
-                    & (cnt + k <= sw)  # high-water: leftovers flush
-                )
-                # a crashed host consumes its due events without running
-                # them (quarantine): the slot still clears below — via
-                # `active` — so the drain makes progress, but the handler
-                # never fires and no emits escape the dead host
-                if self._f_crash:
-                    alv = self._alive_at(al_sh, eff_t)
-                    runm = active & alv
-                    stats = dataclasses.replace(
-                        stats,
-                        n_quarantined=stats.n_quarantined
-                        + (active & ~alv),
+                with phase("select"):
+                    ev, mss, onehot, cnt = sm
+                    ev_t = ev.time
+                    eff_t = (
+                        jnp.maximum(ev_t, cpu_free) if self._cpu_enabled else ev_t
                     )
-                else:
-                    runm = active
-                stage = dataclasses.replace(
-                    stage,
-                    time=jnp.where(
-                        onehot & active[:, None], TIME_INVALID, stage.time
-                    ),
-                )
-                ev = dataclasses.replace(
-                    ev,
-                    time=jnp.where(runm, eff_t, TIME_INVALID),
-                    dst=gids,
-                )
+                    active = (
+                        (ev_t != TIME_INVALID)
+                        & (eff_t < window_end)
+                        & precede_q(ev_t, mss)
+                        & (cnt + k <= sw)  # high-water: leftovers flush
+                    )
+                    # a crashed host consumes its due events without running
+                    # them (quarantine): the slot still clears below — via
+                    # `active` — so the drain makes progress, but the handler
+                    # never fires and no emits escape the dead host
+                    if self._f_crash:
+                        alv = self._alive_at(al_sh, eff_t)
+                        runm = active & alv
+                        stats = dataclasses.replace(
+                            stats,
+                            n_quarantined=stats.n_quarantined
+                            + (active & ~alv),
+                        )
+                    else:
+                        runm = active
+                    stage = dataclasses.replace(
+                        stage,
+                        time=jnp.where(
+                            onehot & active[:, None], TIME_INVALID, stage.time
+                        ),
+                    )
+                    ev = dataclasses.replace(
+                        ev,
+                        time=jnp.where(runm, eff_t, TIME_INVALID),
+                        dst=gids,
+                    )
                 (hosts, src_seq, exec_cnt, stats, out, _fmask, trace,
                  splane) = self._execute_step(
                     hosts, src_seq, exec_cnt, stats, ev, runm,
                     window_end, gids, trace, splane,
                 )
-                if self._cpu_enabled:
-                    ev_cost = _kind_cost(cpu_cost, ev.kind)
-                    if self.cfg.burst is not None:
-                        # a folded arrival stands for nseg segments: the
-                        # virtual CPU pays per segment, not per event.
-                        # Zero-payload count carriers (dup ACKs) are one
-                        # packet; their count is protocol bookkeeping.
-                        bkind, _sq, blen = self.cfg.burst[:3]
-                        lw = ev.args[:, blen]
-                        nseg = jnp.where(
-                            (lw & BURST_LEN_MASK) > 0,
-                            jnp.maximum(lw >> BURST_NSEG_SHIFT, 1), 1,
+                with phase("append"):
+                    if self._cpu_enabled:
+                        ev_cost = _kind_cost(cpu_cost, ev.kind)
+                        if self.cfg.burst is not None:
+                            # a folded arrival stands for nseg segments: the
+                            # virtual CPU pays per segment, not per event.
+                            # Zero-payload count carriers (dup ACKs) are one
+                            # packet; their count is protocol bookkeeping.
+                            bkind, _sq, blen = self.cfg.burst[:3]
+                            lw = ev.args[:, blen]
+                            nseg = jnp.where(
+                                (lw & BURST_LEN_MASK) > 0,
+                                jnp.maximum(lw >> BURST_NSEG_SHIFT, 1), 1,
+                            )
+                            ev_cost = ev_cost * jnp.where(
+                                ev.kind == bkind, nseg.astype(ev_cost.dtype), 1
+                            )
+                        cpu_free = jnp.where(
+                            runm & (ev_cost > 0), eff_t + ev_cost,
+                            cpu_free,
                         )
-                        ev_cost = ev_cost * jnp.where(
-                            ev.kind == bkind, nseg.astype(ev_cost.dtype), 1
-                        )
-                    cpu_free = jnp.where(
-                        runm & (ev_cost > 0), eff_t + ev_cost,
-                        cpu_free,
+                    stage = self._stage_append(stage, out)
+                    stats = dataclasses.replace(
+                        stats, n_inner_steps=stats.n_inner_steps + 1
                     )
-                stage = self._stage_append(stage, out)
-                stats = dataclasses.replace(
-                    stats, n_inner_steps=stats.n_inner_steps + 1
-                )
                 sm2 = self._stage_min(stage)
                 return (can_run(sm2, cpu_free), sm2, stage, hosts, src_seq,
                         exec_cnt, stats, cpu_free, trace, splane)
@@ -1659,65 +1687,66 @@ class Engine:
                  exec_cnt, stats, cpu_free, trace, splane),
             )
 
-            # 3. flush staging leftovers (clamped remote sends, far-future
-            # locals, high-water overflow) in one push + exchange. A
-            # row-wise key sort compacts valid entries to a prefix; the
-            # common case pushes only a narrow column slice (staged
-            # leftovers are few), with a full-width fallback when any
-            # host's count exceeds it — exact either way.
-            skey = pack_srcseq(stage.src, stage.seq)
-            t2, _ss2, dst2, src2, seq2, kind2, *acols = jax.lax.sort(
-                (stage.time, skey, stage.dst, stage.src, stage.seq,
-                 stage.kind,
-                 *[stage.args[:, :, i] for i in range(cfg.n_args)]),
-                dimension=1, num_keys=2,
-            )
-            stage = Events(
-                time=t2, dst=dst2, src=src2, seq=seq2, kind=kind2,
-                args=jnp.stack(acols, axis=-1),
-            )
-            w1 = min(sw, 16)
-            maxcnt = jnp.max(
-                jnp.sum(stage.time != TIME_INVALID, axis=1, dtype=jnp.int32)
-            )
-
-            def push_narrow(args):
-                q, xchg, stage = args
-                sl = jax.tree.map(lambda a: a[:, :w1], stage)
-                flat = sl.flatten()
-                return self._exchange_push(
-                    q, xchg, flat, flat.time != TIME_INVALID, host0
+            with phase("flush"):
+                # 3. flush staging leftovers (clamped remote sends, far-future
+                # locals, high-water overflow) in one push + exchange. A
+                # row-wise key sort compacts valid entries to a prefix; the
+                # common case pushes only a narrow column slice (staged
+                # leftovers are few), with a full-width fallback when any
+                # host's count exceeds it — exact either way.
+                skey = pack_srcseq(stage.src, stage.seq)
+                t2, _ss2, dst2, src2, seq2, kind2, *acols = jax.lax.sort(
+                    (stage.time, skey, stage.dst, stage.src, stage.seq,
+                     stage.kind,
+                     *[stage.args[:, :, i] for i in range(cfg.n_args)]),
+                    dimension=1, num_keys=2,
+                )
+                stage = Events(
+                    time=t2, dst=dst2, src=src2, seq=seq2, kind=kind2,
+                    args=jnp.stack(acols, axis=-1),
+                )
+                w1 = min(sw, 16)
+                maxcnt = jnp.max(
+                    jnp.sum(stage.time != TIME_INVALID, axis=1, dtype=jnp.int32)
                 )
 
-            def push_full(args):
-                q, xchg, stage = args
-                flat = stage.flatten()
-                return self._exchange_push(
-                    q, xchg, flat, flat.time != TIME_INVALID, host0
-                )
+                def push_narrow(args):
+                    q, xchg, stage = args
+                    sl = jax.tree.map(lambda a: a[:, :w1], stage)
+                    flat = sl.flatten()
+                    return self._exchange_push(
+                        q, xchg, flat, flat.time != TIME_INVALID, host0
+                    )
 
-            if w1 == sw:
-                q, xchg, xr, nc = push_full((q, xchg, stage))
-            elif cfg.axis_name is not None:
-                # sharded: the exchange's collectives must run under a
-                # shard-uniform program, and maxcnt differs per shard —
-                # make the branch choice global. The ExchangeBuf's one
-                # static engine-level width is what lets both branches
-                # return the same carried-buffer shape.
-                go_wide = self._gany(maxcnt > w1)
-                q, xchg, xr, nc = jax.lax.cond(
-                    go_wide, push_full, push_narrow, (q, xchg, stage)
+                def push_full(args):
+                    q, xchg, stage = args
+                    flat = stage.flatten()
+                    return self._exchange_push(
+                        q, xchg, flat, flat.time != TIME_INVALID, host0
+                    )
+
+                if w1 == sw:
+                    q, xchg, xr, nc = push_full((q, xchg, stage))
+                elif cfg.axis_name is not None:
+                    # sharded: the exchange's collectives must run under a
+                    # shard-uniform program, and maxcnt differs per shard —
+                    # make the branch choice global. The ExchangeBuf's one
+                    # static engine-level width is what lets both branches
+                    # return the same carried-buffer shape.
+                    go_wide = self._gany(maxcnt > w1)
+                    q, xchg, xr, nc = jax.lax.cond(
+                        go_wide, push_full, push_narrow, (q, xchg, stage)
+                    )
+                else:
+                    q, xchg, xr, nc = jax.lax.cond(
+                        maxcnt > w1, push_full, push_narrow, (q, xchg, stage)
+                    )
+                stats = dataclasses.replace(
+                    stats,
+                    n_sweeps=stats.n_sweeps + 1,
+                    n_xchg_rounds=stats.n_xchg_rounds + xr,
+                    n_cross_shard=stats.n_cross_shard + nc,
                 )
-            else:
-                q, xchg, xr, nc = jax.lax.cond(
-                    maxcnt > w1, push_full, push_narrow, (q, xchg, stage)
-                )
-            stats = dataclasses.replace(
-                stats,
-                n_sweeps=stats.n_sweeps + 1,
-                n_xchg_rounds=stats.n_xchg_rounds + xr,
-                n_cross_shard=stats.n_cross_shard + nc,
-            )
             more = self._drain_flag(q, cpu_free, window_end)
             return (more, q, xchg, hosts, src_seq, exec_cnt, stats,
                     cpu_free, trace, splane)
@@ -1733,14 +1762,16 @@ class Engine:
             q, xchg = self._xchg_deliver(q, xchg, host0)
         # each shard's inner loop trips independently; fold this window's
         # delta across shards so the counter stays replicated-consistent
-        inner = st.stats.n_inner_steps + self._gsum(
-            stats.n_inner_steps - st.stats.n_inner_steps
-        )
-        if self._stats and splane is not None:
-            # per-window occupancy: events each host executed this
-            # window (hosts that ran nothing contribute no sample)
-            occ = stats.n_executed - st.stats.n_executed
-            splane = splane.observe("occ", occ, occ > 0)
+        with phase("barrier"):
+            inner = st.stats.n_inner_steps + self._gsum(
+                stats.n_inner_steps - st.stats.n_inner_steps
+            )
+        with phase("observe"):
+            if self._stats and splane is not None:
+                # per-window occupancy: events each host executed this
+                # window (hosts that ran nothing contribute no sample)
+                occ = stats.n_executed - st.stats.n_executed
+                splane = splane.observe("occ", occ, occ > 0)
         return dataclasses.replace(
             st,
             queues=q,
@@ -1831,61 +1862,64 @@ class Engine:
              splane) = carry
             q, xchg = self._xchg_deliver(q, xchg, host0)
 
-            # 1. frontier dump into staging — identical to the chained
-            # drain (same prefix clear, same optional burst fold)
-            bvalid = q.time[:, :b] < window_end
-            ndump = jnp.sum(bvalid, axis=1, dtype=jnp.int32)
-            if self._stats and splane is not None:
-                # same pre-clear observation point as the chained drain,
-                # so qfill histograms are bit-identical across contracts
-                splane = splane.observe(
-                    "qfill",
-                    jnp.sum(q.time != TIME_INVALID, axis=1,
-                            dtype=jnp.int64),
-                    ndump > 0,
+            with phase("pop"):
+                # 1. frontier dump into staging — identical to the chained
+                # drain (same prefix clear, same optional burst fold)
+                bvalid = q.time[:, :b] < window_end
+                ndump = jnp.sum(bvalid, axis=1, dtype=jnp.int32)
+                with phase("observe"):
+                    if self._stats and splane is not None:
+                        # same pre-clear observation point as the chained drain,
+                        # so qfill histograms are bit-identical across contracts
+                        splane = splane.observe(
+                            "qfill",
+                            jnp.sum(q.time != TIME_INVALID, axis=1,
+                                    dtype=jnp.int64),
+                            ndump > 0,
+                        )
+                pad = ((0, 0), (0, sw - b))
+                stage = Events(
+                    time=jnp.pad(
+                        jnp.where(bvalid, q.time[:, :b], TIME_INVALID),
+                        pad, constant_values=TIME_INVALID,
+                    ),
+                    dst=jnp.pad(jnp.broadcast_to(gids[:, None], (h, b)), pad),
+                    src=jnp.pad(q.src[:, :b], pad),
+                    seq=jnp.pad(q.seq[:, :b], pad),
+                    kind=jnp.pad(q.kind[:, :b], pad),
+                    args=jnp.pad(q.args[:, :b], (*pad, (0, 0))),
                 )
-            pad = ((0, 0), (0, sw - b))
-            stage = Events(
-                time=jnp.pad(
-                    jnp.where(bvalid, q.time[:, :b], TIME_INVALID),
-                    pad, constant_values=TIME_INVALID,
-                ),
-                dst=jnp.pad(jnp.broadcast_to(gids[:, None], (h, b)), pad),
-                src=jnp.pad(q.src[:, :b], pad),
-                seq=jnp.pad(q.seq[:, :b], pad),
-                kind=jnp.pad(q.kind[:, :b], pad),
-                args=jnp.pad(q.args[:, :b], (*pad, (0, 0))),
-            )
-            cleared = jnp.arange(c, dtype=jnp.int32)[None, :] < ndump[:, None]
-            q = dataclasses.replace(
-                q, time=jnp.where(cleared, TIME_INVALID, q.time)
-            )
-            if cfg.burst is not None:
-                stage = self._burst_fold(stage)
+                cleared = jnp.arange(c, dtype=jnp.int32)[None, :] < ndump[:, None]
+                q = dataclasses.replace(
+                    q, time=jnp.where(cleared, TIME_INVALID, q.time)
+                )
+                if cfg.burst is not None:
+                    stage = self._burst_fold(stage)
 
-            # queue-head guard — identical to the chained drain
-            headsel = (
-                jnp.arange(c, dtype=jnp.int32)[None, :] == ndump[:, None]
-            )
-            qh_t = jnp.min(jnp.where(headsel, q.time, i64max), axis=1)
-            qh_ss = jnp.min(
-                jnp.where(
-                    headsel & (q.time != TIME_INVALID),
-                    pack_srcseq(q.src, q.seq), i64max,
-                ),
-                axis=1,
-            )
+                # queue-head guard — identical to the chained drain
+                headsel = (
+                    jnp.arange(c, dtype=jnp.int32)[None, :] == ndump[:, None]
+                )
+                qh_t = jnp.min(jnp.where(headsel, q.time, i64max), axis=1)
+                qh_ss = jnp.min(
+                    jnp.where(
+                        headsel & (q.time != TIME_INVALID),
+                        pack_srcseq(q.src, q.seq), i64max,
+                    ),
+                    axis=1,
+                )
 
             def precede_q(ev_t, ev_ss):
                 return (ev_t < qh_t) | ((ev_t == qh_t) & (ev_ss < qh_ss))
 
             def can_run(sm, cpu_free):
-                ev, mss, _oh, cnt = sm
-                mt = ev.time
-                eff = jnp.maximum(mt, cpu_free) if self._cpu_enabled else mt
-                return jnp.any(
-                    (eff < window_end) & precede_q(mt, mss) & (cnt + k <= sw)
-                )
+                with phase("select"):
+                    ev, mss, _oh, cnt = sm
+                    mt = ev.time
+                    eff = jnp.maximum(mt, cpu_free) if self._cpu_enabled else mt
+                    return jnp.any(
+                        (eff < window_end) & precede_q(mt, mss) & (cnt + k <= sw)
+                    )
 
             # 2. rounds: sort once, execute a run, bookkeep once
             def round_cond(rc):
@@ -1894,24 +1928,25 @@ class Engine:
             def round_body(rc):
                 (_, stage, hosts, src_seq, exec_cnt, stats, cpu_free,
                  trace, splane) = rc
-                skey = pack_srcseq(stage.src, stage.seq)
-                t2, ss2, dst2, src2, seq2, kind2, *acols = jax.lax.sort(
-                    (stage.time, skey, stage.dst, stage.src, stage.seq,
-                     stage.kind,
-                     *[stage.args[:, :, i] for i in range(cfg.n_args)]),
-                    dimension=1, num_keys=2,
-                )
-                args2 = jnp.stack(acols, axis=-1)
-                cnt0 = jnp.sum(
-                    t2 != TIME_INVALID, axis=1, dtype=jnp.int32
-                )
-                t0 = t2[:, 0]
-                kind0 = kind2[:, 0]
-                if fk is not None:
-                    allowed0 = jnp.zeros((h,), bool)
-                    for kk in fk:
-                        allowed0 = allowed0 | (kind0 == kk)
-                uidx = jnp.arange(u, dtype=jnp.int32)
+                with phase("select"):
+                    skey = pack_srcseq(stage.src, stage.seq)
+                    t2, ss2, dst2, src2, seq2, kind2, *acols = jax.lax.sort(
+                        (stage.time, skey, stage.dst, stage.src, stage.seq,
+                         stage.kind,
+                         *[stage.args[:, :, i] for i in range(cfg.n_args)]),
+                        dimension=1, num_keys=2,
+                    )
+                    args2 = jnp.stack(acols, axis=-1)
+                    cnt0 = jnp.sum(
+                        t2 != TIME_INVALID, axis=1, dtype=jnp.int32
+                    )
+                    t0 = t2[:, 0]
+                    kind0 = kind2[:, 0]
+                    if fk is not None:
+                        allowed0 = jnp.zeros((h,), bool)
+                        for kk in fk:
+                            allowed0 = allowed0 | (kind0 == kk)
+                    uidx = jnp.arange(u, dtype=jnp.int32)
 
                 def pos_cond(pc):
                     return pc[0]
@@ -1919,177 +1954,187 @@ class Engine:
                 def pos_body(pc):
                     (_, j, still, hosts, src_seq, exec_cnt, stats,
                      cpu_free, cnt, nact, outbuf, trbuf, splane) = pc
-                    col = lambda a: jax.lax.dynamic_index_in_dim(
-                        a, j, axis=1, keepdims=False
-                    )
-                    ev_t = col(t2)
-                    ev_ss = col(ss2)
-                    e_src = col(src2)
-                    e_seq = col(seq2)
-                    e_kind = col(kind2)
-                    e_args = col(args2)
-                    eff_t = (
-                        jnp.maximum(ev_t, cpu_free)
-                        if self._cpu_enabled else ev_t
-                    )
-                    member = (ev_t == t0) & (e_kind == kind0)
-                    if fk is not None:
-                        member = member & (allowed0 | (j == 0))
-                    active = (
-                        still & member
-                        & (ev_t != TIME_INVALID)
-                        & (eff_t < window_end)
-                        & precede_q(ev_t, ev_ss)
-                        & (cnt + k <= sw)
-                    )
-                    if self._f_crash:
-                        alv = self._alive_at(al_sh, eff_t)
-                        runm = active & alv
+                    with phase("select"):
+                        col = lambda a: jax.lax.dynamic_index_in_dim(
+                            a, j, axis=1, keepdims=False
+                        )
+                        ev_t = col(t2)
+                        ev_ss = col(ss2)
+                        e_src = col(src2)
+                        e_seq = col(seq2)
+                        e_kind = col(kind2)
+                        e_args = col(args2)
+                        eff_t = (
+                            jnp.maximum(ev_t, cpu_free)
+                            if self._cpu_enabled else ev_t
+                        )
+                        member = (ev_t == t0) & (e_kind == kind0)
+                        if fk is not None:
+                            member = member & (allowed0 | (j == 0))
+                        active = (
+                            still & member
+                            & (ev_t != TIME_INVALID)
+                            & (eff_t < window_end)
+                            & precede_q(ev_t, ev_ss)
+                            & (cnt + k <= sw)
+                        )
+                        if self._f_crash:
+                            alv = self._alive_at(al_sh, eff_t)
+                            runm = active & alv
+                            stats = dataclasses.replace(
+                                stats,
+                                n_quarantined=stats.n_quarantined
+                                + (active & ~alv),
+                            )
+                        else:
+                            runm = active
+                        ev = Events(
+                            time=jnp.where(runm, eff_t, TIME_INVALID),
+                            dst=gids, src=e_src, seq=e_seq, kind=e_kind,
+                            args=e_args,
+                        )
+                    with phase("handler"):
+                        hkeys, rkeys = srng.event_keys(
+                            self._base_key, gids, exec_cnt
+                        )
+                        hosts2, emit = jax.vmap(per_host)(hosts, ev, hkeys)
+                        hosts = _select_rows(runm, hosts2, hosts)
+                        emask = emit.mask & runm[:, None]
+                    with phase("route"):
+                        inc = emask.astype(jnp.int32)
+                        within = jnp.cumsum(inc, axis=1) - inc
+                        seq = src_seq[:, None] + within
+                        src_seq = src_seq + jnp.sum(inc, axis=1, dtype=jnp.int32)
+                        out, final_mask, dropped, fdropped, _t, _is_local = (
+                            self._route(
+                                emit, ev.time, gids, window_end, rkeys, emask,
+                                seq,
+                            )
+                        )
+                    with phase("observe"):
+                        if self._stats and splane is not None:
+                            # same observation as _execute_step's, so the
+                            # wait/net histograms are bit-identical to the
+                            # chained drain's
+                            delta = _t - ev.time[:, None]
+                            splane = splane.observe("wait", delta, final_mask)
+                            splane = splane.observe(
+                                "net", delta, final_mask & ~_is_local
+                            )
+                    with phase("append"):
+                        if self._cpu_enabled:
+                            ev_cost = _kind_cost(cpu_cost, ev.kind)
+                            if cfg.burst is not None:
+                                bkind, _sq, blen = cfg.burst[:3]
+                                lw = ev.args[:, blen]
+                                nseg = jnp.where(
+                                    (lw & BURST_LEN_MASK) > 0,
+                                    jnp.maximum(lw >> BURST_NSEG_SHIFT, 1), 1,
+                                )
+                                ev_cost = ev_cost * jnp.where(
+                                    ev.kind == bkind,
+                                    nseg.astype(ev_cost.dtype), 1,
+                                )
+                            cpu_free = jnp.where(
+                                runm & (ev_cost > 0), eff_t + ev_cost, cpu_free
+                            )
+                    with phase("handler"):
+                        exec_cnt = exec_cnt + runm.astype(jnp.int32)
                         stats = dataclasses.replace(
                             stats,
-                            n_quarantined=stats.n_quarantined
-                            + (active & ~alv),
+                            n_executed=stats.n_executed + runm,
+                            n_emitted=stats.n_emitted
+                            + jnp.sum(inc, axis=1, dtype=jnp.int64),
+                            n_net_dropped=stats.n_net_dropped
+                            + jnp.sum(dropped, axis=1, dtype=jnp.int64),
+                            n_fault_dropped=stats.n_fault_dropped
+                            + jnp.sum(fdropped, axis=1, dtype=jnp.int64),
+                            n_by_kind=stats.n_by_kind + (
+                                jax.nn.one_hot(
+                                    jnp.clip(
+                                        ev.kind, 0, len(self.handlers) - 1
+                                    ),
+                                    len(self.handlers), dtype=jnp.int64,
+                                )
+                                * runm[:, None]
+                            ),
                         )
-                    else:
-                        runm = active
-                    ev = Events(
-                        time=jnp.where(runm, eff_t, TIME_INVALID),
-                        dst=gids, src=e_src, seq=e_seq, kind=e_kind,
-                        args=e_args,
-                    )
-                    hkeys, rkeys = srng.event_keys(
-                        self._base_key, gids, exec_cnt
-                    )
-                    hosts2, emit = jax.vmap(per_host)(hosts, ev, hkeys)
-                    hosts = _select_rows(runm, hosts2, hosts)
-                    emask = emit.mask & runm[:, None]
-                    inc = emask.astype(jnp.int32)
-                    within = jnp.cumsum(inc, axis=1) - inc
-                    seq = src_seq[:, None] + within
-                    src_seq = src_seq + jnp.sum(inc, axis=1, dtype=jnp.int32)
-                    out, final_mask, dropped, fdropped, _t, _is_local = (
-                        self._route(
-                            emit, ev.time, gids, window_end, rkeys, emask,
-                            seq,
+                    with phase("append"):
+                        cnt = (
+                            cnt - active.astype(jnp.int32)
+                            + jnp.sum(final_mask, axis=1, dtype=jnp.int32)
                         )
-                    )
-                    if self._stats and splane is not None:
-                        # same observation as _execute_step's, so the
-                        # wait/net histograms are bit-identical to the
-                        # chained drain's
-                        delta = _t - ev.time[:, None]
-                        splane = splane.observe("wait", delta, final_mask)
-                        splane = splane.observe(
-                            "net", delta, final_mask & ~_is_local
-                        )
-                    if self._cpu_enabled:
-                        ev_cost = _kind_cost(cpu_cost, ev.kind)
-                        if cfg.burst is not None:
-                            bkind, _sq, blen = cfg.burst[:3]
-                            lw = ev.args[:, blen]
-                            nseg = jnp.where(
-                                (lw & BURST_LEN_MASK) > 0,
-                                jnp.maximum(lw >> BURST_NSEG_SHIFT, 1), 1,
+                        nact = nact + active.astype(jnp.int32)
+
+                        def buf_put(buf, v):
+                            m = (uidx == j).reshape(
+                                (1, u) + (1,) * (buf.ndim - 2)
                             )
-                            ev_cost = ev_cost * jnp.where(
-                                ev.kind == bkind,
-                                nseg.astype(ev_cost.dtype), 1,
-                            )
-                        cpu_free = jnp.where(
-                            runm & (ev_cost > 0), eff_t + ev_cost, cpu_free
+                            return jnp.where(m, v[:, None], buf)
+
+                        outbuf = Events(
+                            time=buf_put(outbuf.time, out.time),
+                            dst=buf_put(outbuf.dst, out.dst),
+                            src=buf_put(outbuf.src, out.src),
+                            seq=buf_put(outbuf.seq, out.seq),
+                            kind=buf_put(outbuf.kind, out.kind),
+                            args=buf_put(outbuf.args, out.args),
                         )
-                    exec_cnt = exec_cnt + runm.astype(jnp.int32)
-                    stats = dataclasses.replace(
-                        stats,
-                        n_executed=stats.n_executed + runm,
-                        n_emitted=stats.n_emitted
-                        + jnp.sum(inc, axis=1, dtype=jnp.int64),
-                        n_net_dropped=stats.n_net_dropped
-                        + jnp.sum(dropped, axis=1, dtype=jnp.int64),
-                        n_fault_dropped=stats.n_fault_dropped
-                        + jnp.sum(fdropped, axis=1, dtype=jnp.int64),
-                        n_by_kind=stats.n_by_kind + (
-                            jax.nn.one_hot(
-                                jnp.clip(
-                                    ev.kind, 0, len(self.handlers) - 1
+                    with phase("observe"):
+                        if use_tr:
+                            ecol = lambda a: a[:, None]
+                            op_send = jnp.where(
+                                dropped, OP_DROP,
+                                jnp.where(fdropped, OP_FDROP, OP_SEND),
+                            ).astype(jnp.int32)
+                            row = (
+                                jnp.concatenate(
+                                    [ecol(ev.time),
+                                     jnp.broadcast_to(ecol(ev.time), (h, k))],
+                                    1,
                                 ),
-                                len(self.handlers), dtype=jnp.int64,
+                                jnp.concatenate([ecol(ev.src), out.src], 1),
+                                jnp.concatenate([ecol(ev.dst), out.dst], 1),
+                                jnp.concatenate([ecol(ev.kind), out.kind], 1),
+                                jnp.concatenate(
+                                    [ev.args[:, la:la + 1],
+                                     out.args[:, :, la]], 1
+                                ),
+                                jnp.concatenate([ecol(ev.seq), out.seq], 1),
+                                jnp.concatenate(
+                                    [jnp.full((h, 1), OP_EXEC, jnp.int32),
+                                     op_send], 1
+                                ),
+                                jnp.concatenate(
+                                    [ecol(runm), emask & ~_is_local], 1
+                                ),
                             )
-                            * runm[:, None]
-                        ),
-                    )
-                    cnt = (
-                        cnt - active.astype(jnp.int32)
-                        + jnp.sum(final_mask, axis=1, dtype=jnp.int32)
-                    )
-                    nact = nact + active.astype(jnp.int32)
-
-                    def buf_put(buf, v):
-                        m = (uidx == j).reshape(
-                            (1, u) + (1,) * (buf.ndim - 2)
-                        )
-                        return jnp.where(m, v[:, None], buf)
-
-                    outbuf = Events(
-                        time=buf_put(outbuf.time, out.time),
-                        dst=buf_put(outbuf.dst, out.dst),
-                        src=buf_put(outbuf.src, out.src),
-                        seq=buf_put(outbuf.seq, out.seq),
-                        kind=buf_put(outbuf.kind, out.kind),
-                        args=buf_put(outbuf.args, out.args),
-                    )
-                    if use_tr:
-                        ecol = lambda a: a[:, None]
-                        op_send = jnp.where(
-                            dropped, OP_DROP,
-                            jnp.where(fdropped, OP_FDROP, OP_SEND),
-                        ).astype(jnp.int32)
-                        row = (
-                            jnp.concatenate(
-                                [ecol(ev.time),
-                                 jnp.broadcast_to(ecol(ev.time), (h, k))],
-                                1,
-                            ),
-                            jnp.concatenate([ecol(ev.src), out.src], 1),
-                            jnp.concatenate([ecol(ev.dst), out.dst], 1),
-                            jnp.concatenate([ecol(ev.kind), out.kind], 1),
-                            jnp.concatenate(
-                                [ev.args[:, la:la + 1],
-                                 out.args[:, :, la]], 1
-                            ),
-                            jnp.concatenate([ecol(ev.seq), out.seq], 1),
-                            jnp.concatenate(
-                                [jnp.full((h, 1), OP_EXEC, jnp.int32),
-                                 op_send], 1
-                            ),
-                            jnp.concatenate(
-                                [ecol(runm), emask & ~_is_local], 1
-                            ),
-                        )
-                        trbuf = tuple(
-                            buf_put(bb, vv) for bb, vv in zip(trbuf, row)
-                        )
-                    go = jnp.any(active) & (j + 1 < u)
+                            trbuf = tuple(
+                                buf_put(bb, vv) for bb, vv in zip(trbuf, row)
+                            )
+                    with phase("select"):
+                        go = jnp.any(active) & (j + 1 < u)
                     return (go, j + 1, active, hosts, src_seq, exec_cnt,
                             stats, cpu_free, cnt, nact, outbuf, trbuf,
                             splane)
 
-                outbuf0 = Events(
-                    time=jnp.full((h, u, k), TIME_INVALID, jnp.int64),
-                    dst=jnp.zeros((h, u, k), jnp.int32),
-                    src=jnp.zeros((h, u, k), jnp.int32),
-                    seq=jnp.zeros((h, u, k), jnp.int32),
-                    kind=jnp.zeros((h, u, k), jnp.int32),
-                    args=jnp.zeros((h, u, k, cfg.n_args), jnp.int32),
-                )
-                trbuf0 = None
-                if use_tr:
-                    z32 = jnp.zeros((h, u, 1 + k), jnp.int32)
-                    trbuf0 = (
-                        jnp.zeros((h, u, 1 + k), jnp.int64),
-                        z32, z32, z32, z32, z32, z32,
-                        jnp.zeros((h, u, 1 + k), bool),
+                with phase("append"):
+                    outbuf0 = Events(
+                        time=jnp.full((h, u, k), TIME_INVALID, jnp.int64),
+                        dst=jnp.zeros((h, u, k), jnp.int32),
+                        src=jnp.zeros((h, u, k), jnp.int32),
+                        seq=jnp.zeros((h, u, k), jnp.int32),
+                        kind=jnp.zeros((h, u, k), jnp.int32),
+                        args=jnp.zeros((h, u, k, cfg.n_args), jnp.int32),
                     )
+                    trbuf0 = None
+                    if use_tr:
+                        z32 = jnp.zeros((h, u, 1 + k), jnp.int32)
+                        trbuf0 = (
+                            jnp.zeros((h, u, 1 + k), jnp.int64),
+                            z32, z32, z32, z32, z32, z32,
+                            jnp.zeros((h, u, 1 + k), bool),
+                        )
                 (_, jn, _still, hosts, src_seq, exec_cnt, stats, cpu_free,
                  _cnt, nact, outbuf, trbuf, splane) = jax.lax.while_loop(
                     pos_cond, pos_body,
@@ -2098,50 +2143,53 @@ class Engine:
                      stats, cpu_free, cnt0, jnp.zeros((h,), jnp.int32),
                      outbuf0, trbuf0, splane),
                 )
-                if self._stats and splane is not None:
-                    # frontier run length: how many positions each host
-                    # actually executed this round — the quantity that
-                    # decides whether the per-round sort amortizes
-                    splane = splane.observe("runlen", nact, nact > 0)
+                with phase("observe"):
+                    if self._stats and splane is not None:
+                        # frontier run length: how many positions each host
+                        # actually executed this round — the quantity that
+                        # decides whether the per-round sort amortizes
+                        splane = splane.observe("runlen", nact, nact > 0)
 
-                # 3. per-round bookkeeping: prefix-clear the executed
-                # columns, one deferred append of every position's routed
-                # emits (headroom is guaranteed — the per-position gate
-                # kept cnt + K <= SW with the exact chained accounting),
-                # one wide trace append in chained record order
-                colmask = (
-                    jnp.arange(sw, dtype=jnp.int32)[None, :] < nact[:, None]
-                )
-                stage = Events(
-                    time=jnp.where(colmask, TIME_INVALID, t2),
-                    dst=dst2, src=src2, seq=seq2, kind=kind2, args=args2,
-                )
-                stage = self._stage_append(
-                    stage,
-                    Events(
-                        time=outbuf.time.reshape(h, u * k),
-                        dst=outbuf.dst.reshape(h, u * k),
-                        src=outbuf.src.reshape(h, u * k),
-                        seq=outbuf.seq.reshape(h, u * k),
-                        kind=outbuf.kind.reshape(h, u * k),
-                        args=outbuf.args.reshape(h, u * k, cfg.n_args),
-                    ),
-                )
-                if use_tr:
-                    w = u * (1 + k)
-                    rs = lambda a: a.reshape(h, w)
-                    trace = trace_append(
-                        trace, cfg.trace,
-                        time=rs(trbuf[0]), src=rs(trbuf[1]),
-                        dst=rs(trbuf[2]), kind=rs(trbuf[3]),
-                        plen=rs(trbuf[4]), seq=rs(trbuf[5]),
-                        op=rs(trbuf[6]), mask=rs(trbuf[7]),
+                with phase("append"):
+                    # 3. per-round bookkeeping: prefix-clear the executed
+                    # columns, one deferred append of every position's routed
+                    # emits (headroom is guaranteed — the per-position gate
+                    # kept cnt + K <= SW with the exact chained accounting),
+                    # one wide trace append in chained record order
+                    colmask = (
+                        jnp.arange(sw, dtype=jnp.int32)[None, :] < nact[:, None]
                     )
-                stats = dataclasses.replace(
-                    stats,
-                    n_inner_steps=stats.n_inner_steps
-                    + jn.astype(jnp.int64),
-                )
+                    stage = Events(
+                        time=jnp.where(colmask, TIME_INVALID, t2),
+                        dst=dst2, src=src2, seq=seq2, kind=kind2, args=args2,
+                    )
+                    stage = self._stage_append(
+                        stage,
+                        Events(
+                            time=outbuf.time.reshape(h, u * k),
+                            dst=outbuf.dst.reshape(h, u * k),
+                            src=outbuf.src.reshape(h, u * k),
+                            seq=outbuf.seq.reshape(h, u * k),
+                            kind=outbuf.kind.reshape(h, u * k),
+                            args=outbuf.args.reshape(h, u * k, cfg.n_args),
+                        ),
+                    )
+                    with phase("observe"):
+                        if use_tr:
+                            w = u * (1 + k)
+                            rs = lambda a: a.reshape(h, w)
+                            trace = trace_append(
+                                trace, cfg.trace,
+                                time=rs(trbuf[0]), src=rs(trbuf[1]),
+                                dst=rs(trbuf[2]), kind=rs(trbuf[3]),
+                                plen=rs(trbuf[4]), seq=rs(trbuf[5]),
+                                op=rs(trbuf[6]), mask=rs(trbuf[7]),
+                            )
+                    stats = dataclasses.replace(
+                        stats,
+                        n_inner_steps=stats.n_inner_steps
+                        + jn.astype(jnp.int64),
+                    )
                 sm2 = self._stage_min(stage)
                 return (can_run(sm2, cpu_free), stage, hosts, src_seq,
                         exec_cnt, stats, cpu_free, trace, splane)
@@ -2154,55 +2202,56 @@ class Engine:
                  stats, cpu_free, trace, splane),
             )
 
-            # 4. flush staging leftovers — identical to the chained drain
-            skey = pack_srcseq(stage.src, stage.seq)
-            t2, _ss2, dst2, src2, seq2, kind2, *acols = jax.lax.sort(
-                (stage.time, skey, stage.dst, stage.src, stage.seq,
-                 stage.kind,
-                 *[stage.args[:, :, i] for i in range(cfg.n_args)]),
-                dimension=1, num_keys=2,
-            )
-            stage = Events(
-                time=t2, dst=dst2, src=src2, seq=seq2, kind=kind2,
-                args=jnp.stack(acols, axis=-1),
-            )
-            w1 = min(sw, 16)
-            maxcnt = jnp.max(
-                jnp.sum(stage.time != TIME_INVALID, axis=1, dtype=jnp.int32)
-            )
-
-            def push_narrow(args):
-                q, xchg, stage = args
-                sl = jax.tree.map(lambda a: a[:, :w1], stage)
-                flat = sl.flatten()
-                return self._exchange_push(
-                    q, xchg, flat, flat.time != TIME_INVALID, host0
+            with phase("flush"):
+                # 4. flush staging leftovers — identical to the chained drain
+                skey = pack_srcseq(stage.src, stage.seq)
+                t2, _ss2, dst2, src2, seq2, kind2, *acols = jax.lax.sort(
+                    (stage.time, skey, stage.dst, stage.src, stage.seq,
+                     stage.kind,
+                     *[stage.args[:, :, i] for i in range(cfg.n_args)]),
+                    dimension=1, num_keys=2,
+                )
+                stage = Events(
+                    time=t2, dst=dst2, src=src2, seq=seq2, kind=kind2,
+                    args=jnp.stack(acols, axis=-1),
+                )
+                w1 = min(sw, 16)
+                maxcnt = jnp.max(
+                    jnp.sum(stage.time != TIME_INVALID, axis=1, dtype=jnp.int32)
                 )
 
-            def push_full(args):
-                q, xchg, stage = args
-                flat = stage.flatten()
-                return self._exchange_push(
-                    q, xchg, flat, flat.time != TIME_INVALID, host0
-                )
+                def push_narrow(args):
+                    q, xchg, stage = args
+                    sl = jax.tree.map(lambda a: a[:, :w1], stage)
+                    flat = sl.flatten()
+                    return self._exchange_push(
+                        q, xchg, flat, flat.time != TIME_INVALID, host0
+                    )
 
-            if w1 == sw:
-                q, xchg, xr, nc = push_full((q, xchg, stage))
-            elif cfg.axis_name is not None:
-                go_wide = self._gany(maxcnt > w1)
-                q, xchg, xr, nc = jax.lax.cond(
-                    go_wide, push_full, push_narrow, (q, xchg, stage)
+                def push_full(args):
+                    q, xchg, stage = args
+                    flat = stage.flatten()
+                    return self._exchange_push(
+                        q, xchg, flat, flat.time != TIME_INVALID, host0
+                    )
+
+                if w1 == sw:
+                    q, xchg, xr, nc = push_full((q, xchg, stage))
+                elif cfg.axis_name is not None:
+                    go_wide = self._gany(maxcnt > w1)
+                    q, xchg, xr, nc = jax.lax.cond(
+                        go_wide, push_full, push_narrow, (q, xchg, stage)
+                    )
+                else:
+                    q, xchg, xr, nc = jax.lax.cond(
+                        maxcnt > w1, push_full, push_narrow, (q, xchg, stage)
+                    )
+                stats = dataclasses.replace(
+                    stats,
+                    n_sweeps=stats.n_sweeps + 1,
+                    n_xchg_rounds=stats.n_xchg_rounds + xr,
+                    n_cross_shard=stats.n_cross_shard + nc,
                 )
-            else:
-                q, xchg, xr, nc = jax.lax.cond(
-                    maxcnt > w1, push_full, push_narrow, (q, xchg, stage)
-                )
-            stats = dataclasses.replace(
-                stats,
-                n_sweeps=stats.n_sweeps + 1,
-                n_xchg_rounds=stats.n_xchg_rounds + xr,
-                n_cross_shard=stats.n_cross_shard + nc,
-            )
             more = self._drain_flag(q, cpu_free, window_end)
             return (more, q, xchg, hosts, src_seq, exec_cnt, stats,
                     cpu_free, trace, splane)
@@ -2214,12 +2263,14 @@ class Engine:
          trace, splane) = jax.lax.while_loop(outer_cond, outer_body, carry)
         if self._cpu_enabled:
             q, xchg = self._xchg_deliver(q, xchg, host0)
-        inner = st.stats.n_inner_steps + self._gsum(
-            stats.n_inner_steps - st.stats.n_inner_steps
-        )
-        if self._stats and splane is not None:
-            occ = stats.n_executed - st.stats.n_executed
-            splane = splane.observe("occ", occ, occ > 0)
+        with phase("barrier"):
+            inner = st.stats.n_inner_steps + self._gsum(
+                stats.n_inner_steps - st.stats.n_inner_steps
+            )
+        with phase("observe"):
+            if self._stats and splane is not None:
+                occ = stats.n_executed - st.stats.n_executed
+                splane = splane.observe("occ", occ, occ > 0)
         return dataclasses.replace(
             st,
             queues=q,
@@ -2235,6 +2286,7 @@ class Engine:
             splane=splane,
         )
 
+    @phase("barrier")
     def _next_time(self, st: EngineState) -> jax.Array:
         """Global earliest executable time (one reduction + one pmin):
         per host the earliest pending event, deferred to when its virtual
@@ -2253,6 +2305,7 @@ class Engine:
             m = jnp.minimum(m, st.xchg.sent_min[0])
         return self._gmin(m)
 
+    @phase("fault")
     def _apply_fault_epoch(self, st: EngineState, nxt, host0) -> EngineState:
         """Apply fault-schedule transitions entered since the last window.
 
@@ -2347,7 +2400,8 @@ class Engine:
         """
         if window is None:
             window = self.cfg.lookahead
-        window_end = jnp.minimum(nxt + window, stop)
+        with phase("barrier"):
+            window_end = jnp.minimum(nxt + window, stop)
         if st.xchg is not None:
             # open of window k: merge window k-1's in-flight exchange.
             # Must precede the fault-epoch wipe (an immediate push would

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from shadow_tpu.core import merge_pallas
+from shadow_tpu.core.phases import phase
 from shadow_tpu.core.timebase import TIME_INVALID
 
 # Number of i32 payload words carried by every event. The reference carries a
@@ -296,6 +297,7 @@ def queue_pop(
     return q2, ev, active
 
 
+@phase("merge")
 def queue_push(
     q: EventQueue, ev: Events, mask: jax.Array, host0,
     kernel: str = "xla",

@@ -11,6 +11,13 @@ the Chrome wall-time tracks), and samples per-window occupancy —
 events per sweep, queue fill, stall margin — from engine summary
 deltas.
 
+Every phase also opens a `jax.profiler.TraceAnnotation` of its name,
+with or without a `WindowProfiler` (`host_phase`), so an XLA profiler
+capture (`--xprof`) shows each phase on the host's timeline, on the same
+clock as the device ops: a gap on the device lines up with the host
+phase that held it up. The annotation costs next to nothing while no
+capture runs.
+
 Wall-clock numbers are nondeterministic by nature; everything this
 module emits is either confined to the `"profile"` summary key or the
 `[supervisor]`-style heartbeat fields, both of which
@@ -33,6 +40,13 @@ from contextlib import contextmanager
 PHASES = ("build", "step", "drain", "pump", "checkpoint")
 
 
+def host_phase(name: str):
+    """The phase's span on the profiler's host timeline, untimed."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)
+
+
 class WindowProfiler:
     """Accumulates per-phase wall time + per-window occupancy samples."""
 
@@ -50,7 +64,8 @@ class WindowProfiler:
     def phase(self, name: str):
         t0 = time.perf_counter()
         try:
-            yield
+            with host_phase(name):
+                yield
         finally:
             dt = time.perf_counter() - t0
             a = self._agg.setdefault(

@@ -44,6 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from shadow_tpu.core.phases import phase
+
 NB = 64  # buckets per family
 _N_POWERS = NB - 1  # compare ladder 2^0 .. 2^62
 _POWERS = tuple(1 << i for i in range(_N_POWERS))
@@ -113,6 +115,7 @@ class StatPlane:
         s = jnp.zeros((n_hosts,), jnp.int64)
         return StatPlane(n, s, n, s, n, s, n, s, n, s)
 
+    @phase("observe")
     def observe(self, family: str, values: jax.Array,
                 mask: jax.Array) -> "StatPlane":
         """Fold a batch of samples into one family's histogram.

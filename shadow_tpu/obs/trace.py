@@ -56,6 +56,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from shadow_tpu.core.phases import phase
+
 # record classes
 OP_EXEC = 0   # event executed (row = executing host)
 OP_SEND = 1   # non-local emit routed onto the wire (row = source host)
@@ -100,6 +102,7 @@ class TraceRing:
         )
 
 
+@phase("observe")
 def trace_append(ring: TraceRing, cap: int, *, time, src, dst, kind, plen,
                  seq, op, mask) -> TraceRing:
     """Append a masked [H, M] record batch into each host's ring.

@@ -797,10 +797,7 @@ class ProcessTier:
                     )
                 comps.append((pid, COMP_TIMER, fd, int(n_exp if interval > 0 else 1), gen))
 
-            if self.sim.profiler is not None:
-                with self.sim.profiler.phase("pump"):
-                    reqs = self.rt.pump(now, comps)
-            else:
+            with self.sim.phase("pump"):
                 reqs = self.rt.pump(now, comps)
             st = self._inject(st, self._translate(reqs, now), now)
             if supervisor is not None:

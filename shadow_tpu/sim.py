@@ -306,12 +306,10 @@ class Simulation:
             return out
         if self._jit_run is None:
             object.__setattr__(self, "_jit_run", self._wrap(self.engine.run))
-        if self.profiler is not None:
-            with self.profiler.phase("step"):
-                out = self._jit_run(st, stop)
-                out.now.block_until_ready()  # shadowlint: no-deadline=library run() path; the supervised CLI uses HeartbeatHarvest
-        else:
+        with self.phase("step"):
             out = self._jit_run(st, stop)
+            if self.profiler is not None:
+                out.now.block_until_ready()  # shadowlint: no-deadline=library run() path; the supervised CLI uses HeartbeatHarvest
         out = self._note_owned(out)
         if self.overflow == "strict" or self.strict_overflow:
             drops = int(jax.device_get(out.queues.drops.sum()))  # shadowlint: no-deadline=library run() path; the supervised CLI uses HeartbeatHarvest
@@ -434,12 +432,20 @@ class Simulation:
             self._ensure_step_w()
             args = (state, stop, jnp.int64(window_ns))
             jit_step = self._jit_step_w
-        if self.profiler is not None:
-            with self.profiler.phase("step"):
-                out = jit_step(*args)
+        with self.phase("step"):
+            out = jit_step(*args)
+            if self.profiler is not None:
                 out.now.block_until_ready()  # shadowlint: no-deadline=library run() path; the supervised CLI uses HeartbeatHarvest
-            return self._note_owned(out)
-        return self._note_owned(jit_step(*args))
+        return self._note_owned(out)
+
+    def phase(self, name: str):
+        """The profiler's timed phase, or without one only the phase's
+        span on the XLA profiler's timeline (obs.profiler.host_phase)."""
+        if self.profiler is not None:
+            return self.profiler.phase(name)
+        from shadow_tpu.obs.profiler import host_phase
+
+        return host_phase(name)
 
     def _ensure_step_w(self):
         """Build the traced-window step jit once (--window N / auto)."""

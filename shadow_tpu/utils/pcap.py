@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from shadow_tpu.core.phases import phase
+
 # record meta word layout ([H, R, 8] i32)
 M_SRC = 0
 M_DST = 1
@@ -91,6 +93,7 @@ class CaptureRing:
             enabled=enabled,
         )
 
+    @phase("observe")
     def append(self, now, src, dst, sport, dport, meta_word, length, seq,
                ack, verdict):
         """Append one record (scalar row context under vmap)."""
