@@ -227,9 +227,9 @@ def merge_vmem_report(h: int, hc: int, w: int, m: int, nw: int,
                       chips: Iterable[str] = CHIP_NAMES) -> dict:
     """VMEM working set of one fused-merge invocation (the gridless
     pallas_call holds every ref whole): tile-padded input + output
-    blocks double-buffered, plus the merge-path intermediates ([h, hc,
-    w] and [h, hc+w, w] compare/count planes, charged at i32 width —
-    TPU masks occupy full lanes). `fits`/`max_rows` per chip row."""
+    blocks double-buffered, plus the row sort's concatenated operands
+    (time, srcseq and each payload word as [h, hc+w] i64).
+    `fits`/`max_rows` per chip row."""
     ncol = hc + w
     i64, i32 = 8, 4
 
@@ -248,11 +248,7 @@ def merge_vmem_report(h: int, hc: int, w: int, m: int, nw: int,
             + _pb(c, (h, ncol), i64) * 2      # ot, oss
             + _pb(c, (h, ncol, nw), i64)      # opay
         )
-        mid_bytes = (
-            _pb(c, (h, hc, w), i32)           # le compare plane
-            + _pb(c, (h, ncol, w), i32)       # jb count plane
-            + _pb(c, (h, ncol, nw), i64)      # apay staging
-        )
+        mid_bytes = _pb(c, (h, ncol), i64) * (2 + nw)  # sort operands
         ws = io_bytes * DOUBLE_BUFFER + mid_bytes
         rec = {"working_set_bytes": ws}
         if c.vmem_bytes is not None:

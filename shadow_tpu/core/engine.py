@@ -340,7 +340,7 @@ class EngineConfig:
     # retransmit fires at the same byte position as unfolded.
     burst: tuple | None = None
     # Queue-merge kernel for queue_push (core.events): "xla" (default)
-    # lowers the densify + rotate + merge as plain XLA ops; "pallas"
+    # lowers the densify + row-sort merge as plain XLA ops; "pallas"
     # fuses them into one Pallas kernel call (core.merge_pallas), which
     # runs only interpreted on the CPU: Engine refuses it on any other
     # backend. The two are bit-identical by construction and pinned so

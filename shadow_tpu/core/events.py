@@ -11,18 +11,18 @@ form [H, C] device arrays. Rows maintain a **sorted invariant**: slots are
 ordered by the event key (time, src, seq) with empty slots
 (time == TIME_INVALID) at the end. That choice is TPU-motivated: XLA
 scatters with computed indices serialize on TPU (~ms for tens of
-thousands of updates), while flat `lax.sort` + gathers + row-wise merge
-networks are fast VPU work — so push is implemented as "group incoming
-events by destination via one flat sort, gather each host's contiguous
-run into a dense block, merge the block into the row with a stable
-merge-path network" with no scatter anywhere (see `queue_push` and
-core.merge_pallas), and pop-min / frontier extraction are free prefix
-reads of the sorted rows. Bounded capacity drops the
-*largest*-key events on overflow and accounts them in `drops` — or, when
-the queue carries a `SpillRing` (shadow_tpu.runtime.pressure), lands them
-in the per-host overflow ring instead so a host-side reservoir can
-harvest and re-insert them at window boundaries (lossless pressure
-handling; see docs/9-Queue-Pressure.md).
+thousands of updates), while flat and row-wise `lax.sort`s are fast —
+so push is implemented as "group incoming events by destination via one
+flat sort, gather each host's contiguous run into a dense block, merge
+the block into the row with one stable row sort" with no scatter
+anywhere (see `queue_push` and core.merge_pallas), and pop-min /
+frontier extraction are free prefix reads of the sorted rows. Bounded
+capacity drops the *largest*-key events on overflow and accounts them
+in `drops` — or, when the queue carries a `SpillRing`
+(shadow_tpu.runtime.pressure), lands them in the per-host overflow ring
+instead so a host-side reservoir can harvest and re-insert them at
+window boundaries (lossless pressure handling; see
+docs/9-Queue-Pressure.md).
 """
 
 from __future__ import annotations
@@ -43,8 +43,8 @@ from shadow_tpu.core.timebase import TIME_INVALID
 N_ARGS = 6
 
 # Common-round block width for queue_push (step 3 of its docstring): the
-# [H, MERGE_W] incoming block bounds the merge network's per-row compare
-# count, so it is sized to cover every per-destination per-sweep count a
+# [H, MERGE_W] incoming block bounds the width of the merge's row sort,
+# so it is sized to cover every per-destination per-sweep count a
 # steady-state workload produces (Poisson tails at typical loads put
 # P(count > 24) below 1e-8 per host); rarer bursts take the exact
 # full-width fallback round.
@@ -320,7 +320,7 @@ def queue_push(
 
     Scatter-free algorithm (XLA scatters with computed indices serialize
     on TPU; everything here is one flat sort plus gathers, searchsorted,
-    and a merge network — all budgeted by analysis/hlo_audit.py):
+    and a row sort — all budgeted by analysis/hlo_audit.py):
 
     1. One flat multi-key sort groups incoming events by destination in
        (time, src, seq) order. Grouping in key order means the per-row
@@ -350,24 +350,24 @@ def queue_push(
        with a second flat sort over [incoming | H*W fillers]; the
        gather replaces the dominant sort of the whole push at ~1/300
        of its cost on the current bench target.)
-    4. The block merges into the resident rows WITHOUT a row sort:
-       rows already hold the sorted invariant (module docstring) apart
-       from a cleared-empty prefix, so a rotation compacts each row's
-       prefix out in one gather, and a stable MERGE-PATH network
-       (broadcast compares + take_along_axis, core.merge_pallas) merges
-       the two sorted sequences exactly as `lax.sort` over their
-       concatenation would — ties resolve resident-first, matching the
-       stable sort it replaces. `kernel="pallas"` runs this densify +
-       rotate + merge fused as one Pallas kernel invocation
-       (interpret-mode off-TPU); `kernel="xla"` (default) runs the
-       identical arithmetic as plain XLA ops. The two are bit-identical
-       by construction and pinned so by test.
+    4. The block merges into the resident rows by ONE stable row-wise
+       `lax.sort` over [resident | block], keyed on (time, srcseq), the
+       packed payload words riding along as operands (core.merge_pallas).
+       Resident empties, including the cleared prefix the engine leaves
+       after executing a frontier, are made canonical first, so they
+       sort after every valid event and before the block's fillers; ties
+       resolve resident-first by stability. No computed-index gather
+       runs here: on the TPU a row sort is cheap and a gather along the
+       row is not. `kernel="pallas"` runs this densify + sort as one
+       Pallas kernel invocation (interpret-mode off-TPU); `kernel="xla"`
+       (default) runs the identical arithmetic as plain XLA ops. The two
+       are bit-identical by construction and pinned so by test.
     5. Truncating the merged row to capacity keeps the smallest keys;
        the cut tail plus the final round's rank overflow are counted as
-       drops (or spill to the ring). Empty slots in the kept region are
-       re-canonicalized (src = seq = kind = args = 0), which both keeps
-       rotation exact on the next push and restores the empties-last
-       invariant behind the engine's prefix-clear of executed events.
+       drops (or spill to the ring). The kept region's empty slots are
+       all resident empties, made canonical (src = seq = kind = args =
+       0) in step 4: the block's fillers sort after every one of the hc
+       resident slots, so none reaches the kept region.
 
     Round structure is TWO-LEVEL: the common round runs at a narrow W1
     (MERGE_W covers every per-destination count seen in steady state);
@@ -467,7 +467,7 @@ def queue_push(
                 ),
                 axis=-1,
             )  # [H, hc, NW]
-            # -- 4. fused densify + rotate + merge (see step 4 above)
+            # -- 4. densify + row sort (see step 4 above)
             body = (
                 merge_pallas.fused_merge
                 if kernel == "pallas"
@@ -517,11 +517,10 @@ def queue_push(
                     fill_hwm=spill.fill_hwm,
                 )
                 drops_add = lost
-            # -- 5. truncate + re-canonicalize kept empties
+            # -- 5. truncate to capacity
             keep_t = mt[:, :hc]
-            emp = keep_t == TIME_INVALID
-            new_src, new_seq = unpk(jnp.where(emp, 0, mss[:, :hc]))
-            pay_k = jnp.where(emp[:, :, None], 0, mpay[:, :hc, :])
+            new_src, new_seq = unpk(mss[:, :hc])
+            pay_k = mpay[:, :hc, :]
             words = unpack_words(
                 [pay_k[:, :, i] for i in range(pay_k.shape[-1])], nw
             )

@@ -1,15 +1,14 @@
-"""Fused queue-merge Pallas kernel: densify + merge in one pass.
+"""Fused queue-merge Pallas kernel: densify + row sort in one pass.
 
 `queue_push` (core.events) splits a push into a flat grouping sort, a
-gather densify, and a stable merge of each row's sorted incoming block
-into its sorted resident prefix. With ``kernel="xla"`` those last two
-stages lower as separate XLA ops — gathers and broadcast compares that
-each round-trip the hot columns through memory. This module fuses them
-into ONE Pallas kernel invocation per merge round: the kernel reads the
-flat grouped key arrays and the queue's hot columns once, densifies the
-per-destination runs by value-level gather, rotates each row's
-cleared-empty prefix out, computes stable merge-path positions, and
-writes the merged rows — a single pass over the hot columns.
+gather densify, and a merge of each row's sorted incoming block into its
+resident row. The merge is one stable row-wise `lax.sort` over
+[resident | block] keyed on (time, srcseq), with the payload words
+riding along as sort operands: the TPU sorts rows cheaply, while a
+computed-index gather along a row costs milliseconds per push. With
+``kernel="xla"`` the densify and the sort lower as separate XLA ops;
+this module also runs them as ONE Pallas kernel invocation per merge
+round.
 
 The arithmetic is element-for-element the same as the XLA path, so the
 two kernels are bit-identical on every input (pinned by
@@ -48,18 +47,24 @@ MOSAIC_REFUSAL = (
 
 
 def merge_body(qt, qss, qpay, st, sss, bpay, starts, cnt):
-    """The densify + rotate + merge arithmetic, shared verbatim by the
-    Pallas kernel body and the plain-XLA path (`queue_push` calls this
-    directly when kernel="xla"). Shapes: qt/qss [H, hc], qpay
-    [H, hc, nw], st/sss [m] flat grouped keys, bpay [H, w, nw],
-    starts/cnt [H]."""
+    """The densify + row-sort arithmetic, shared verbatim by the Pallas
+    kernel body and the plain-XLA path (`queue_push` calls this directly
+    when kernel="xla"). Shapes: qt/qss [H, hc], qpay [H, hc, nw], st/sss
+    [m] flat grouped keys, bpay [H, w, nw], starts/cnt [H]. Returns
+    (mt, mss, mpay) of width hc + w: each row of [resident | block]
+    sorted stably by (time, srcseq), so ties keep resident-first order.
+
+    One row-wise `lax.sort` does the merge: the TPU sorts rows cheaply,
+    while a computed-index gather along the minor axis costs
+    milliseconds per push.
+    """
     h, hc = qt.shape
     w = bpay.shape[1]
     m = st.shape[0]
 
     # densify: group g's admitted events sit at flat positions
     # starts[g] .. starts[g]+cnt[g]-1 in key order; masked lanes become
-    # fillers with the same key an empty-padded sort would produce
+    # fillers (i64max, i64max, 0) that sort after every resident empty
     lane = jnp.arange(w, dtype=jnp.int32)
     gidx = starts[:, None] + lane[None, :]
     okl = lane[None, :] < cnt[:, None]
@@ -67,53 +72,21 @@ def merge_body(qt, qss, qpay, st, sss, bpay, starts, cnt):
     bt = jnp.where(okl, st[gsafe], _I64MAX)
     bss = jnp.where(okl, sss[gsafe], _I64MAX)
 
-    # rotate the cleared-empty prefix to the tail: rows arrive as
-    # [empties x k | valid ascending | empties] (the engine's frontier
-    # prefix-clear), and every empty is canonical (t=INV, ss=0, pay=0)
+    # resident empties (anywhere in the row: the engine clears executed
+    # prefixes by time alone) become canonical (INV, 0, 0), so they sort
+    # after every valid event and before the fillers, in a fixed order
     inv = qt == TIME_INVALID
-    k = jnp.sum(jnp.cumprod(inv.astype(jnp.int32), axis=1), axis=1)
-    ridx = jnp.arange(hc, dtype=jnp.int32)[None, :] + k[:, None]
-    rin = ridx < hc
-    rsafe = jnp.minimum(ridx, hc - 1)
-    gat = lambda x, fill: jnp.where(
-        rin, jnp.take_along_axis(x, rsafe, axis=1), fill
-    )
-    at = gat(qt, _I64MAX)
-    ass = gat(qss, 0)
-    apay = jnp.where(
-        rin[:, :, None],
-        jnp.take_along_axis(qpay, rsafe[:, :, None], axis=1),
-        0,
-    )
+    qss = jnp.where(inv, 0, qss)
+    qpay = jnp.where(inv[:, :, None], 0, qpay)
 
-    # stable merge-path: A ([H, hc] sorted) + B ([H, w] sorted); ties
-    # place A first, matching lax.sort's stability over [A | B]
-    le = (at[:, :, None] < bt[:, None, :]) | (
-        (at[:, :, None] == bt[:, None, :])
-        & (ass[:, :, None] <= bss[:, None, :])
+    cat = lambda a, b: jnp.concatenate([a, b], axis=1)
+    out = jax.lax.sort(
+        (cat(qt, bt), cat(qss, bss))
+        + tuple(cat(qpay[..., i], bpay[..., i])
+                for i in range(qpay.shape[-1])),
+        dimension=1, num_keys=2, is_stable=True,
     )
-    pos_b = lane[None, :] + jnp.sum(le, axis=1, dtype=jnp.int32)  # [H, w]
-    ncol = hc + w
-    p = jnp.arange(ncol, dtype=jnp.int32)[None, :]
-    jb = jnp.sum(
-        pos_b[:, None, :] <= p[:, :, None], axis=2, dtype=jnp.int32
-    )  # [H, ncol]: incoming events placed at or before each output slot
-    ib = jnp.clip(jb - 1, 0, w - 1)
-    isb = (jb > 0) & (jnp.take_along_axis(pos_b, ib, axis=1) == p)
-    ia = jnp.clip(p - jb, 0, hc - 1)
-    mrg = lambda xa, xb: jnp.where(
-        isb,
-        jnp.take_along_axis(xb, ib, axis=1),
-        jnp.take_along_axis(xa, ia, axis=1),
-    )
-    mt = mrg(at, bt)
-    mss = mrg(ass, bss)
-    mpay = jnp.where(
-        isb[:, :, None],
-        jnp.take_along_axis(bpay, ib[:, :, None], axis=1),
-        jnp.take_along_axis(apay, ia[:, :, None], axis=1),
-    )
-    return mt, mss, mpay
+    return out[0], out[1], jnp.stack(out[2:], axis=-1)
 
 
 def _kernel(qt_ref, qss_ref, qpay_ref, st_ref, sss_ref, bpay_ref,
@@ -146,7 +119,7 @@ def _build_call(h, hc, w, m, nw, interpret):
 
 def fused_merge(qt, qss, qpay, st, sss, bpay, starts, cnt, *,
                 interpret: bool | None = None):
-    """One fused densify + rotate + merge pass over the hot columns.
+    """One fused densify + row-sort pass over the hot columns.
 
     Returns (mt, mss, mpay) merged rows of width hc + w, exactly what
     `lax.sort` over [resident | block] with key (time, srcseq) yields.

@@ -11,6 +11,9 @@ spill-ring contents including eviction order. This file pins that:
   overflowing, spill-ring, multi-round rank overflow, out-of-shard and
   masked rejects, cleared-empty prefixes from engine pops);
 - an engine-level PHOLD run compared state-leaf by state-leaf;
+- `merge_body` itself against a NumPy oracle (a stable lexsort of each
+  [resident | block] row), across cleared prefixes, key ties, wide
+  blocks and all-empty rows;
 - a zero-cost HLO identity: building with an explicit `kernel="xla"`
   lowers byte-identically to the knob-absent default, so the knob's
   plumbing costs nothing when off.
@@ -30,6 +33,7 @@ import pytest
 
 from shadow_tpu.analysis.hlo_audit import lower_text
 from shadow_tpu.core.events import EventQueue, Events, queue_pop, queue_push
+from shadow_tpu.core.merge_pallas import merge_body
 from shadow_tpu.core.timebase import TIME_INVALID
 from shadow_tpu.models import phold
 
@@ -104,6 +108,97 @@ def test_sharded_host0_equivalence():
     q = EventQueue.create(4, 8)
     ev = _rand_events(rng, 32, 8, 100)  # dst over TWO shards' range
     _push_both(q, ev, jnp.ones(32, bool), host0=4)
+
+
+I64MAX = np.iinfo(np.int64).max
+
+
+def _merge_case(rng, h, hc, w, nw, t_max, prefixes, fill, cnt):
+    """merge_body inputs: row r holds `prefixes[r]` cleared slots (time
+    INVALID, stale srcseq and payload, as the engine's prefix clear
+    leaves them), then up to `fill` sorted valid events, then canonical
+    empties; block row r admits `cnt[r]` sorted events."""
+    qt = np.full((h, hc), int(TIME_INVALID), np.int64)
+    qss = np.zeros((h, hc), np.int64)
+    qpay = np.zeros((h, hc, nw), np.int64)
+    for r in range(h):
+        k = prefixes[r]
+        nv = min(fill, hc - k)
+        keys = np.sort(rng.integers(0, t_max, nv) * 4
+                       + rng.integers(0, 4, nv))
+        qt[r, k:k + nv] = keys // 4
+        qss[r, k:k + nv] = keys % 4
+        qpay[r, k:k + nv] = rng.integers(-I64MAX, I64MAX, (nv, nw))
+        qss[r, :k] = rng.integers(0, 4, k)
+        qpay[r, :k] = rng.integers(-I64MAX, I64MAX, (k, nw))
+    m = h * w
+    st = np.full(m, I64MAX, np.int64)
+    sss = np.full(m, I64MAX, np.int64)
+    starts = (np.arange(h) * w).astype(np.int32)
+    bpay = np.zeros((h, w, nw), np.int64)
+    for r in range(h):
+        n = cnt[r]
+        keys = np.sort(rng.integers(0, t_max, n) * 4 + rng.integers(0, 4, n))
+        st[starts[r]:starts[r] + n] = keys // 4
+        sss[starts[r]:starts[r] + n] = keys % 4
+        bpay[r, :n] = rng.integers(-I64MAX, I64MAX, (n, nw))
+    return qt, qss, qpay, st, sss, bpay, starts, np.asarray(cnt, np.int32)
+
+
+def _merge_oracle(qt, qss, qpay, st, sss, bpay, starts, cnt):
+    """Row by row: canonical resident empties (INVALID, 0, 0), then the
+    block with fillers (i64max, i64max, 0) past its count, then one
+    stable lexsort on (time, srcseq) over [resident | block]."""
+    h, hc = qt.shape
+    w = bpay.shape[1]
+    emp = qt == TIME_INVALID
+    rss = np.where(emp, 0, qss)
+    rpay = np.where(emp[:, :, None], 0, qpay)
+    outs = []
+    for r in range(h):
+        lane = np.arange(w) < cnt[r]
+        idx = np.minimum(starts[r] + np.arange(w), st.shape[0] - 1)
+        t = np.concatenate([qt[r], np.where(lane, st[idx], I64MAX)])
+        ss = np.concatenate([rss[r], np.where(lane, sss[idx], I64MAX)])
+        pay = np.concatenate([rpay[r], np.where(lane[:, None], bpay[r], 0)])
+        order = np.lexsort((ss, t))
+        outs.append((t[order], ss[order], pay[order]))
+    return tuple(np.stack(x) for x in zip(*outs))
+
+
+# (name, h, hc, w, nw, t_max, prefixes(h, hc), fill, cnt(h, w))
+MERGE_CASES = [
+    # every cleared-prefix length 0..hc, one per row
+    ("prefixes", 13, 12, 5, 4, 1000,
+     lambda h, hc: list(range(hc + 1)), 6, lambda h, w: [3] * h),
+    # 2 times x 4 srcseqs: resident and block keys tie, payloads differ
+    ("ties", 8, 10, 9, 3, 2,
+     lambda h, hc: [0, 1, 2, 3, 0, 1, 2, 3], 10, lambda h, w: [9] * h),
+    # a block wider than the rows, hc + w = 19
+    ("wide-block", 6, 5, 14, 2, 50,
+     lambda h, hc: [0, 2, 5, 1, 0, 3], 5,
+     lambda h, w: [14, 0, 7, 14, 3, 11]),
+    # all-empty resident rows meet partly and wholly filled blocks
+    ("empty-rows", 4, 7, 6, 4, 20,
+     lambda h, hc: [0, 7, 3, 0], 0, lambda h, w: [0, 6, 2, 5]),
+    # full rows meet all-filler blocks
+    ("filler-blocks", 4, 9, 7, 1, 20,
+     lambda h, hc: [0, 4, 0, 9], 9, lambda h, w: [0] * h),
+]
+
+
+@pytest.mark.parametrize("case", MERGE_CASES, ids=[c[0] for c in MERGE_CASES])
+def test_merge_body_matches_lexsort_oracle(case):
+    _, h, hc, w, nw, t_max, prefixes, fill, cnt = case
+    body = jax.jit(merge_body)
+    for seed in range(3):
+        rng = np.random.default_rng(seed)
+        args = _merge_case(rng, h, hc, w, nw, t_max, prefixes(h, hc),
+                           fill, cnt(h, w))
+        got = body(*(jnp.asarray(x) for x in args))
+        want = _merge_oracle(*args)
+        for g, x in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), x)
 
 
 def test_engine_level_phold_identity():

@@ -9,6 +9,7 @@ written here cannot be read back without a chip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,3 +84,24 @@ def test_pallas_merge_refused_by_mosaic(one_chip):
     assert ("Only 2D gather is supported" in msg
             or "64-bit types are not supported" in msg), msg[:2000]
     assert "64-bit types" in MOSAIC_REFUSAL and "2-D" in MOSAIC_REFUSAL
+
+
+def test_row_merge_compiles_to_a_sort_without_merge_gathers(one_chip):
+    """The shared merge body compiles for the chip as a row sort: the
+    only gathers left are the densify reads of the flat grouped keys
+    (the merge-path body it replaced compiled to 23 gathers at this
+    shape). w > hc and hc + w = 112, not a power of two."""
+    from shadow_tpu.core.merge_pallas import merge_body
+
+    h, hc, w, nw = 256, 48, 64, 4
+
+    def spec(shape, dtype=jnp.int64):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (spec((h, hc)), spec((h, hc)), spec((h, hc, nw)),
+            spec((h * w,)), spec((h * w,)), spec((h, w, nw)),
+            spec((h,), jnp.int32), spec((h,), jnp.int32))
+    text = jax.jit(merge_body).lower(*args).compile().as_text()
+    assert " sort(" in text
+    # the densify reads st[gsafe] and sss[gsafe] compile to 4 gathers
+    assert len(re.findall(r"= \S+ gather\(", text)) <= 4
