@@ -588,6 +588,12 @@ class Engine:
         else:
             self._xchg_r = 0
 
+    @property
+    def xchg_width(self) -> int:
+        """Slots per destination shard in each all_to_all round of the
+        cross-shard exchange (the [S, R] bucket's R); 0 when unsharded."""
+        return self._xchg_r
+
     # -- collectives (identity when unsharded) ------------------------------
     @phase("barrier")
     def _gmin(self, x):
