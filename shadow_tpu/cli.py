@@ -1672,6 +1672,9 @@ def main(argv=None) -> int:
         ),
         # scheduler self-profiling (scheduler.c:266-271 analog)
         "sweeps": int(jax.device_get(stats.n_sweeps)),  # shadowlint: no-deadline=post-loop summary; watchdogs released, state materialized
+        # queue-merge overflow rounds: lanes read against events admitted
+        "merge_over_lanes": int(jax.device_get(stats.n_merge_over_lanes.sum())),  # shadowlint: no-deadline=post-loop summary; watchdogs released, state materialized
+        "merge_over_events": int(jax.device_get(stats.n_merge_over_events.sum())),  # shadowlint: no-deadline=post-loop summary; watchdogs released, state materialized
         "cross_shard_packets": int(jax.device_get(stats.n_cross_shard)),  # shadowlint: no-deadline=post-loop summary; watchdogs released, state materialized
         "rx_bytes": int(
             jax.device_get(st.hosts.net.sockets.rx_bytes.sum())  # shadowlint: no-deadline=post-loop summary; watchdogs released, state materialized

@@ -58,7 +58,7 @@ from shadow_tpu.core.events import (
     Events,
     group_run_starts,
     pack_srcseq,
-    queue_push,
+    queue_push_counted,
 )
 from shadow_tpu.core.phases import phase
 from shadow_tpu.core.timebase import TIME_INVALID
@@ -158,6 +158,13 @@ class Stats:
     # event died with its crashed host)
     n_fault_dropped: jax.Array  # i64[H] packets lost to fault overlays
     n_quarantined: jax.Array  # i64[H] events voided by host crashes
+    # queue-merge self-profiling (`queue_push_counted`): per host, the
+    # lanes its row read in overflow rounds and the events it admitted
+    # in them. Per host so they shard like n_executed; how a push is
+    # split into rounds depends on the shard's own pushes, so the
+    # values differ between mesh shapes and drain contracts.
+    n_merge_over_lanes: jax.Array  # i64[H]
+    n_merge_over_events: jax.Array  # i64[H]
 
     @staticmethod
     def create(n_hosts: int, n_kinds: int = 1) -> "Stats":
@@ -166,7 +173,7 @@ class Stats:
         return Stats(
             z, z, z, s,
             jnp.zeros((n_hosts, n_kinds), jnp.int64),
-            s, s, s, s, z, z,
+            s, s, s, s, z, z, z, z,
         )
 
 
@@ -635,29 +642,41 @@ class Engine:
             nxt = jnp.maximum(nxt, cpu_free)
         return self._gany(jnp.any(nxt < window_end))
 
+    @phase("merge")
+    def _push(self, q: EventQueue, stats: Stats, ev: Events,
+              mask: jax.Array, host0):
+        """`queue_push`, adding its overflow-round counters to stats."""
+        q, lanes, events = queue_push_counted(q, ev, mask, host0,
+                                              self.cfg.kernel)
+        return q, dataclasses.replace(
+            stats,
+            n_merge_over_lanes=stats.n_merge_over_lanes + lanes,
+            n_merge_over_events=stats.n_merge_over_events + events,
+        )
+
     @phase("deliver")
-    def _xchg_deliver(self, q: EventQueue, xchg, host0):
+    def _xchg_deliver(self, q: EventQueue, xchg, stats: Stats, host0):
         """Merge the in-flight exchange buffer into the local queue and
         return it emptied. The guard predicate is shard-local and both
         branches are collective-free, so per-shard divergence is safe
         under shard_map; the common no-cross-traffic case skips the
         queue merge entirely."""
         if xchg is None:
-            return q, xchg
+            return q, xchg, stats
         flat = xchg.bucket.flatten()
         valid = flat.time != TIME_INVALID
-        q = jax.lax.cond(
+        q, stats = jax.lax.cond(
             jnp.any(valid),
-            lambda q: queue_push(q, flat, valid, host0, self.cfg.kernel),
-            lambda q: q,
-            q,
+            lambda qs: self._push(*qs, flat, valid, host0),
+            lambda qs: qs,
+            (q, stats),
         )
         return q, ExchangeBuf.create(
             self.cfg.n_shards, self._xchg_r, self.cfg.n_args
-        )
+        ), stats
 
     @phase("exchange")
-    def _exchange_push(self, q: EventQueue, xchg, ev: Events,
+    def _exchange_push(self, q: EventQueue, xchg, stats: Stats, ev: Events,
                        mask: jax.Array, host0):
         """Push a flat routed batch, delivering cross-shard events by
         bucketed all_to_all.
@@ -677,11 +696,12 @@ class Engine:
         body — and the final round's recv rides out in the returned
         ExchangeBuf to the next sweep or window (double buffering). The
         loop predicate reads a carried flag; the psum deciding another
-        round runs in the body (see `_drain_flag`).
+        round runs in the body (see `_drain_flag`). Returns (q, xchg,
+        stats), stats counting the rounds, crossings and merges.
         """
-        z = jnp.zeros((), jnp.int64)
         if self.cfg.axis_name is None:
-            return queue_push(q, ev, mask, host0, self.cfg.kernel), xchg, z, z
+            q, stats = self._push(q, stats, ev, mask, host0)
+            return q, xchg, stats
         cfg = self.cfg
         ax = cfg.axis_name
         h, s = cfg.n_hosts, cfg.n_shards
@@ -697,7 +717,7 @@ class Engine:
         dshard = ev.dst // jnp.int32(h)
         in_range = (dshard >= 0) & (dshard < s)
         is_local = mask & (dshard == my)
-        q = queue_push(q, ev, is_local, host0, cfg.kernel)
+        q, stats = self._push(q, stats, ev, is_local, host0)
         remaining = mask & in_range & ~is_local
 
         pos = jnp.arange(m, dtype=jnp.int32)
@@ -706,8 +726,8 @@ class Engine:
             return carry[0]
 
         def body(carry):
-            _, q, xchg, rem, rounds = carry
-            q, xchg = self._xchg_deliver(q, xchg, host0)
+            _, q, xchg, stats, rem, rounds = carry
+            q, xchg, stats = self._xchg_deliver(q, xchg, stats, host0)
             dkey = jnp.where(rem, dshard, s)
             order = jnp.argsort(dkey, stable=True)
             sd = dkey[order]
@@ -739,19 +759,23 @@ class Engine:
             )
             sent = jnp.zeros((m,), bool).at[order].set(sel)
             rem = rem & ~sent
-            return self._gany(jnp.any(rem)), q, xchg, rem, rounds + 1
+            return self._gany(jnp.any(rem)), q, xchg, stats, rem, rounds + 1
 
         # global count (each shard only sees its own outbound packets;
         # the replicated stats scalar needs the psum'd total)
         n_cross = jax.lax.psum(
             jnp.sum(remaining, dtype=jnp.int64), ax
         )
-        _, q, xchg, _, rounds = jax.lax.while_loop(
+        _, q, xchg, stats, _, rounds = jax.lax.while_loop(
             cond, body,
-            (self._gany(jnp.any(remaining)), q, xchg, remaining,
+            (self._gany(jnp.any(remaining)), q, xchg, stats, remaining,
              jnp.zeros((), jnp.int64)),
         )
-        return q, xchg, rounds, n_cross
+        return q, xchg, dataclasses.replace(
+            stats,
+            n_xchg_rounds=stats.n_xchg_rounds + rounds,
+            n_cross_shard=stats.n_cross_shard + n_cross,
+        )
 
     # -- state construction -------------------------------------------------
     def _trace_slack(self) -> int:
@@ -777,7 +801,10 @@ class Engine:
         )
         flat = initial.flatten()
         valid = flat.time != TIME_INVALID
-        q = queue_push(q, flat, valid, host0, cfg.kernel)
+        q, stats = self._push(
+            q, Stats.create(cfg.n_hosts, len(self.handlers)), flat, valid,
+            host0,
+        )
         # start each source's sequence counter past any seq the initial
         # events consumed, so engine-emitted events never reuse a (src, seq)
         # pair — uniqueness is what makes the (time, src, seq) total order
@@ -808,7 +835,7 @@ class Engine:
             hosts=hosts,
             src_seq=seq0,
             exec_cnt=jnp.zeros((cfg.n_hosts,), jnp.int32),
-            stats=Stats.create(cfg.n_hosts, len(self.handlers)),
+            stats=stats,
             cpu_free=jnp.zeros((cfg.n_hosts,), jnp.int64),
             fault_epoch=jnp.zeros((), jnp.int32),
             trace=trace,
@@ -1096,7 +1123,7 @@ class Engine:
             # merge window k-1's in-flight exchange before reading the
             # frontier: the gap since the sending sweep's push contains
             # no queue operation, so deferred delivery is bit-identical
-            q, xchg = self._xchg_deliver(q, xchg, host0)
+            q, xchg, stats = self._xchg_deliver(q, xchg, stats, host0)
             with phase("select"):
                 bt = q.time[:, :b]
                 # a host whose virtual CPU is busy past the barrier runs
@@ -1266,15 +1293,10 @@ class Engine:
                 q = dataclasses.replace(
                     q, time=jnp.where(cleared, TIME_INVALID, q.time)
                 )
-            q, xchg, xr, nc = self._exchange_push(
-                q, xchg, out.flatten(), final_mask.reshape(-1), host0
+            q, xchg, stats2 = self._exchange_push(
+                q, xchg, stats2, out.flatten(), final_mask.reshape(-1), host0
             )
-            stats2 = dataclasses.replace(
-                stats2,
-                n_sweeps=stats2.n_sweeps + 1,
-                n_xchg_rounds=stats2.n_xchg_rounds + xr,
-                n_cross_shard=stats2.n_cross_shard + nc,
-            )
+            stats2 = dataclasses.replace(stats2, n_sweeps=stats2.n_sweeps + 1)
             more = self._drain_flag(q, cpu_free, window_end)
             return (more, q, xchg, hosts, src_seq, exec_cnt, stats2,
                     cpu_free, trace, splane)
@@ -1288,7 +1310,7 @@ class Engine:
             # the barrier's sent_min shortcut cannot see a destination
             # host's busy CPU; flush in-flight events before `_next_time`
             # runs so the max(min_time, cpu_free) defer stays exact
-            q, xchg = self._xchg_deliver(q, xchg, host0)
+            q, xchg, stats = self._xchg_deliver(q, xchg, stats, host0)
         if self._stats and splane is not None:
             occ = stats.n_executed - st.stats.n_executed
             splane = splane.observe("occ", occ, occ > 0)
@@ -1522,7 +1544,7 @@ class Engine:
             # merge the previous sweep's in-flight exchange before the
             # frontier read: no queue op ran since its sending push, so
             # the deferred merge is bit-identical to an immediate one
-            q, xchg = self._xchg_deliver(q, xchg, host0)
+            q, xchg, stats = self._xchg_deliver(q, xchg, stats, host0)
 
             with phase("pop"):
                 # 1. move the frontier into staging: queue rows are sorted by
@@ -1717,22 +1739,22 @@ class Engine:
                 )
 
                 def push_narrow(args):
-                    q, xchg, stage = args
+                    q, xchg, stats, stage = args
                     sl = jax.tree.map(lambda a: a[:, :w1], stage)
                     flat = sl.flatten()
                     return self._exchange_push(
-                        q, xchg, flat, flat.time != TIME_INVALID, host0
+                        q, xchg, stats, flat, flat.time != TIME_INVALID, host0
                     )
 
                 def push_full(args):
-                    q, xchg, stage = args
+                    q, xchg, stats, stage = args
                     flat = stage.flatten()
                     return self._exchange_push(
-                        q, xchg, flat, flat.time != TIME_INVALID, host0
+                        q, xchg, stats, flat, flat.time != TIME_INVALID, host0
                     )
 
                 if w1 == sw:
-                    q, xchg, xr, nc = push_full((q, xchg, stage))
+                    q, xchg, stats = push_full((q, xchg, stats, stage))
                 elif cfg.axis_name is not None:
                     # sharded: the exchange's collectives must run under a
                     # shard-uniform program, and maxcnt differs per shard —
@@ -1740,19 +1762,16 @@ class Engine:
                     # static engine-level width is what lets both branches
                     # return the same carried-buffer shape.
                     go_wide = self._gany(maxcnt > w1)
-                    q, xchg, xr, nc = jax.lax.cond(
-                        go_wide, push_full, push_narrow, (q, xchg, stage)
+                    q, xchg, stats = jax.lax.cond(
+                        go_wide, push_full, push_narrow,
+                        (q, xchg, stats, stage),
                     )
                 else:
-                    q, xchg, xr, nc = jax.lax.cond(
-                        maxcnt > w1, push_full, push_narrow, (q, xchg, stage)
+                    q, xchg, stats = jax.lax.cond(
+                        maxcnt > w1, push_full, push_narrow,
+                        (q, xchg, stats, stage),
                     )
-                stats = dataclasses.replace(
-                    stats,
-                    n_sweeps=stats.n_sweeps + 1,
-                    n_xchg_rounds=stats.n_xchg_rounds + xr,
-                    n_cross_shard=stats.n_cross_shard + nc,
-                )
+                stats = dataclasses.replace(stats, n_sweeps=stats.n_sweeps + 1)
             more = self._drain_flag(q, cpu_free, window_end)
             return (more, q, xchg, hosts, src_seq, exec_cnt, stats,
                     cpu_free, trace, splane)
@@ -1765,7 +1784,7 @@ class Engine:
         if self._cpu_enabled:
             # sent_min cannot see a destination's busy CPU: flush the
             # in-flight buffer before `_next_time`'s cpu_free defer runs
-            q, xchg = self._xchg_deliver(q, xchg, host0)
+            q, xchg, stats = self._xchg_deliver(q, xchg, stats, host0)
         # each shard's inner loop trips independently; fold this window's
         # delta across shards so the counter stays replicated-consistent
         with phase("barrier"):
@@ -1866,7 +1885,7 @@ class Engine:
         def outer_body(carry):
             (_, q, xchg, hosts, src_seq, exec_cnt, stats, cpu_free, trace,
              splane) = carry
-            q, xchg = self._xchg_deliver(q, xchg, host0)
+            q, xchg, stats = self._xchg_deliver(q, xchg, stats, host0)
 
             with phase("pop"):
                 # 1. frontier dump into staging — identical to the chained
@@ -2227,37 +2246,34 @@ class Engine:
                 )
 
                 def push_narrow(args):
-                    q, xchg, stage = args
+                    q, xchg, stats, stage = args
                     sl = jax.tree.map(lambda a: a[:, :w1], stage)
                     flat = sl.flatten()
                     return self._exchange_push(
-                        q, xchg, flat, flat.time != TIME_INVALID, host0
+                        q, xchg, stats, flat, flat.time != TIME_INVALID, host0
                     )
 
                 def push_full(args):
-                    q, xchg, stage = args
+                    q, xchg, stats, stage = args
                     flat = stage.flatten()
                     return self._exchange_push(
-                        q, xchg, flat, flat.time != TIME_INVALID, host0
+                        q, xchg, stats, flat, flat.time != TIME_INVALID, host0
                     )
 
                 if w1 == sw:
-                    q, xchg, xr, nc = push_full((q, xchg, stage))
+                    q, xchg, stats = push_full((q, xchg, stats, stage))
                 elif cfg.axis_name is not None:
                     go_wide = self._gany(maxcnt > w1)
-                    q, xchg, xr, nc = jax.lax.cond(
-                        go_wide, push_full, push_narrow, (q, xchg, stage)
+                    q, xchg, stats = jax.lax.cond(
+                        go_wide, push_full, push_narrow,
+                        (q, xchg, stats, stage),
                     )
                 else:
-                    q, xchg, xr, nc = jax.lax.cond(
-                        maxcnt > w1, push_full, push_narrow, (q, xchg, stage)
+                    q, xchg, stats = jax.lax.cond(
+                        maxcnt > w1, push_full, push_narrow,
+                        (q, xchg, stats, stage),
                     )
-                stats = dataclasses.replace(
-                    stats,
-                    n_sweeps=stats.n_sweeps + 1,
-                    n_xchg_rounds=stats.n_xchg_rounds + xr,
-                    n_cross_shard=stats.n_cross_shard + nc,
-                )
+                stats = dataclasses.replace(stats, n_sweeps=stats.n_sweeps + 1)
             more = self._drain_flag(q, cpu_free, window_end)
             return (more, q, xchg, hosts, src_seq, exec_cnt, stats,
                     cpu_free, trace, splane)
@@ -2268,7 +2284,7 @@ class Engine:
         (_, q, xchg, hosts, src_seq, exec_cnt, stats, cpu_free,
          trace, splane) = jax.lax.while_loop(outer_cond, outer_body, carry)
         if self._cpu_enabled:
-            q, xchg = self._xchg_deliver(q, xchg, host0)
+            q, xchg, stats = self._xchg_deliver(q, xchg, stats, host0)
         with phase("barrier"):
             inner = st.stats.n_inner_steps + self._gsum(
                 stats.n_inner_steps - st.stats.n_inner_steps
@@ -2413,8 +2429,9 @@ class Engine:
             # Must precede the fault-epoch wipe (an immediate push would
             # have) and the drain's initial flag, whose barrier these
             # events may now be below.
-            q, xchg = self._xchg_deliver(st.queues, st.xchg, host0)
-            st = dataclasses.replace(st, queues=q, xchg=xchg)
+            q, xchg, stats = self._xchg_deliver(st.queues, st.xchg, st.stats,
+                                                host0)
+            st = dataclasses.replace(st, queues=q, xchg=xchg, stats=stats)
         if self._f_crash or self._f_link or self._f_bw:
             # link-only schedules advance just the epoch watermark (one
             # scalar compare per window): keeping the watermark current
@@ -2441,8 +2458,10 @@ class Engine:
             # "while now < stop: step_window" terminate. Flush any
             # in-flight exchange so the final queues match a run whose
             # deliveries were immediate (i.e. the single-device run).
-            q, xchg = self._xchg_deliver(st.queues, st.xchg, host0)
-            return dataclasses.replace(st, queues=q, xchg=xchg, now=stop)
+            q, xchg, stats = self._xchg_deliver(st.queues, st.xchg, st.stats,
+                                                host0)
+            return dataclasses.replace(st, queues=q, xchg=xchg, stats=stats,
+                                       now=stop)
 
         return jax.lax.cond(
             nxt < stop,
@@ -2477,8 +2496,9 @@ class Engine:
             # flush the last window's in-flight exchange: every remaining
             # event is >= stop, but it must sit in the queues (not the
             # double buffer) for the final state to match single-device
-            q, xchg = self._xchg_deliver(st.queues, st.xchg, host0)
-            st = dataclasses.replace(st, queues=q, xchg=xchg)
+            q, xchg, stats = self._xchg_deliver(st.queues, st.xchg, st.stats,
+                                                host0)
+            st = dataclasses.replace(st, queues=q, xchg=xchg, stats=stats)
         return dataclasses.replace(st, now=stop)
 
 

@@ -46,8 +46,8 @@ N_ARGS = 6
 # [H, MERGE_W] incoming block bounds the width of the merge's row sort,
 # so it is sized to cover every per-destination per-sweep count a
 # steady-state workload produces (Poisson tails at typical loads put
-# P(count > 24) below 1e-8 per host); rarer bursts take the exact
-# full-width fallback round.
+# P(count > 24) below 1e-8 per host); rarer bursts take further exact
+# rounds of the same width, as many as the push's largest count needs.
 MERGE_W = 24
 
 # Hot-region width for the row-wise merge (step 4): when every row's
@@ -297,12 +297,24 @@ def queue_pop(
     return q2, ev, active
 
 
-@phase("merge")
 def queue_push(
     q: EventQueue, ev: Events, mask: jax.Array, host0,
     kernel: str = "xla",
 ) -> EventQueue:
+    """`queue_push_counted` without its overflow-round counters."""
+    return queue_push_counted(q, ev, mask, host0, kernel)[0]
+
+
+@phase("merge")
+def queue_push_counted(
+    q: EventQueue, ev: Events, mask: jax.Array, host0,
+    kernel: str = "xla",
+) -> tuple[EventQueue, jax.Array, jax.Array]:
     """Insert a flat batch of events [M] into their destination queues.
+
+    Returns (queue, over_lanes, over_events): per host, i64[H], the
+    lanes its row read in overflow rounds (the rounds past the first,
+    see "Round structure" below) and the events it admitted in them.
 
     `host0` is the global id of this shard's first host; events whose dst
     falls outside [host0, host0 + H) are silently ignored (the caller routes
@@ -369,15 +381,30 @@ def queue_push(
        0) in step 4: the block's fillers sort after every one of the hc
        resident slots, so none reaches the kept region.
 
-    Round structure is TWO-LEVEL: the common round runs at a narrow W1
-    (MERGE_W covers every per-destination count seen in steady state);
-    iff some destination's count exceeds W1, a `lax.cond` fallback round
-    admits the rank >= W1 remainder at full width. The split is exact,
+    Round structure: rounds of width W1 = min(w_full, MERGE_W), w_full
+    = min(C, M), under one `lax.while_loop`. Round k admits rank
+    [k W1, (k + 1) W1); the first always runs (it also restores the
+    sorted invariant after the engine's prefix clear), and further
+    rounds run while some destination's count exceeds the ranks
+    admitted so far. So a push whose largest count is n reads
+    ceil(n / W1) x H x W1 lanes, sized by the push and not by C; a
+    push that fits W1 runs the first round alone. The loop stops once
+    w_full ranks are admitted: a rank >= w_full >= C has C smaller
+    incoming keys in its own row, so it is counted as a drop without
+    being merged. With a spill ring every rank must reach the ring, so
+    after the first round the rounds run at w_full until every rank is
+    covered (the ring's slack equals C >= w_full, so each round's
+    eviction tail fits one append). The split into rounds is exact,
     not approximate: the merge keeps the C smallest keys whatever round
-    events arrive in, so one round vs two produces identical queues (an
-    element dropped at the intermediate truncation has C smaller
+    events arrive in, so one round vs several produces identical queues
+    (an element dropped at an intermediate truncation has C smaller
     elements that persist to the end, so it would have been dropped
-    regardless).
+    regardless), and each dropped event is counted once, where it
+    leaves the row. One loop body holds one round's temporaries: a
+    `lax.switch` over rounds of several widths, or a first round
+    outside the loop, holds each copy's temporaries apart and raises
+    the window program's peak memory. over_lanes and over_events count
+    the rounds after the first.
 
     Payload words (kind + args) ride bit-packed into i64 word pairs.
     """
@@ -435,10 +462,9 @@ def queue_push(
     mpos = bounds[:h]
     count = bounds[1:] - mpos
 
-    def merge_round(q, lo, w, count_tail):
+    def merge_round(q, lo, w):
         """Admit rank in [lo, lo + w) into a [H, w] block, merge into the
-        queue rows, truncate to capacity. `count_tail`: this is the last
-        round — account rank >= lo + w as drops."""
+        queue rows, truncate to capacity."""
         cnt_r = jnp.clip(count - lo, 0, w)
         starts = mpos + lo
         # -- 3. densify the block payload by gather through the sorted
@@ -480,8 +506,6 @@ def queue_push(
             )
             spill = q.spill
             if spill is None:
-                if count_tail:
-                    over = over + jnp.maximum(count - lo - w, 0)
                 drops_add = over.astype(jnp.int64)
             else:
                 # the merged row keeps empties last, so the evicted
@@ -570,28 +594,28 @@ def queue_push(
 
     w_full = min(c, m)
     w1 = min(w_full, MERGE_W)
-    if q.spill is None:
-        if w1 == w_full:
-            return merge_round(q, 0, w_full, True)
-        q = merge_round(q, 0, w1, False)
-        return jax.lax.cond(
-            jnp.any(count > w1),
-            lambda q: merge_round(q, w1, w_full, True),
-            lambda q: q,
-            q,
+
+    def rounds(q, lo, w, more):
+        """Merge rounds of width w from rank lo while `more(lo)`."""
+        return jax.lax.while_loop(
+            lambda carry: more(carry[1]),
+            lambda carry: (merge_round(carry[0], carry[1], w), carry[1] + w),
+            (q, jnp.asarray(lo, jnp.int32)),
         )
-    # spill: a rank past lo + w in the last round would be dropped
-    # without ever materializing in the ring, so instead of capping,
-    # keep admitting at full width until every rank is covered (the
-    # ring slack equals the queue capacity >= w_full, so each round's
-    # eviction tail always fits one append)
-    q = merge_round(q, 0, w1, False)
-    q, _ = jax.lax.while_loop(
-        lambda carry: jnp.any(count > carry[1]),
-        lambda carry: (
-            merge_round(carry[0], carry[1], w_full, False),
-            carry[1] + w_full,
-        ),
-        (q, jnp.asarray(w1, jnp.int32)),
-    )
-    return q
+
+    if q.spill is None:
+        # the first round always runs: it also restores the sorted
+        # invariant after the engine's prefix clear
+        q, lo = rounds(q, 0, w1, lambda lo: (lo < w1) | (
+            jnp.any(count > lo) & (lo < w_full)))
+        # ranks >= lo were never merged: drops (see above)
+        q = dataclasses.replace(
+            q, drops=q.drops + jnp.maximum(count - lo, 0).astype(jnp.int64)
+        )
+    else:
+        q, lo = rounds(merge_round(q, 0, w1), w1, w_full,
+                       lambda lo: jnp.any(count > lo))
+    # every row read lo - w1 lanes past the first round
+    over = (lo - w1).astype(jnp.int64)
+    admitted = jnp.clip(count - w1, 0, over).astype(jnp.int64)
+    return q, jnp.full((h,), over), admitted

@@ -104,8 +104,10 @@ def _mesh_info(sim):
 # bit-for-bit (ISSUE acceptance — mirrors bench.py CHAOS_CMP_KEYS).
 # n_inner_steps counts per-shard drain substeps: each shard drains its
 # own slice, so the global total grows with the shard count even when
-# every event executes identically.
-_MESH_TELEMETRY = ("n_cross_shard", "n_xchg_rounds", "n_inner_steps")
+# every event executes identically. The merge's overflow counters follow
+# each shard's own pushes.
+_MESH_TELEMETRY = ("n_cross_shard", "n_xchg_rounds", "n_inner_steps",
+                   "n_merge_over_lanes", "n_merge_over_events")
 
 
 def _portable_leaves(state):

@@ -101,6 +101,8 @@ def test_batched_drain_bit_identical_to_sequential():
             n_sweeps=jnp.zeros((), jnp.int64),
             n_inner_steps=jnp.zeros((), jnp.int64),
             n_xchg_rounds=jnp.zeros((), jnp.int64),
+            n_merge_over_lanes=jnp.zeros_like(st.stats.n_merge_over_lanes),
+            n_merge_over_events=jnp.zeros_like(st.stats.n_merge_over_events),
         ),
     )
     for x, y in zip(jax.tree.leaves(strip(a)), jax.tree.leaves(strip(b))):

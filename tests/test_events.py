@@ -6,8 +6,12 @@ src/main/core/work/event.c:110-153): pop yields (time, src, seq)-minimal
 events, pushes land in the right queues, overflow is accounted.
 """
 
+import dataclasses
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from shadow_tpu.core.events import EventQueue, Events, queue_pop, queue_push
 from shadow_tpu.core.timebase import TIME_INVALID
@@ -144,3 +148,126 @@ def test_negative_time_events_excluded():
     q = queue_push(q, ev, jnp.ones(3, bool), host0=0)
     assert q.size().tolist() == [0, 1]
     assert int(q.time[1, 0]) == 7 and int(q.kind[1, 0]) == 3
+
+
+def _ladder_case(max_count, cap=128, n_hosts=6, seed=0):
+    """A resident queue (ties on time, a cleared prefix on every other
+    row, as the engine's pop leaves it) and a push whose largest
+    per-destination count is `max_count`, padded with out-of-shard and
+    masked events so the push spans more than `cap` lanes."""
+    rng = np.random.default_rng(seed + max_count)
+    counts = [max_count, min(max_count, 30), 5, 0, max_count - 1, 1]
+    seq = iter(range(10**6))
+
+    def batch(per_host, t_max):
+        rows = [(int(rng.integers(0, t_max)), h, int(rng.integers(0, 8)),
+                 next(seq), int(rng.integers(0, 100)))
+                for h, n in enumerate(per_host) for _ in range(n)]
+        return [rows[i] for i in rng.permutation(len(rows))]
+
+    q = EventQueue.create(n_hosts=n_hosts, capacity=cap)
+    res = batch([10] * n_hosts, 40)
+    q = queue_push(q, mk_events(res), jnp.ones(len(res), bool), host0=0)
+    cleared = ((np.arange(cap)[None, :] < 3)
+               & (np.arange(n_hosts)[:, None] % 2 == 0))
+    q = dataclasses.replace(
+        q, time=jnp.where(jnp.asarray(cleared), TIME_INVALID, q.time))
+    rows = batch(counts, 40)
+    rows += [(7, n_hosts + 3, 0, next(seq), 0)] * 130  # out of shard
+    mask = np.ones(len(rows), bool)
+    rows += [(5, 0, 0, next(seq), 0)] * 20  # masked off
+    mask = np.concatenate([mask, np.zeros(20, bool)])
+    return q, mk_events(rows), jnp.asarray(mask), np.asarray(counts)
+
+
+def _oracle(q, ev, mask, cap):
+    """Per host: stable sort of resident U incoming on (time, src, seq),
+    keep the `cap` smallest, the rest are drops."""
+    n_hosts = q.time.shape[0]
+    want = {k: np.zeros_like(np.asarray(getattr(q, k)))
+            for k in ("time", "src", "seq", "kind", "args")}
+    want["time"][:] = int(TIME_INVALID)
+    drops = np.asarray(q.drops).copy()
+    et, ed, es, eq, ek = (np.asarray(getattr(ev, k)) for k in
+                          ("time", "dst", "src", "seq", "kind"))
+    ea, m = np.asarray(ev.args), np.asarray(mask)
+    for h in range(n_hosts):
+        qt = np.asarray(q.time[h])
+        keep = qt != int(TIME_INVALID)
+        recs = [(int(qt[j]), int(q.src[h, j]), int(q.seq[h, j]),
+                 int(q.kind[h, j]), tuple(np.asarray(q.args[h, j])))
+                for j in np.nonzero(keep)[0]]
+        recs += [(int(et[i]), int(es[i]), int(eq[i]), int(ek[i]),
+                  tuple(ea[i])) for i in np.nonzero(m & (ed == h))[0]]
+        recs.sort(key=lambda r: r[:3])
+        for j, (t, s, sq, k, a) in enumerate(recs[:cap]):
+            want["time"][h, j], want["src"][h, j], want["seq"][h, j] = t, s, sq
+            want["kind"][h, j], want["args"][h, j] = k, a
+        drops[h] += max(len(recs) - cap, 0)
+    return want, drops
+
+
+# largest per-destination count -> the overflow rounds it takes
+# (C = 128, MERGE_W = 24: a round per 24 ranks past the first 24, until
+# 128 ranks are admitted); 170 runs past capacity and drops its tail
+LADDER_CASES = [(25, 1), (48, 1), (49, 2), (73, 3), (121, 5), (170, 5)]
+
+
+@pytest.mark.parametrize("max_count,rounds", LADDER_CASES,
+                         ids=[f"count{c}" for c, _ in LADDER_CASES])
+def test_overflow_round_width_ladder(max_count, rounds):
+    """The overflow rounds read MERGE_W lanes per row each, as many as
+    the push's largest count past MERGE_W needs (at most until C ranks
+    are in), and the queue is exactly the C smallest keys of resident
+    U incoming whatever the rounds."""
+    from shadow_tpu.core.events import MERGE_W, queue_push_counted
+
+    cap = 128
+    q, ev, mask, counts = _ladder_case(max_count, cap)
+    got, lanes, events = jax.jit(queue_push_counted)(q, ev, mask, 0)
+    want, drops = _oracle(q, ev, mask, cap)
+    for k, v in want.items():
+        np.testing.assert_array_equal(np.asarray(getattr(got, k)), v, k)
+    np.testing.assert_array_equal(np.asarray(got.drops), drops)
+    assert int(lanes.sum()) == q.time.shape[0] * MERGE_W * rounds
+    np.testing.assert_array_equal(
+        np.asarray(events), np.clip(counts - MERGE_W, 0, MERGE_W * rounds))
+    if max_count > MERGE_W * (1 + rounds):
+        assert int(got.drops[0]) > 0
+
+
+def test_overflow_round_skipped_below_merge_w():
+    """A push whose largest count fits MERGE_W runs no overflow round:
+    no overflow lanes, no overflow events."""
+    from shadow_tpu.core.events import MERGE_W, queue_push_counted
+
+    q, ev, mask, _ = _ladder_case(MERGE_W)
+    _, lanes, events = queue_push_counted(q, ev, mask, 0)
+    assert int(lanes.sum()) == 0 and int(events.sum()) == 0
+
+
+def test_overflow_round_lowering_reads_merge_w_lanes():
+    """Lowering only, no compile, at H = 256 and C = 768: the overflow
+    rounds are one `while` whose body densifies [256, MERGE_W] blocks,
+    and no [256, 768] gather runs anywhere in the push."""
+    from shadow_tpu.analysis.hlo_graph import parse_module
+    from shadow_tpu.core.events import MERGE_W
+
+    h, cap, m = 256, 768, 4096
+    q = EventQueue.create(n_hosts=h, capacity=cap)
+    ev = Events.empty((m,))
+    text = jax.jit(queue_push).lower(q, ev, jnp.ones(m, bool), 0).as_text()
+    mod = parse_module(text)
+
+    def gathers(ops):
+        return [t for op in ops if op.short == "gather"
+                for t in op.result_types]
+
+    rounds = [r for op in mod.ops() if op.short == "while"
+              for r in op.regions if r.label == "do"
+              and any(o.short == "sort" for o in r.walk())]
+    assert len(rounds) == 1
+    body = gathers(rounds[0].walk())
+    assert body and all(t.startswith(f"tensor<{h}x{MERGE_W}") for t in body)
+    assert not [t for t in gathers(mod.ops())
+                if t.startswith(f"tensor<{h}x{cap}")]

@@ -8,8 +8,9 @@ must be BIT-identical on every input — queues, drop counters, and
 spill-ring contents including eviction order. This file pins that:
 
 - a randomized property sweep across capacity/pressure regimes (sparse,
-  overflowing, spill-ring, multi-round rank overflow, out-of-shard and
-  masked rejects, cleared-empty prefixes from engine pops);
+  overflowing, spill-ring, multi-round rank overflow at narrow and full
+  overflow widths, out-of-shard and masked rejects, cleared-empty
+  prefixes from engine pops);
 - an engine-level PHOLD run compared state-leaf by state-leaf;
 - `merge_body` itself against a NumPy oracle (a stable lexsort of each
   [resident | block] row), across cleared prefixes, key ties, wide
@@ -82,11 +83,13 @@ REGIMES = [
     (3, 6, 48, 24, 30),       # overflow into a spill ring
     (2, 4, 40, 8, 10),        # ring itself overflows -> n_lost
     (5, 8, 80, 0, 5),         # multi-round: per-dest counts >> MERGE_W
+    (2, 128, 360, 0, 40),     # 2-4 overflow rounds: 48-96 lanes per row
 ]
 
 
 @pytest.mark.parametrize("regime", REGIMES, ids=[
-    "sparse", "overflow", "spill", "ring-overflow", "multi-round"])
+    "sparse", "overflow", "spill", "ring-overflow", "multi-round",
+    "mid-ladder"])
 def test_randomized_push_equivalence(regime):
     n_hosts, cap, m, spill, t_max = regime
     for seed in (0, 1, 2):

@@ -23,7 +23,9 @@ sequential fold wherever the model declares ordering sensitivity
 `stats.n_inner_steps` is exempt from the state comparison by design:
 the chained drain counts per-event inner steps, the frontier drain
 counts per-position rounds (including one terminating probe per run).
-Sweeps, windows, and every other counter must match exactly.
+So are the queue merge's overflow counters (`n_merge_over_*`), which
+count how pushes split into merge rounds. Sweeps, windows, and every
+other counter must match exactly.
 """
 
 import jax
@@ -37,9 +39,10 @@ from shadow_tpu.obs.trace import TraceDrain
 from shadow_tpu.sim import build_simulation
 
 # state-leaf paths the contract deliberately leaves free: bookkeeping
-# whose granularity differs between drains (inner steps), and the trace
-# ring's physical layout (records must still match, see _run_pair)
-_EXEMPT = ("n_inner_steps", ".trace.")
+# whose granularity differs between drains (inner steps, merge rounds),
+# and the trace ring's physical layout (records must still match, see
+# _run_pair)
+_EXEMPT = ("n_inner_steps", "n_merge_over_", ".trace.")
 
 
 def _run_pair(cfg_xml, frontier, *, seed, trace=0, **kw):
