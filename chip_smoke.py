@@ -360,8 +360,6 @@ def check_sharded_phold(ctx):
     import jax
     import jax.numpy as jnp
 
-    from shadow_tpu.parallel import mesh as pmesh
-
     stop = jnp.int64(SIZES["sharded_phold_stop_s"] * SECOND)
     run, init, _ = ctx["sharded"]
     stN, wall_s = _timed(run, init(), stop)
@@ -380,7 +378,7 @@ def check_sharded_phold(ctx):
     evN = int(jax.device_get(stN.stats.n_executed).sum())
     _check(ev1 == evN > 0, f"events {ev1} vs {evN}")
     return {"hosts": SIZES["sharded_phold_hosts"], "shards": 4,
-            "spmd": pmesh.select_spmd("auto"), "events": evN,
+            "events": evN,
             "equals_one_device": True,
             "one_device_compile_s": ctx["one_device_compile_s"],
             "one_device_wall_s": wall1_s,
@@ -400,7 +398,7 @@ def prepare_sharded_tgen(cpu):
         return sim, _timed(sim.run, 0)[1]
 
     shard, one = _parallel(
-        lambda: compiled(mesh=make_mesh(4), spmd="auto"), compiled)
+        lambda: compiled(mesh=make_mesh(4)), compiled)
     return {"simN": shard[0], "sim1": one[0], "compile_s": shard[1],
             "one_device_compile_s": one[1]}
 
@@ -421,7 +419,7 @@ def check_sharded_tgen(ctx):
               for x in (socks.rx_bytes, socks.tx_bytes))
     _check(rx == tx == 3342336 and b["executed"] == 820,
            f"rx {rx} tx {tx} events {b['executed']}")
-    return {"hosts": len(simN.names), "shards": 4, "spmd": simN.spmd_path,
+    return {"hosts": len(simN.names), "shards": 4,
             "events": b["executed"], "rx_bytes": rx, "tx_bytes": tx,
             "equals_one_device": True,
             "one_device_compile_s": ctx["one_device_compile_s"],

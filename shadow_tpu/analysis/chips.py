@@ -3,20 +3,18 @@ readiness").
 
 One frozen row per accelerator generation the silicon campaign
 (ROADMAP item 1) targets, plus a CPU row describing the single-core
-container every BENCH_r* number was measured on. The rows feed three
+container every BENCH_r* number was measured on. The rows feed two
 consumers:
 
 - the tile auditor (`tpu_readiness`): native tile geometry per dtype
   width — the (sublane, 128) minimum tile of the Pallas guide's table
   (f32 (8,128), bf16 (16,128), int8/fp8 (32,128); i64 is emulated as
   two i32 words so it pads like a 4-byte type);
-- the VMEM fit check: per-core VMEM capacity the fused merge kernel's
-  working set is checked against;
 - the roofline cost model (`costmodel`): HBM bandwidth and VPU/MXU
   peaks that price one window round.
 
-Provenance: tile geometry, the ~16 MB/core VMEM figure, and the
-8x128 VPU / 128x128 MXU shapes come from the Pallas TPU guide; HBM
+Provenance: tile geometry and the 8x128 VPU / 128x128 MXU shapes
+come from the Pallas TPU guide; HBM
 capacity/bandwidth and peak bf16 FLOPs are the public v5e/v5p/v6e
 spec-sheet numbers. `sort_gcps` (sustainable sort compares/s) is the
 one deliberately soft number: on the TPU rows it assumes the
@@ -24,16 +22,14 @@ per-round `lax.sort` lowers to a vectorized bitonic network filling
 the 8x128 VPU (the frontier drain's whole bet, BENCH_r07); the CPU
 row is calibrated against this repo's measured single-core container
 (BENCH_r07: scalar, branchy compare-exchange ~0.1 G compares/s).
-Error bars are a factor of ~2 either way — the model ranks drains and
-flags order-of-magnitude VMEM misses, it does not predict wall
-seconds to a percent (docs/10-Static-Analysis.md spells this out).
+Error bars are a factor of ~2 either way — the model ranks drains, it
+does not predict wall seconds to a percent (docs/10-Static-Analysis.md spells this out).
 """
 
 from __future__ import annotations
 
 import dataclasses
 
-MIB = 1 << 20
 GIB = 1 << 30
 
 
@@ -44,7 +40,6 @@ class Chip:
     name: str
     lane: int                 # last-dim tile width (128 on TPU)
     sublanes: dict            # element bytes -> second-to-last tile dim
-    vmem_bytes: int | None    # per-core VMEM; None = no VMEM tier (CPU)
     hbm_bytes: int            # device memory capacity
     hbm_gbps: float           # memory bandwidth, GB/s
     vpu_gflops: float         # elementwise/vector peak, GFLOP/s
@@ -95,31 +90,31 @@ CHIPS: dict[str, Chip] = {
     # v5e: 16 GiB HBM @ 819 GB/s, 197 bf16 TFLOP/s MXU per chip.
     "v5e": Chip(
         name="v5e", lane=128, sublanes=_TPU_SUBLANES,
-        vmem_bytes=16 * MIB, hbm_bytes=16 * GIB, hbm_gbps=819.0,
+        hbm_bytes=16 * GIB, hbm_gbps=819.0,
         vpu_gflops=3900.0, mxu_tflops=197.0, sort_gcps=450.0,
         round_overhead_us=2.0,
     ),
     # v5p: 95 GiB HBM @ 2765 GB/s, 459 bf16 TFLOP/s per chip (2 cores).
     "v5p": Chip(
         name="v5p", lane=128, sublanes=_TPU_SUBLANES,
-        vmem_bytes=16 * MIB, hbm_bytes=95 * GIB, hbm_gbps=2765.0,
+        hbm_bytes=95 * GIB, hbm_gbps=2765.0,
         vpu_gflops=7800.0, mxu_tflops=459.0, sort_gcps=900.0,
         round_overhead_us=2.0,
     ),
     # v6e (Trillium): 32 GiB HBM @ 1640 GB/s, 918 bf16 TFLOP/s.
     "v6e": Chip(
         name="v6e", lane=128, sublanes=_TPU_SUBLANES,
-        vmem_bytes=32 * MIB, hbm_bytes=32 * GIB, hbm_gbps=1640.0,
+        hbm_bytes=32 * GIB, hbm_gbps=1640.0,
         vpu_gflops=7800.0, mxu_tflops=918.0, sort_gcps=900.0,
         round_overhead_us=2.0,
     ),
     # The measured baseline: one CPU core of the CI container (every
-    # BENCH_r* CPU number). No tiling (lane 1), no VMEM tier, no MXU;
-    # sort_gcps is the scalar compare-exchange rate calibrated against
-    # BENCH_r07's chained-vs-frontier gap on this box.
+    # BENCH_r* CPU number). No tiling (lane 1), no MXU; sort_gcps is
+    # the scalar compare-exchange rate calibrated against BENCH_r07's
+    # chained-vs-frontier gap on this box.
     "cpu": Chip(
         name="cpu", lane=1, sublanes={1: 1, 2: 1, 4: 1},
-        vmem_bytes=None, hbm_bytes=16 * GIB, hbm_gbps=12.0,
+        hbm_bytes=16 * GIB, hbm_gbps=12.0,
         vpu_gflops=12.0, mxu_tflops=0.0, sort_gcps=0.1,
         round_overhead_us=0.5,
     ),

@@ -140,7 +140,7 @@ def _budget(scatter: int) -> dict:
 
 
 # The number of forced-CPU devices the sharded contract lowers over
-# (the tests' conftest and measure_all.sh both force this count).
+# (the tests' conftest forces this count).
 SHARDED_DEVICES = 8
 
 CONTRACTS: dict[str, HloContract] = {

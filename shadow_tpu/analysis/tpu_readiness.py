@@ -19,19 +19,14 @@ static signals ROADMAP item 1's campaign needs before chip time:
   dynamic_update_slice relative to the window `while` body, hot ones
   flagged with their region path (`Module.ops_with_path`) — the ops
   whose TPU lowering quality decides the drain's round time.
-- **VMEM fit**: the fused merge kernel's working set (its actual
-  traced block shapes, recorded off the lowering, x dtype bytes x
-  double buffering) checked against each generation's VMEM capacity,
-  with the max merge rows that fit per chip.
 
 Findings land in the checked-in `analysis/TPU_READINESS.json`
-baseline: waste %, churn counts, hot-op counts, VMEM bytes, and the
-cost model's predicted events/s floors (`analysis.costmodel`). The
-audit fails on regressions against the baseline (more waste, new hot
-ops, bigger VMEM set, a floor dropping below tolerance) and on a CPU
-cost-model prediction that disagrees with BENCH_r07's measured
-chained-vs-frontier direction; improvements land silently and show up
-in `--diff`. Refresh deliberately with
+baseline: waste %, churn counts, hot-op counts, and the cost model's
+predicted events/s floors (`analysis.costmodel`). The audit fails on
+regressions against the baseline (more waste, new hot ops, a floor
+dropping below tolerance) and on a CPU cost-model prediction that
+disagrees with BENCH_r07's measured chained-vs-frontier direction;
+improvements land silently and show up in `--diff`. Refresh deliberately with
 ``python -m shadow_tpu.tools.lint --tpu-audit all --update-baseline``.
 """
 
@@ -42,15 +37,11 @@ import os
 from typing import Iterable
 
 from shadow_tpu.analysis import costmodel, hlo_graph
-from shadow_tpu.analysis.chips import CHIP_NAMES, CHIPS, chip as chip_row
+from shadow_tpu.analysis.chips import chip as chip_row
 from shadow_tpu.analysis.costmodel import parse_tensor
 
 BASELINE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "TPU_READINESS.json")
-
-# Inputs and outputs of the (gridless) merge pallas_call stream
-# through VMEM double-buffered; intermediates are single-buffered.
-DOUBLE_BUFFER = 2
 
 # Tolerances: waste may drift this many percentage points before the
 # audit fires (tiny-build shapes make the % jumpy at the margin);
@@ -79,12 +70,9 @@ def ready_configs() -> list[str]:
 # ------------------------------------------------------------ lowering
 
 
-def lower_config(name: str) -> tuple[str, list[dict], int]:
-    """(lowered text, merge-kernel shape records, host rows) for one
-    config. Merge shapes are recorded by wrapping
-    `merge_pallas.merge_body` during the trace — the wrapper sees the
-    exact block shapes the kernel is built with (per lane, under the
-    fleet vmaps). Host rows feed the cost model's linear scale-up."""
+def lower_config(name: str) -> tuple[str, int]:
+    """(lowered text, host rows) for one config. Host rows feed the
+    cost model's linear scale-up."""
     if name in EXTRA_CONFIGS:
         from shadow_tpu.analysis.donation import _sim_tiny
         from shadow_tpu.runtime.harvest import HeartbeatHarvest
@@ -93,30 +81,12 @@ def lower_config(name: str) -> tuple[str, list[dict], int]:
         h = HeartbeatHarvest(sim)
         text = h._build(name == "harvest_full").lower(
             sim.state0).as_text()
-        return text, [], _host_rows(sim.state0)
+        return text, _host_rows(sim.state0)
 
     from shadow_tpu.analysis import hlo_audit
 
     run, state, stop = hlo_audit._build(name)
-    shapes: list[dict] = []
-    from shadow_tpu.core import merge_pallas
-
-    orig = merge_pallas.merge_body
-
-    def _recording(qt, qss, qpay, st, sss, bpay, starts, cnt):
-        shapes.append({
-            "h": int(qt.shape[0]), "hc": int(qt.shape[1]),
-            "w": int(bpay.shape[1]), "m": int(st.shape[0]),
-            "nw": int(qpay.shape[2]),
-        })
-        return orig(qt, qss, qpay, st, sss, bpay, starts, cnt)
-
-    merge_pallas.merge_body = _recording
-    try:
-        text = hlo_audit.lower_text(run, state, stop)
-    finally:
-        merge_pallas.merge_body = orig
-    return text, shapes, _host_rows(state)
+    return hlo_audit.lower_text(run, state, stop), _host_rows(state)
 
 
 def _host_rows(state) -> int:
@@ -220,74 +190,18 @@ def _is_hot(path: str) -> bool:
     return "while@" in path and ".do" in path
 
 
-# ---------------------------------------------------------- VMEM check
-
-
-def merge_vmem_report(h: int, hc: int, w: int, m: int, nw: int,
-                      chips: Iterable[str] = CHIP_NAMES) -> dict:
-    """VMEM working set of one fused-merge invocation (the gridless
-    pallas_call holds every ref whole): tile-padded input + output
-    blocks double-buffered, plus the row sort's concatenated operands
-    (time, srcseq and each payload word as [h, hc+w] i64).
-    `fits`/`max_rows` per chip row."""
-    ncol = hc + w
-    i64, i32 = 8, 4
-
-    def _pb(chip, dims, eb):
-        return chip.padded_bytes(list(dims), eb)
-
-    per_chip: dict[str, dict] = {}
-    for cname in chips:
-        c = chip_row(cname)
-        io_bytes = (
-            _pb(c, (h, hc), i64) * 2          # qt, qss
-            + _pb(c, (h, hc, nw), i64)        # qpay
-            + _pb(c, (m,), i64) * 2           # st, sss
-            + _pb(c, (h, w, nw), i64)         # bpay
-            + _pb(c, (h,), i32) * 2           # starts, cnt
-            + _pb(c, (h, ncol), i64) * 2      # ot, oss
-            + _pb(c, (h, ncol, nw), i64)      # opay
-        )
-        mid_bytes = _pb(c, (h, ncol), i64) * (2 + nw)  # sort operands
-        ws = io_bytes * DOUBLE_BUFFER + mid_bytes
-        rec = {"working_set_bytes": ws}
-        if c.vmem_bytes is not None:
-            rec["fits"] = ws <= c.vmem_bytes
-            rec["max_rows"] = max(int(h * c.vmem_bytes / ws), 0) \
-                if ws else 0
-        per_chip[cname] = rec
-    return {"h": h, "hc": hc, "w": w, "m": m, "nw": nw,
-            "working_set_bytes":
-                per_chip[next(iter(per_chip))]["working_set_bytes"]
-                if per_chip else 0,
-            "per_chip": per_chip}
-
-
-def merge_report(shapes: list[dict]) -> dict | None:
-    """The VMEM report of a config's LARGEST recorded merge call (the
-    binding constraint); None when the config never merges."""
-    if not shapes:
-        return None
-    biggest = max(shapes, key=lambda s: (s["h"] * (s["hc"] + s["w"])
-                                         * (s["nw"] + 2), s["m"]))
-    rep = merge_vmem_report(**biggest)
-    rep["calls"] = len(shapes)
-    return rep
-
-
 # ------------------------------------------------------------ auditing
 
 
 def audit_config(name: str) -> dict:
     """Full readiness report for one config."""
-    text, shapes, rows = lower_config(name)
+    text, rows = lower_config(name)
     module = hlo_graph.parse_module(text)
     return {
         "hosts": rows,
         "tile": tile_report(module),
         "churn": churn_report(module),
         "placement": placement_report(module),
-        "vmem": merge_report(shapes),
         "_module": module,  # stripped before serialization
     }
 
@@ -379,21 +293,6 @@ def check_config(name: str, rep: dict, bl: dict | None) -> list[str]:
                      f"exceeds baseline {bhot} — a new {op_name} "
                      f"entered the window while body "
                      f"(placement.{op_name}.flagged has the paths)")
-    bvm = bl.get("vmem")
-    vm = rep.get("vmem")
-    if vm is not None and bvm is not None:
-        if vm["working_set_bytes"] > bvm["working_set_bytes"]:
-            v.append(f"{name}: merge-kernel VMEM working set "
-                     f"{vm['working_set_bytes']} bytes exceeds "
-                     f"baseline {bvm['working_set_bytes']} — the "
-                     f"fused merge block grew")
-        for cn, rec in vm["per_chip"].items():
-            if "fits" in rec and not rec["fits"] \
-                    and bvm.get("per_chip", {}).get(cn, {}).get(
-                        "fits", True):
-                v.append(f"{name}: merge kernel no longer fits {cn} "
-                         f"VMEM ({rec['working_set_bytes']} bytes > "
-                         f"{CHIPS[cn].vmem_bytes})")
     return v
 
 
@@ -425,14 +324,6 @@ def save_baseline(results: dict, path: str = BASELINE_PATH) -> dict:
             "hot_ops": {k: r["hot"]
                         for k, r in rep["placement"].items()},
         }
-        vm = rep.get("vmem")
-        if vm is not None:
-            entry["vmem"] = {
-                "working_set_bytes": vm["working_set_bytes"],
-                "per_chip": {cn: {k: r[k] for k in ("fits",)
-                                  if k in r}
-                             for cn, r in vm["per_chip"].items()},
-            }
         if "floors" in rep:
             entry["floors"] = rep["floors"]
         configs[name] = entry
@@ -443,8 +334,7 @@ def save_baseline(results: dict, path: str = BASELINE_PATH) -> dict:
     data = {
         "version": 1,
         "comment": "TPU-readiness baseline (tile waste / layout churn "
-                   "/ hot-loop placement / merge-kernel VMEM / "
-                   "predicted events-per-s floors) over the lowered "
+                   "/ hot-loop placement / predicted events-per-s floors) over the lowered "
                    "production programs; regenerate with `python -m "
                    "shadow_tpu.tools.lint --tpu-audit all "
                    "--update-baseline`",

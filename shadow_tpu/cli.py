@@ -101,14 +101,6 @@ def make_parser() -> argparse.ArgumentParser:
                    help="arrange the mesh as M slices joined over DCN "
                         "(multi-slice; the reference's unfinished "
                         "multi-machine design, master.c:414-416)")
-    p.add_argument("--spmd", default="auto",
-                   choices=("auto", "shard_map", "constraint", "pmap"),
-                   help="SPMD execution path for sharded runs (see "
-                        "docs/12-Sharding.md): auto resolves to "
-                        "shard_map; constraint partitions a global "
-                        "program via jit sharding constraints; pmap is "
-                        "the legacy 1-D fallback kept for soak "
-                        "comparison")
     p.add_argument("--runahead", type=float, default=None,
                    help="override the conservative window width in "
                         "MILLISECONDS (options.c --runahead minTimeJump; "
@@ -968,7 +960,7 @@ def main(argv=None) -> int:
         return build_simulation(
             cfg, seed=args.seed, n_sockets=args.sockets,
             capacity=capacity,
-            mesh=mesh, spmd=args.spmd, tcp_cc=args.tcp_congestion_control,
+            mesh=mesh, tcp_cc=args.tcp_congestion_control,
             rx_queue=args.router_queue, qdisc=args.interface_qdisc,
             interface_buffer=args.interface_buffer, locality=args.locality,
             runahead_ns=(
@@ -1139,8 +1131,8 @@ def main(argv=None) -> int:
 
     def _close_metrics():
         # SHADOW_TPU_METRICS_LINGER_S keeps the endpoints up briefly
-        # after the summary line so harnesses (measure_all.sh
-        # metrics_smoke) can take their final reconciliation scrape
+        # after the summary line so a scraping harness can take its
+        # final reconciliation scrape
         if server is None:
             return
         linger_s = float(
@@ -1216,7 +1208,7 @@ def main(argv=None) -> int:
             compile_grace=True,
         )
 
-    # chaos-harness stall injector (tests + bench --chaos-worker): wedge
+    # chaos-harness stall injector (tests/test_elastic.py): wedge
     # the next harvest fetch for N seconds, exactly what a lost peer's
     # never-completing collective looks like from this process. A marker
     # file next to the checkpoint makes the injection one-shot across

@@ -346,13 +346,6 @@ class EngineConfig:
     # answering a fold carries its segment count, so the peer's fast
     # retransmit fires at the same byte position as unfolded.
     burst: tuple | None = None
-    # Queue-merge kernel for queue_push (core.events): "xla" (default)
-    # lowers the densify + row-sort merge as plain XLA ops; "pallas"
-    # fuses them into one Pallas kernel call (core.merge_pallas), which
-    # runs only interpreted on the CPU: Engine refuses it on any other
-    # backend. The two are bit-identical by construction and pinned so
-    # by tests/test_kernel_equivalence.py.
-    kernel: str = "xla"
     # Frontier run batching: the THIRD drain contract, between the fully
     # chained path and the commutative batch_handler path. When > 0 (and
     # no batch_handler is installed) the window drain runs
@@ -383,10 +376,6 @@ class EngineConfig:
     stats: int = 0
 
     def __post_init__(self):
-        if self.kernel not in ("xla", "pallas"):
-            raise ValueError(
-                f"kernel must be 'xla' or 'pallas', got {self.kernel!r}"
-            )
         # a window of width 0 can never drain an event: the compiled outer
         # loop would spin forever on-device with no Python escape. The
         # reference bounds runahead below by 1ms for the same reason
@@ -522,13 +511,6 @@ class Engine:
         exact chained order (the explicit in-host ordering fold) — they
         just never amortize. None (the default) allows every kind.
         Ignored when cfg.frontier == 0."""
-        if cfg.kernel == "pallas" and jax.devices()[0].platform != "cpu":
-            from shadow_tpu.core.merge_pallas import MOSAIC_REFUSAL
-
-            raise ValueError(
-                f"kernel='pallas' runs only interpreted on the CPU, not "
-                f"on {jax.devices()[0].platform!r}: {MOSAIC_REFUSAL}. "
-                "Use kernel='xla' (ROADMAP C2)")
         self.cfg = cfg
         self.handlers = tuple(handlers)
         self.network = network
@@ -646,8 +628,7 @@ class Engine:
     def _push(self, q: EventQueue, stats: Stats, ev: Events,
               mask: jax.Array, host0):
         """`queue_push`, adding its overflow-round counters to stats."""
-        q, lanes, events = queue_push_counted(q, ev, mask, host0,
-                                              self.cfg.kernel)
+        q, lanes, events = queue_push_counted(q, ev, mask, host0)
         return q, dataclasses.replace(
             stats,
             n_merge_over_lanes=stats.n_merge_over_lanes + lanes,

@@ -15,7 +15,7 @@ thousands of updates), while flat and row-wise `lax.sort`s are fast —
 so push is implemented as "group incoming events by destination via one
 flat sort, gather each host's contiguous run into a dense block, merge
 the block into the row with one stable row sort" with no scatter
-anywhere (see `queue_push` and core.merge_pallas), and pop-min /
+anywhere (see `queue_push_counted` and `merge_body`), and pop-min /
 frontier extraction are free prefix reads of the sorted rows. Bounded
 capacity drops the *largest*-key events on overflow and accounts them
 in `drops` — or, when the queue carries a `SpillRing`
@@ -33,7 +33,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from shadow_tpu.core import merge_pallas
 from shadow_tpu.core.phases import phase
 from shadow_tpu.core.timebase import TIME_INVALID
 
@@ -299,16 +298,60 @@ def queue_pop(
 
 def queue_push(
     q: EventQueue, ev: Events, mask: jax.Array, host0,
-    kernel: str = "xla",
 ) -> EventQueue:
     """`queue_push_counted` without its overflow-round counters."""
-    return queue_push_counted(q, ev, mask, host0, kernel)[0]
+    return queue_push_counted(q, ev, mask, host0)[0]
+
+
+_I64MAX = jnp.iinfo(jnp.int64).max
+
+
+def merge_body(qt, qss, qpay, st, sss, bpay, starts, cnt):
+    """One merge round's densify + row sort (step 4 of
+    `queue_push_counted`). Shapes: qt/qss [H, hc], qpay [H, hc, nw],
+    st/sss [m] flat grouped keys, bpay [H, w, nw], starts/cnt [H].
+    Returns (mt, mss, mpay) of width hc + w: each row of
+    [resident | block] sorted stably by (time, srcseq), so ties keep
+    resident-first order.
+
+    One row-wise `lax.sort` does the merge: the TPU sorts rows cheaply,
+    while a computed-index gather along the minor axis costs
+    milliseconds per push.
+    """
+    h, hc = qt.shape
+    w = bpay.shape[1]
+    m = st.shape[0]
+
+    # densify: group g's admitted events sit at flat positions
+    # starts[g] .. starts[g]+cnt[g]-1 in key order; masked lanes become
+    # fillers (i64max, i64max, 0) that sort after every resident empty
+    lane = jnp.arange(w, dtype=jnp.int32)
+    gidx = starts[:, None] + lane[None, :]
+    okl = lane[None, :] < cnt[:, None]
+    gsafe = jnp.minimum(gidx, m - 1)
+    bt = jnp.where(okl, st[gsafe], _I64MAX)
+    bss = jnp.where(okl, sss[gsafe], _I64MAX)
+
+    # resident empties (anywhere in the row: the engine clears executed
+    # prefixes by time alone) become canonical (INV, 0, 0), so they sort
+    # after every valid event and before the fillers, in a fixed order
+    inv = qt == TIME_INVALID
+    qss = jnp.where(inv, 0, qss)
+    qpay = jnp.where(inv[:, :, None], 0, qpay)
+
+    cat = lambda a, b: jnp.concatenate([a, b], axis=1)
+    out = jax.lax.sort(
+        (cat(qt, bt), cat(qss, bss))
+        + tuple(cat(qpay[..., i], bpay[..., i])
+                for i in range(qpay.shape[-1])),
+        dimension=1, num_keys=2, is_stable=True,
+    )
+    return out[0], out[1], jnp.stack(out[2:], axis=-1)
 
 
 @phase("merge")
 def queue_push_counted(
     q: EventQueue, ev: Events, mask: jax.Array, host0,
-    kernel: str = "xla",
 ) -> tuple[EventQueue, jax.Array, jax.Array]:
     """Insert a flat batch of events [M] into their destination queues.
 
@@ -364,16 +407,13 @@ def queue_push_counted(
        of its cost on the current bench target.)
     4. The block merges into the resident rows by ONE stable row-wise
        `lax.sort` over [resident | block], keyed on (time, srcseq), the
-       packed payload words riding along as operands (core.merge_pallas).
+       packed payload words riding along as operands (`merge_body`).
        Resident empties, including the cleared prefix the engine leaves
        after executing a frontier, are made canonical first, so they
        sort after every valid event and before the block's fillers; ties
        resolve resident-first by stability. No computed-index gather
        runs here: on the TPU a row sort is cheap and a gather along the
-       row is not. `kernel="pallas"` runs this densify + sort as one
-       Pallas kernel invocation (interpret-mode off-TPU); `kernel="xla"`
-       (default) runs the identical arithmetic as plain XLA ops. The two
-       are bit-identical by construction and pinned so by test.
+       row is not.
     5. Truncating the merged row to capacity keeps the smallest keys;
        the cut tail plus the final round's rank overflow are counted as
        drops (or spill to the ring). The kept region's empty slots are
@@ -381,35 +421,34 @@ def queue_push_counted(
        0) in step 4: the block's fillers sort after every one of the hc
        resident slots, so none reaches the kept region.
 
-    Round structure: rounds of width W1 = min(w_full, MERGE_W), w_full
-    = min(C, M), under one `lax.while_loop`. Round k admits rank
-    [k W1, (k + 1) W1); the first always runs (it also restores the
-    sorted invariant after the engine's prefix clear), and further
-    rounds run while some destination's count exceeds the ranks
-    admitted so far. So a push whose largest count is n reads
-    ceil(n / W1) x H x W1 lanes, sized by the push and not by C; a
-    push that fits W1 runs the first round alone. The loop stops once
-    w_full ranks are admitted: a rank >= w_full >= C has C smaller
-    incoming keys in its own row, so it is counted as a drop without
-    being merged. With a spill ring every rank must reach the ring, so
-    after the first round the rounds run at w_full until every rank is
-    covered (the ring's slack equals C >= w_full, so each round's
-    eviction tail fits one append). The split into rounds is exact,
-    not approximate: the merge keeps the C smallest keys whatever round
-    events arrive in, so one round vs several produces identical queues
-    (an element dropped at an intermediate truncation has C smaller
-    elements that persist to the end, so it would have been dropped
-    regardless), and each dropped event is counted once, where it
-    leaves the row. One loop body holds one round's temporaries: a
-    `lax.switch` over rounds of several widths, or a first round
-    outside the loop, holds each copy's temporaries apart and raises
-    the window program's peak memory. over_lanes and over_events count
-    the rounds after the first.
+    Round structure: every round runs `merge_body` once. Round k
+    admits ranks [lo, lo + w) of each destination's grouped run, where
+    W1 = min(w_full, MERGE_W) and w_full = min(C, M).
+    - Without a spill ring, one `lax.while_loop` runs rounds of width
+      W1. The first always runs (it also restores the sorted invariant
+      after the engine's prefix clear); further rounds run while some
+      destination's count exceeds the ranks admitted, until w_full
+      ranks are. A push whose largest count is n reads
+      ceil(n / W1) x H x W1 lanes, sized by the push and not by C. A
+      rank >= w_full >= C has C smaller incoming keys in its own row,
+      so it is counted as a drop without being merged.
+    - With a spill ring every rank must reach the ring: after a first
+      round of width W1, the loop runs rounds of width w_full until
+      every rank is admitted (the ring's slack equals C >= w_full, so
+      each round's eviction tail fits one append).
+    The split into rounds is exact, not approximate: the merge keeps
+    the C smallest keys whatever round events arrive in, so one round
+    vs several produces identical queues (an element dropped at an
+    intermediate truncation has C smaller elements that persist to the
+    end, so it would have been dropped regardless), and each dropped
+    event is counted once, where it leaves the row. One loop body holds
+    one round's temporaries; a `lax.switch` over rounds of several
+    widths would hold each branch's apart and raise the window
+    program's peak memory. over_lanes and over_events count the rounds
+    after the first.
 
     Payload words (kind + args) ride bit-packed into i64 word pairs.
     """
-    if kernel not in ("xla", "pallas"):
-        raise ValueError(f"kernel must be 'xla' or 'pallas', got {kernel!r}")
     h, c = q.n_hosts, q.capacity
     m = ev.time.shape[0]
     a = q.args.shape[-1]
@@ -494,12 +533,9 @@ def queue_push_counted(
                 axis=-1,
             )  # [H, hc, NW]
             # -- 4. densify + row sort (see step 4 above)
-            body = (
-                merge_pallas.fused_merge
-                if kernel == "pallas"
-                else merge_pallas.merge_body
+            mt, mss, mpay = merge_body(
+                qt, qss, qpay, st, sss, bpay, starts, cnt_r
             )
-            mt, mss, mpay = body(qt, qss, qpay, st, sss, bpay, starts, cnt_r)
 
             over = jnp.sum(
                 mt[:, hc:] != TIME_INVALID, axis=1, dtype=jnp.int32

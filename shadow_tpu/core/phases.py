@@ -17,7 +17,7 @@ the innermost phase on its name stack.
 | route | per-source sequence numbers and emit routing |
 | append | staging append and the virtual-CPU charge |
 | flush | the staging row sort and the narrow/full push choice |
-| merge | `queue_push`, either kernel |
+| merge | `queue_push`, its merge rounds |
 | exchange | the all_to_all rounds of a sharded push |
 | barrier | next-event time, drain flag, global reductions |
 | fault | fault-schedule epoch transitions |

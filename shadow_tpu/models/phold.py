@@ -142,7 +142,6 @@ def build(
     trace: int = 0,
     stats: int = 0,
     spill: int = 0,
-    kernel: str = "xla",
 ):
     """Build (engine, initial_state) for an n_hosts PHOLD network.
 
@@ -164,7 +163,6 @@ def build(
         trace=trace,
         stats=stats,
         spill=spill,
-        kernel=kernel,
     )
     net = ConstantNetwork(latency_ns)
     eng = Engine(

@@ -18,8 +18,8 @@ adding a single device round-trip:
 - `render()` emits the OpenMetrics text format (`# TYPE`/`# HELP`
   lines, counters sampled as `<family>_total`, terminated by `# EOF`),
   deterministically: two scrapes between heartbeats are byte-identical.
-  `validate_openmetrics` is the ~40-line syntax checker the
-  measure_all.sh metrics_smoke stage runs against a live scrape.
+  `validate_openmetrics` is the ~40-line syntax checker a harness
+  runs against a live scrape (`tools.check_openmetrics`).
 - `FlightRecorder` keeps a bounded host-side ring of the last K
   fetched heartbeat summaries + supervisor events; every diagnostic
   bundle the supervisor/watchdog/pressure/peer-lost paths write

@@ -28,17 +28,6 @@ HOSTS_AXIS = "hosts"
 DCN_AXIS = "dcn"
 
 
-def select_spmd(spmd: str = "auto") -> str:
-    """Resolve an --spmd request to the executed path: "shard_map",
-    "constraint" (jit + explicit shardings, GSPMD partitioning), or
-    "pmap" (the legacy 1-D path). "auto" is shard_map."""
-    if spmd not in ("auto", "shard_map", "constraint", "pmap"):
-        raise ValueError(
-            f"spmd must be auto|shard_map|constraint|pmap, got {spmd!r}"
-        )
-    return "shard_map" if spmd == "auto" else spmd
-
-
 def make_mesh(n_devices: int | None = None, axis: str = HOSTS_AXIS,
               dcn_slices: int = 1) -> Mesh:
     devs = jax.devices()
@@ -95,111 +84,51 @@ def state_specs(st, n_hosts_local: int, axis: str = HOSTS_AXIS):
     return specs
 
 
-def pmap_call(fn, mesh: Mesh, specs, per: int, axes):
-    """Run `fn(state, stop, host0)` data-parallel via `jax.pmap` — the
-    legacy 1-D path, kept for soak comparison until ROADMAP C3 settles
-    it.
+def shard_jit(fn, mesh: Mesh, in_specs, out_specs, per: int, axes=None,
+              donate: bool = False):
+    """Jit `fn(*args, host0)` under `shard_map` over `mesh`: the one
+    sharded call every mesh path builds on.
 
-    `specs` is the state's PartitionSpec pytree: leaves sharded on the
-    mesh axis reshape [S*d0, ...] <-> [S, d0, ...] around the pmap
-    (d0 = leading dim / S: host-dim leaves use `per`, the exchange
-    buffer its own width); replicated leaves broadcast in and take
-    device 0's copy out (the same contract shard_map's P() out_spec
-    has).
+    `host0` is the shard's first global host id, its index along `axes`
+    (default `hosts_axes(mesh)`) times `per` hosts per shard. `in_specs`
+    and `out_specs` are shard_map's; a `P()` spec replicates an argument
+    (a stop time, a key, a tuple of them). `donate` donates the first
+    argument.
     """
-    if not isinstance(axes, str):
-        raise NotImplementedError(
-            "the pmap fallback is single-axis only: a multi-slice "
-            "(dcn x hosts) mesh must run through the SPMD paths — build "
-            "with spmd='auto' (selects "
-            f"{select_spmd('auto')!r}) or spmd='constraint' instead of "
-            "spmd='pmap'"
-        )
-    n = int(np.prod(mesh.devices.shape))
-    mask = jax.tree.map(lambda sp: len(sp) > 0, specs)
-    in_axes = jax.tree.map(lambda m: 0 if m else None, mask)
-
-    def split(st):
-        return jax.tree.map(
-            lambda x, m: x.reshape((n, x.shape[0] // n) + x.shape[1:])
-            if m else x,
-            st, mask,
-        )
-
-    def join(st):
-        return jax.tree.map(
-            lambda x, m: x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
-            if m else x,
-            st, mask,
-        )
-
-    pf = jax.pmap(
-        lambda st, stop: fn(
-            st, stop, jax.lax.axis_index(axes).astype(jnp.int32) * per
+    if axes is None:
+        axes = hosts_axes(mesh)
+    return jax.jit(
+        jax.shard_map(
+            lambda *args: fn(
+                *args, jax.lax.axis_index(axes).astype(jnp.int32) * per
+            ),
+            mesh=mesh,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            check_vma=False,
         ),
-        axis_name=axes,
-        in_axes=(in_axes, None),
-        out_axes=in_axes,
-        devices=list(mesh.devices.flatten()),
+        donate_argnums=0 if donate else (),
     )
-
-    def call(st, stop):
-        return join(pf(split(st), stop))
-
-    return call
 
 
 def build_sharded(eng, init_fn, mesh: Mesh, n_hosts_local: int,
-                  axis: str = HOSTS_AXIS, spmd: str = "auto"):
+                  axis: str = HOSTS_AXIS):
     """Wrap an axis-aware Engine into sharded init/run/step callables.
 
     `eng` must have been built with axis_name=axis and per-shard host count
     n_hosts_local. Returns (init, run, step_window), all jitted over `mesh`:
     init() -> sharded EngineState; run(st, stop) / step_window(st, stop).
 
-    `spmd` picks the execution path (see `select_spmd`): "auto" resolves
-    to shard_map, and "pmap" keeps the legacy 1-D path alive for soak
-    comparison.
+    run and step_window do not donate: this is the raw API, and callers
+    (tests, smoke entries) reread their input state after the call. The
+    managed path (sim.Simulation) donates: it tracks state ownership and
+    can prove the input buffer is dead.
     """
-    path = select_spmd(spmd)
-    if path == "constraint":
-        raise ValueError(
-            "spmd='constraint' partitions a GLOBAL (axis_name=None) "
-            "engine with GSPMD and cannot wrap this per-shard engine; "
-            "build it via sim.build_simulation(..., spmd='constraint')"
-        )
-
-    def _host0():
-        return jax.lax.axis_index(axis).astype(jnp.int32) * n_hosts_local
-
     template = jax.eval_shape(init_fn, jnp.zeros((), jnp.int32))
     specs = state_specs(template, n_hosts_local, axis)
-
-    init = jax.jit(
-        jax.shard_map(
-            lambda: init_fn(_host0()),
-            mesh=mesh,
-            in_specs=(),
-            out_specs=specs,
-            check_vma=False,
-        )
-    )
+    init = shard_jit(init_fn, mesh, (), specs, n_hosts_local, axis)
 
     def _wrap(fn):
-        if path == "pmap":
-            return pmap_call(fn, mesh, specs, n_hosts_local, axis)
-        # no donate_argnums here: this is the raw API and callers (tests,
-        # smoke entries) legitimately reread their input state after the
-        # call. The managed path (sim.Simulation) donates — it tracks
-        # state ownership and can prove the input buffer is dead.
-        return jax.jit(
-            jax.shard_map(
-                lambda s, t: fn(s, t, _host0()),
-                mesh=mesh,
-                in_specs=(specs, P()),
-                out_specs=specs,
-                check_vma=False,
-            )
-        )
+        return shard_jit(fn, mesh, (specs, P()), specs, n_hosts_local, axis)
 
     return init, _wrap(eng.run), _wrap(eng.step_window)

@@ -16,9 +16,9 @@ Lane semantics (docs/16-Scenario-Fleets.md):
   (integer per-mille, applied before the window-barrier clamp), a NIC
   bandwidth scale (state-side, NIC-modelled hosts only), and arbitrary
   array-valued initial-state overrides (`state_override`).
-- MUST be uniform: every static compile-time knob — kernel, frontier,
-  window policy, capacity, host count, drain batch, trace/stats/spill
-  depth. One fleet is one lowered program; sweeping a static knob means
+- MUST be uniform: every static compile-time knob — frontier, window
+  policy, capacity, host count, drain batch, trace/stats/spill depth.
+  One fleet is one lowered program; sweeping a static knob means
   building separate fleets. Violations raise with the knob named.
 
 Termination masking comes from JAX itself: vmapping `lax.while_loop`
@@ -57,11 +57,10 @@ from shadow_tpu.faults.schedule import (
 # knobs that are compile-time constants of the one lowered program; named
 # here so the rejection error can say WHY a per-lane value cannot exist
 STATIC_KNOBS = (
-    "kernel", "frontier", "window", "capacity", "lookahead", "drain_batch",
-    "n_hosts", "max_emit", "n_args", "trace", "stats", "spill", "batched",
-    "overflow", "mesh", "n_shards", "stage_width", "route_bucket",
-    "hot_hosts", "hot_weight", "msgs_per_host", "latency_ns",
-    "mean_delay_ns",
+    "frontier", "window", "capacity", "lookahead", "drain_batch", "n_hosts",
+    "max_emit", "n_args", "trace", "stats", "spill", "batched", "overflow",
+    "mesh", "n_shards", "stage_width", "route_bucket", "hot_hosts",
+    "hot_weight", "msgs_per_host", "latency_ns", "mean_delay_ns",
 )
 
 LANE_KNOBS = ("seeds", "faults", "latency_scale", "bandwidth_scale",
@@ -277,13 +276,12 @@ class Fleet:
     """L scenario lanes lowered as one donation-preserving program.
 
     Duck-types the slice of `Simulation` the harvest/CLI layers use
-    (`state0`, `mesh`, `spmd_path`, `pressure`, `profiler`,
+    (`state0`, `mesh`, `pressure`, `profiler`,
     `_fresh_state`, `_note_owned`, `dispatch`, `check_drops`), so
     `HeartbeatHarvest` drives a fleet exactly like a solo run.
     """
 
     mesh = None
-    spmd_path = None
     pressure = None
     profiler = None
 

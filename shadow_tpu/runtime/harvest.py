@@ -167,14 +167,10 @@ class HeartbeatHarvest:
                     )
             return state, bundle
 
-        # donation mirrors Simulation._wrap's gate: single-device jits
-        # and the SPMD paths (shard_map / constraint — their states are
-        # ordinary sharded jit arrays, safe to donate through the
-        # pass-through) donate; only the pmap fallback's stacked outputs
-        # go through undonated
-        if sim.mesh is None or sim.spmd_path != "pmap":
-            return jax.jit(extract, donate_argnums=0)
-        return jax.jit(extract)  # shadowlint: no-donate=pmap-fallback stacked states; mirrors Simulation._wrap's donation gate
+        # donates as Simulation._wrap does: a sharded state is an
+        # ordinary sharded jit array, safe to donate through the
+        # pass-through
+        return jax.jit(extract, donate_argnums=0)
 
     def extract(self, state, *, full: bool):
         """Queue the extraction behind whatever is in flight; returns

@@ -135,13 +135,6 @@ class Simulation:
     app: Any  # the AppModel instance
     stack: Stack
     mesh: Any = None  # jax.sharding.Mesh when sharded
-    # requested SPMD lowering for the sharded paths: "auto" resolves via
-    # parallel.mesh.select_spmd (shard_map on every supported jax;
-    # "constraint" = jit + explicit NamedShardings over a GLOBAL engine,
-    # GSPMD inserts the collectives; "pmap" = the legacy 1-D fallback,
-    # kept alive for soak comparison). See `spmd_path` for the resolved
-    # value and docs/12-Sharding.md for the selection matrix.
-    spmd: str = "auto"
     pcap_gids: tuple = ()  # hosts with logpcap set
     pcap_dir: str = "shadow.pcap.d"  # from the pcapdir host attr
     kind_names: tuple = ()  # handler-kind names (object-counter labels)
@@ -175,20 +168,10 @@ class Simulation:
     _jit_step_w: Any = None  # traced-window variant (--window auto)
     _owned: Any = None  # weak id-map of donation-safe states we produced
 
-    @property
-    def spmd_path(self) -> str | None:
-        """The EXECUTED sharding path: None (single device), "shard_map",
-        "constraint", or "pmap". This is what tests assert on — no
-        jax.pmap runs unless this says so."""
-        if self.mesh is None:
-            return None
-        from shadow_tpu.parallel.mesh import select_spmd
-
-        return select_spmd(self.spmd)
-
     def _wrap(self, fn):
-        """Jit `fn(state, stop, host0)`, under the selected SPMD path
-        when sharded.
+        """Jit `fn(state, stop, host0)`, under `shard_map` when sharded
+        (`parallel.mesh.shard_jit`; `stop` may be any pytree, and is
+        replicated).
 
         The state argument is DONATED: the [H, C] queue arrays, staging
         buffers, and trace/spill rings alias the outputs instead of
@@ -203,67 +186,24 @@ class Simulation:
         builds against donated builds and hold unchanged."""
         if self.mesh is None:
             return jax.jit(lambda st, stop: fn(st, stop, 0), donate_argnums=0)
+        return self._shard_jit(fn, 1)
+
+    def _shard_jit(self, fn, n_extra: int):
+        """`fn(state, *extra, host0)` under `shard_map` over the mesh,
+        the state sharded on hosts, `n_extra` replicated arguments."""
         from jax.sharding import PartitionSpec as P
 
-        from shadow_tpu.parallel.mesh import (
-            hosts_axes, state_specs,
-        )
+        from shadow_tpu.parallel.mesh import hosts_axes, shard_jit, state_specs
 
-        axes = hosts_axes(self.mesh)
         per = self.engine.cfg.n_hosts
         # state0 leaves are global-shaped; sharding splits the leading
         # host dim across the axis (or axis tuple for multi-slice)
         specs = state_specs(
-            self.state0, per * self.engine.cfg.n_shards, axes
+            self.state0, per * self.engine.cfg.n_shards,
+            hosts_axes(self.mesh),
         )
-        path = self.spmd_path
-
-        if path == "pmap":
-            from shadow_tpu.parallel.mesh import pmap_call
-
-            # no donation on the pmap path: jax.pmap's donation is
-            # per-device-buffer and interacts badly with the path's
-            # reshape/stack plumbing; it is a soak path, not the perf
-            # path
-            return pmap_call(fn, self.mesh, specs, per, axes)
-
-        if path == "constraint":
-            # GSPMD path: the engine is GLOBAL (axis_name=None — it runs
-            # no manual collectives), the state is pinned to the mesh by
-            # explicit NamedShardings, and the partitioner inserts the
-            # cross-device movement. Bit-identity with single-device is
-            # structural: this IS the single-device program.
-            from jax.sharding import NamedSharding
-
-            shardings = jax.tree.map(
-                lambda sp: NamedSharding(self.mesh, sp), specs
-            )
-
-            def constrained(st, stop):
-                st = jax.lax.with_sharding_constraint(st, shardings)
-                return fn(st, stop, 0)
-
-            return jax.jit(
-                constrained,
-                in_shardings=(shardings, None),
-                out_shardings=shardings,
-                donate_argnums=0,
-            )
-
-        def sharded(st, stop):
-            host0 = jax.lax.axis_index(axes).astype(jnp.int32) * per
-            return fn(st, stop, host0)
-
-        return jax.jit(
-            jax.shard_map(
-                sharded,
-                mesh=self.mesh,
-                in_specs=(specs, P()),
-                out_specs=specs,
-                check_vma=False,
-            ),
-            donate_argnums=0,
-        )
+        return shard_jit(fn, self.mesh, (specs,) + (P(),) * n_extra, specs,
+                         per, donate=True)
 
     strict_overflow: bool = True
 
@@ -451,12 +391,6 @@ class Simulation:
         """Build the traced-window step jit once (--window N / auto)."""
         if self._jit_step_w is not None:
             return
-        if self.spmd_path == "pmap":
-            raise ValueError(
-                "adaptive windows (--window auto) need the shard_map or "
-                "constraint SPMD path; the pmap fallback runs fixed "
-                "windows only (selected spmd='pmap')"
-            )
         if self.mesh is None:
             jsw = jax.jit(
                 lambda st, stop, w: self.engine.step_window(
@@ -465,55 +399,13 @@ class Simulation:
                 donate_argnums=0,
             )
         else:
-            jsw = self._wrap_windowed()
+            jsw = self._shard_jit(
+                lambda st, stop, w, host0: self.engine.step_window(
+                    st, stop, host0, window=w
+                ),
+                2,
+            )
         object.__setattr__(self, "_jit_step_w", jsw)
-
-    def _wrap_windowed(self):
-        """shard_map wrapper for the traced-window step (mesh path)."""
-        from jax.sharding import PartitionSpec as P
-
-        from shadow_tpu.parallel.mesh import (
-            hosts_axes, state_specs,
-        )
-
-        axes = hosts_axes(self.mesh)
-        per = self.engine.cfg.n_hosts
-        specs = state_specs(
-            self.state0, per * self.engine.cfg.n_shards, axes
-        )
-
-        if self.spmd_path == "constraint":
-            from jax.sharding import NamedSharding
-
-            shardings = jax.tree.map(
-                lambda sp: NamedSharding(self.mesh, sp), specs
-            )
-
-            def constrained(st, stop, w):
-                st = jax.lax.with_sharding_constraint(st, shardings)
-                return self.engine.step_window(st, stop, 0, window=w)
-
-            return jax.jit(
-                constrained,
-                in_shardings=(shardings, None, None),
-                out_shardings=shardings,
-                donate_argnums=0,
-            )
-
-        def sharded(st, stop, w):
-            host0 = jax.lax.axis_index(axes).astype(jnp.int32) * per
-            return self.engine.step_window(st, stop, host0, window=w)
-
-        return jax.jit(
-            jax.shard_map(
-                sharded,
-                mesh=self.mesh,
-                in_specs=(specs, P(), P()),
-                out_specs=specs,
-                check_vma=False,
-            ),
-            donate_argnums=0,
-        )
 
     def summary(self, state) -> dict:
         """Host-side progress snapshot (frontier time, window count,
@@ -752,17 +644,11 @@ def build_simulation(
     profiler: Any = None,
     overflow: str = "drop",
     spill_len: int = 0,
-    spmd: str = "auto",
     host_order: Any = None,
 ) -> Simulation:
     """Config -> Simulation; pass a `jax.sharding.Mesh` (1-D "hosts" or
-    2-D "dcn" x "hosts") to shard hosts.
-
-    `spmd` selects the sharded lowering: "auto" resolves to shard_map
-    (public or experimental — the engine's collective-free loop
-    predicates make both safe), "constraint" builds ONE global engine
-    and lets GSPMD partition it from explicit NamedShardings, "pmap"
-    keeps the legacy 1-D fallback. See docs/12-Sharding.md.
+    2-D "dcn" x "hosts") to shard hosts; the window loop then runs
+    under `shard_map` (docs/12-Sharding.md).
 
     `locality=True` (sharded runs only) reorders hosts at build time so
     config-visible traffic partners share a shard, cutting cross-shard
@@ -1130,11 +1016,9 @@ def build_simulation(
         lookahead = runahead_ns
     else:
         lookahead = max(int(topo.min_latency_ms * MILLISECOND), 1)
-    spmd_path = None
     if mesh is not None:
-        from shadow_tpu.parallel.mesh import hosts_axes, select_spmd
+        from shadow_tpu.parallel.mesh import hosts_axes
 
-        spmd_path = select_spmd(spmd)
         n_shards = int(mesh.devices.size)
         if n_hosts % n_shards:
             raise ValueError(
@@ -1142,11 +1026,6 @@ def build_simulation(
             )
         per_shard = n_hosts // n_shards
         axis_name = hosts_axes(mesh)
-        if spmd_path == "constraint":
-            # GSPMD partitions ONE global program: the engine runs no
-            # manual collectives (axis_name=None), sees every host, and
-            # the mesh enters only through _wrap's NamedShardings
-            n_shards, per_shard, axis_name = 1, n_hosts, None
     else:
         n_shards, per_shard, axis_name = 1, n_hosts, None
     # burst delivery (engine._burst_fold): contiguous same-flow TCP
@@ -1311,9 +1190,7 @@ def build_simulation(
         kind=jnp.asarray(kinds), args=jnp.asarray(argw),
     )
 
-    if mesh is None or spmd_path == "constraint":
-        # constraint path: the global init IS the single-device init;
-        # _wrap's in_shardings spread it over the mesh on first call
+    if mesh is None:
         st0 = eng.init_state(hosts_state, init)
     else:
         # build the initial state under shard_map: each shard slices its
@@ -1321,16 +1198,7 @@ def build_simulation(
         # ignores out-of-shard destinations)
         from jax.sharding import PartitionSpec as P
 
-        from shadow_tpu.parallel.mesh import (
-            hosts_axes, state_specs,
-        )
-
-        axes = hosts_axes(mesh)
-        hspecs = jax.tree.map(lambda _: P(axes), hosts_state)
-
-        def init_shard(hslice):
-            host0 = jax.lax.axis_index(axes).astype(jnp.int32) * per_shard
-            return eng.init_state(hslice, init, host0)
+        from shadow_tpu.parallel.mesh import shard_jit, state_specs
 
         slice_shapes = jax.tree.map(
             lambda l: jax.ShapeDtypeStruct(
@@ -1341,15 +1209,12 @@ def build_simulation(
         template = jax.eval_shape(
             lambda hs: eng.init_state(hs, init, 0), slice_shapes
         )
-        ospecs = state_specs(template, per_shard, axes)
-        st0 = jax.jit(
-            jax.shard_map(
-                init_shard,
-                mesh=mesh,
-                in_specs=(hspecs,),
-                out_specs=ospecs,
-                check_vma=False,
-            )
+        st0 = shard_jit(
+            lambda hslice, host0: eng.init_state(hslice, init, host0),
+            mesh,
+            (jax.tree.map(lambda _: P(axis_name), hosts_state),),
+            state_specs(template, per_shard, axis_name),
+            per_shard,
         )(hosts_state)
     if len(pcap_dirs) > 1:
         raise ValueError(
@@ -1367,7 +1232,7 @@ def build_simulation(
     return Simulation(
         engine=eng, state0=st0, stop_ns=int(cfg.stoptime * SECOND),
         dns=dns, topo=topo, names=[h.name for h in hosts], app=model,
-        stack=stack, mesh=mesh, spmd=spmd,
+        stack=stack, mesh=mesh,
         pcap_gids=tuple(int(g) for g in np.nonzero(pcap_mask)[0]),
         pcap_dir=(pcap_dirs.pop() if pcap_dirs else "shadow.pcap.d"),
         kind_names=tuple(kind_names),
@@ -1385,7 +1250,7 @@ def build_fleet(sim: Simulation, lanes: int, **overrides):
     Per-lane knobs (`seeds`, `faults`, `latency_scale`,
     `bandwidth_scale`, `state_override` — see runtime.fleet.FleetPlan)
     become traced inputs of ONE jitted vmapped window loop; static
-    compile-time knobs (kernel/frontier/window/capacity/...) must stay
+    compile-time knobs (frontier/window/capacity/...) must stay
     uniform and are rejected with the reason. The fleet's stacked
     `[L, ...]` state donates through every segment exactly like the
     solo `Simulation` jits, and `HeartbeatHarvest` drives it through

@@ -1,8 +1,7 @@
 """check_openmetrics: lint an OpenMetrics exposition for syntax errors.
 
 Thin CLI over `shadow_tpu.obs.metrics.validate_openmetrics` so shell
-harnesses (measure_all.sh's metrics_smoke / stats_smoke stages) can
-gate on exporter output without a prometheus toolchain in the
+harnesses can gate on exporter output without a prometheus toolchain in the
 container. Histogram families (the --stats expositions) get the full
 semantic check: monotone `le` bucket ordering, the mandatory `+Inf`
 bucket, and `_count`/`_sum` reconciliation against the bucket totals —

@@ -4,7 +4,7 @@ The lint CLI's `--diff` compares two *static-analysis* reports; this
 tool extends the precedent to what a run actually produced: the
 end-of-run summary JSON, an OpenMetrics `/metrics` scrape, a heartbeat
 log's cumulative `[stats]`/`[metrics]` rows, and the BENCH_r*.json
-harness artifacts. Point it at two files — or two directories, where
+records. Point it at two files — or two directories, where
 every like-named artifact present in both is compared — and it prints
 one drift line per diverging key, with a numeric tolerance for the
 wall-clock-contaminated fields.

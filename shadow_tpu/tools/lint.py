@@ -16,9 +16,8 @@ Exit status: 0 when there are no findings outside the checked-in
 baseline (and, with --hlo-audit / --donation-audit / --mem-audit /
 --tpu-audit, every audited config meets its contract); 1 otherwise.
 Output is a
-single JSON document on stdout — machine-readable for the
-measure_all.sh lint and dataflow_audit stages — with human one-liners
-on stderr.
+single JSON document on stdout — machine-readable for scripts and CI
+— with human one-liners on stderr.
 
 The baseline (shadow_tpu/analysis/lint_baseline.json) holds accepted
 findings keyed by (rule | path | function | source line) so they
@@ -30,7 +29,7 @@ baseline shrinks as findings are fixed. `--mem-audit
 (shadow_tpu/analysis/TPU_READINESS.json). `--diff` compares two saved
 JSON reports and prints the per-config drift of op budgets, alias
 counts, memory estimates, and TPU-readiness numbers (tile waste,
-layout churn, merge-kernel VMEM, predicted events/s floors) — the
+layout churn, predicted events/s floors) — the
 review artifact for an intentional budget bump. See
 docs/10-Static-Analysis.md.
 """
@@ -97,11 +96,6 @@ def _diff_reports(old: dict, new: dict) -> list[str]:
             _num("tpu", cfg, f"hot.{op}",
                  op_.get(op, {}).get("hot", 0),
                  np_.get(op, {}).get("hot", 0))
-        ov = oc.get("vmem") or {}
-        nv = nc.get("vmem") or {}
-        _num("tpu", cfg, "vmem.working_set_bytes",
-             ov.get("working_set_bytes", 0),
-             nv.get("working_set_bytes", 0))
         of_, nf = oc.get("floors", {}), nc.get("floors", {})
         for cn in sorted(set(of_) | set(nf)):
             _num("tpu", cfg, f"floor.{cn}",
@@ -146,8 +140,8 @@ def main(argv: list[str] | None = None) -> int:
                          "against MEM_BUDGETS.json")
     ap.add_argument("--tpu-audit", metavar="CONFIGS", default=None,
                     help="TPU-readiness audit (tile waste, layout "
-                         "churn, hot-loop placement, merge-kernel VMEM, "
-                         "roofline drain economics) checked against "
+                         "churn, hot-loop placement, roofline drain "
+                         "economics) checked against "
                          "TPU_READINESS.json: 'all' or a comma list of "
                          "configs")
     ap.add_argument("--diff", nargs=2, metavar=("OLD", "NEW"),

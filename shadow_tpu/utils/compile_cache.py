@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
-Every entry point (`shadow_tpu` run and `serve`, bench.py,
-chip_smoke.py) calls `enable_compile_cache()` once before its first
+Every entry point (`shadow_tpu` run and `serve`, chip_smoke.py, the
+benchmark harness) calls `enable_compile_cache()` once before its first
 compile; importing the package never does. A set
 `JAX_COMPILATION_CACHE_DIR` wins and JAX reads it itself, so no other
 directory is set in code. Otherwise the cache lives at one fixed path

@@ -6,8 +6,8 @@ idiom that must NOT (the exemptions are as load-bearing as the rules:
 bool-compare counts, counter-based stream RNG, ctypes protocol
 attributes). The auditor is exercised against the real phold engine —
 clean by contract — and against an injected forbidden-op variant it
-must reject. The five-config audit runs in the slow lane (and in the
-measure_all.sh lint stage); docs/10-Static-Analysis.md is the catalog.
+must reject. The five-config audit runs in the slow lane;
+docs/10-Static-Analysis.md is the catalog.
 """
 
 import dataclasses
@@ -841,8 +841,8 @@ def test_recompile_guard(phold_build):
 
 @pytest.mark.slow
 def test_all_model_configs_meet_contracts():
-    """The full five-config audit (also the measure_all.sh lint stage):
-    every declared contract holds on today's lowerings."""
+    """The full five-config audit: every declared contract holds on
+    today's lowerings."""
     results = H.audit_all()
     assert sorted(results) == sorted(H.CONTRACTS)
     bad = {k: v["violations"] for k, v in results.items() if not v["ok"]}

@@ -1,7 +1,7 @@
 """Host-side halves of the overlapped drain pipeline (PR 6).
 
 The device halves (donating jits, single-sync harvest) are exercised
-end-to-end by the CLI suites and `tests/test_kernel_equivalence.py`;
+end-to-end by the CLI suites and `tests/test_sharded_sim.py`;
 this file pins the pure-host policy pieces:
 
 - `runtime/adaptive.WindowController` — the `--window auto` policy must

@@ -101,7 +101,7 @@ def _mesh_info(sim):
 
 # The exchange buffer and the scheduling telemetry counters are the
 # only mesh-shaped state; everything else must survive a reshard
-# bit-for-bit (ISSUE acceptance — mirrors bench.py CHAOS_CMP_KEYS).
+# bit-for-bit.
 # n_inner_steps counts per-shard drain substeps: each shard drains its
 # own slice, so the global total grows with the shard count even when
 # every event executes identically. The merge's overflow counters follow

@@ -328,8 +328,7 @@ def test_cli_rejects_per_scenario_flags_and_bad_specs(capsys):
 
 
 def test_phold_build_fleet_convenience_defaults():
-    # the model-level sweep entry point bench.py and perf_smoke use:
-    # seeds default to base seed .. base seed + L - 1
+    # the model-level sweep entry point: seeds default to base seed .. base seed + L - 1
     fleet = phold.build_fleet(N, 3, seed=7, capacity=64, msgs_per_host=2)
     assert fleet.lanes == 3
     assert tuple(int(s) for s in fleet.plan.seeds) == (7, 8, 9)

@@ -2,8 +2,7 @@
 metrics registry, OpenMetrics exporter, flight recorder, health state
 machine, and their CLI wiring (docs/14-Telemetry.md).
 
-The contracts under test mirror the measure_all.sh metrics_smoke gates:
-the exporter is deterministic between ingests, syntactically valid
+The contracts under test: the exporter is deterministic between ingests, syntactically valid
 OpenMetrics, and reconciles exactly with the tracker's [metrics]
 heartbeat rows and the end-of-run summary — single-shard and on the
 forced 8-device mesh. With --metrics off, the harvest extraction must

@@ -1,4 +1,4 @@
-"""SPMD path contract tests (docs/12-Sharding.md).
+"""Sharded window loop contract tests (docs/12-Sharding.md).
 
 The restructured sharded window loop defuses the jax 0.4.x
 experimental-shard_map miscompile structurally: every drain/exchange
@@ -8,10 +8,7 @@ that contract:
 
 - every `cond { ... }` region of the lowered sharded program is
   collective-free (the HLO-level twin of shadowlint SL108);
-- the executed path on this jax is shard_map — `jax.pmap` never runs
-  unless explicitly requested via spmd="pmap";
-- the pmap fallback stays green at 1-D and refuses multi-slice meshes
-  with a message naming the capability probe and the remedy;
+- the sharded run executes under shard_map — `jax.pmap` never runs;
 - a 2-D (dcn x hosts) mesh is bit-identical to the 1-D mesh at the
   same total host count;
 - the sharded lowering meets the hlo_audit phold_sharded budgets.
@@ -23,7 +20,6 @@ import re
 
 import jax
 import jax.numpy as jnp
-import pytest
 
 from shadow_tpu.core.timebase import SECOND
 from shadow_tpu.models import phold
@@ -57,13 +53,13 @@ def _cond_regions(text: str) -> list[str]:
 
 
 def _sharded_phold(per, n_shards, *, axis=pmesh.HOSTS_AXIS, mesh=None,
-                   spmd="auto", **kw):
+                   **kw):
     kw.setdefault("seed", 3)
     kw.setdefault("capacity", 32)
     kw.setdefault("msgs_per_host", 4)
     eng, init = phold.build(per, axis_name=axis, n_shards=n_shards, **kw)
     m = mesh if mesh is not None else pmesh.make_mesh(n_shards)
-    return pmesh.build_sharded(eng, init, m, per, axis=axis, spmd=spmd)
+    return pmesh.build_sharded(eng, init, m, per, axis=axis)
 
 
 def test_window_predicates_have_no_collective():
@@ -86,20 +82,8 @@ def test_window_predicates_have_no_collective():
     assert any(f"stablehlo.{op}" in text for op in COLLECTIVE_OPS)
 
 
-def test_path_selection_matrix():
-    assert pmesh.select_spmd("auto") == "shard_map"
-    assert pmesh.select_spmd("pmap") == "pmap"
-    with pytest.raises(ValueError, match="auto|shard_map"):
-        pmesh.select_spmd("mpi")
-    # the raw per-shard API cannot host the constraint path (that
-    # partitions a GLOBAL engine; sim.build_simulation owns it)
-    with pytest.raises(ValueError, match="constraint"):
-        _sharded_phold(8, 8, spmd="constraint")
-
-
 def test_no_pmap_in_executed_path(monkeypatch):
-    """Acceptance: sharded runs on this jax never route through
-    jax.pmap unless spmd='pmap' is requested."""
+    """Sharded runs never route through jax.pmap."""
     def _poisoned(*a, **k):
         raise AssertionError("jax.pmap reached from the default path")
 
@@ -108,33 +92,6 @@ def test_no_pmap_in_executed_path(monkeypatch):
     st = run(init(), jnp.int64(SECOND))
     assert int(st.now) == SECOND
     assert int(st.stats.n_executed.sum()) > 0
-
-
-def test_pmap_fallback_stays_green():
-    """--spmd pmap keeps the legacy 1-D path alive (soak comparison
-    until the shard_map path has TPU time): bit-identical to the
-    single-device run."""
-    n_shards, per = 4, 8
-    eng1, init1 = phold.build(n_shards * per, seed=3, capacity=32,
-                              msgs_per_host=4)
-    st1 = jax.jit(eng1.run)(init1(), jnp.int64(SECOND))
-
-    init, run, _ = _sharded_phold(per, n_shards, spmd="pmap")
-    stN = run(init(), jnp.int64(SECOND))
-    assert st1.hosts.n_received.tolist() == stN.hosts.n_received.tolist()
-    assert st1.src_seq.tolist() == stN.src_seq.tolist()
-    assert (st1.queues.time.sort(axis=1)
-            == stN.queues.time.sort(axis=1)).all()
-
-
-def test_pmap_multislice_error_names_remedy():
-    m2 = pmesh.make_mesh(8, dcn_slices=2)
-    axes = pmesh.hosts_axes(m2)
-    with pytest.raises(NotImplementedError) as ei:
-        _sharded_phold(4, 8, axis=axes, mesh=m2, spmd="pmap")
-    msg = str(ei.value)
-    assert pmesh.select_spmd("auto") in msg  # the selected remedy path
-    assert "spmd" in msg
 
 
 def test_2d_mesh_bit_identical_to_1d():

@@ -63,35 +63,12 @@ def test_tgen_test_config_step_compiles(one_chip):
     assert c.memory_analysis().argument_size_in_bytes > 0
 
 
-def test_pallas_merge_refused_by_mosaic(one_chip):
-    """The fused merge kernel does not compile for the chip; ROADMAP C2
-    decides whether to rewrite it or delete it. Until then the engine
-    refuses kernel="pallas" off the CPU with these same reasons."""
-    from shadow_tpu.core.merge_pallas import MOSAIC_REFUSAL, fused_merge
-
-    h, hc, w, nw = 256, 64, 8, 6
-
-    def spec(shape, dtype=jnp.int64):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    args = (spec((h, hc)), spec((h, hc)), spec((h, hc, nw)),
-            spec((h * w,)), spec((h * w,)), spec((h, w, nw)),
-            spec((h,), jnp.int32), spec((h,), jnp.int32))
-    with pytest.raises(Exception) as ei:
-        jax.jit(lambda *a: fused_merge(*a, interpret=False)).lower(
-            *args).compile()
-    msg = str(ei.value)
-    assert ("Only 2D gather is supported" in msg
-            or "64-bit types are not supported" in msg), msg[:2000]
-    assert "64-bit types" in MOSAIC_REFUSAL and "2-D" in MOSAIC_REFUSAL
-
-
 def test_row_merge_compiles_to_a_sort_without_merge_gathers(one_chip):
-    """The shared merge body compiles for the chip as a row sort: the
+    """The merge body compiles for the chip as a row sort: the
     only gathers left are the densify reads of the flat grouped keys
     (the merge-path body it replaced compiled to 23 gathers at this
     shape). w > hc and hc + w = 112, not a power of two."""
-    from shadow_tpu.core.merge_pallas import merge_body
+    from shadow_tpu.core.events import merge_body
 
     h, hc, w, nw = 256, 48, 64, 4
 

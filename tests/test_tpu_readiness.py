@@ -1,11 +1,10 @@
 """TPU-readiness auditor + roofline cost model (analysis/chips.py,
 costmodel.py, tpu_readiness.py).
 
-Three pinned regression injections (the acceptance contract): a
-padded-to-waste shape must move the tile report past its baseline, an
-extra gather inside the window while body must trip the placement
-check with its region path, and an oversized Pallas merge block must
-blow the VMEM fit. Plus the cost model's ground-truth anchor: under
+Two pinned regression injections (the acceptance contract): a
+padded-to-waste shape must move the tile report past its baseline, and
+an extra gather inside the window while body must trip the placement
+check with its region path. Plus the cost model's ground-truth anchor: under
 the CPU chip row its chained-vs-frontier prediction must agree in
 direction with BENCH_r07's measured wall times for tor and tgen.
 """
@@ -17,7 +16,7 @@ import pytest
 from shadow_tpu.analysis import costmodel as C
 from shadow_tpu.analysis import hlo_graph as G
 from shadow_tpu.analysis import tpu_readiness as T
-from shadow_tpu.analysis.chips import CHIPS, chip
+from shadow_tpu.analysis.chips import chip
 
 
 # ------------------------------------------------------------ tile math
@@ -176,44 +175,6 @@ def test_churn_census_hot_vs_total():
         and any("transpose" in s for s in v)
 
 
-# ------------------------------------------------------------- VMEM fit
-
-
-def test_merge_vmem_fits_production_shapes():
-    # the shapes the phold audit build actually traces (recorded via
-    # the merge_body wrapper) must fit every TPU generation
-    rep = T.merge_vmem_report(h=8, hc=32, w=32, m=224, nw=1)
-    for name in ("v5e", "v5p", "v6e"):
-        assert rep["per_chip"][name]["fits"], name
-        assert rep["per_chip"][name]["max_rows"] >= 8
-    assert "fits" not in rep["per_chip"]["cpu"]  # no VMEM tier
-
-
-def test_oversized_pallas_block_blows_vmem():
-    # scale the row-block until the double-buffered working set passes
-    # 16 MiB: the fit flag must flip and check_config must trip
-    small = T.merge_vmem_report(h=8, hc=32, w=32, m=224, nw=1)
-    big = T.merge_vmem_report(h=4096, hc=32, w=32, m=224, nw=1)
-    assert big["working_set_bytes"] > CHIPS["v5e"].vmem_bytes
-    assert not big["per_chip"]["v5e"]["fits"]
-    rep = {"tile": {"waste_pct": 0.0}, "churn": {}, "placement": {},
-           "vmem": big}
-    bl = {"tile": {"waste_pct": 0.0}, "churn": {}, "hot_ops": {},
-          "vmem": {"working_set_bytes": small["working_set_bytes"],
-                   "per_chip": {"v5e": {"fits": True}}}}
-    v = T.check_config("phold", rep, bl)
-    assert any("VMEM working set" in s for s in v)
-    assert any("no longer fits v5e" in s for s in v)
-
-
-def test_merge_report_picks_largest_call():
-    shapes = [dict(h=8, hc=32, w=32, m=224, nw=1),
-              dict(h=8, hc=64, w=32, m=288, nw=1)]
-    rep = T.merge_report(shapes)
-    assert rep["hc"] == 64 and rep["calls"] == 2
-    assert T.merge_report([]) is None
-
-
 # ----------------------------------------------------------- cost model
 
 
@@ -318,7 +279,6 @@ def test_save_baseline_roundtrip(tmp_path):
                       for k in T.CHURN_OPS},
             "placement": {k: {"count": 0, "hot": 0, "flagged": []}
                           for k in T.PLACEMENT_OPS},
-            "vmem": T.merge_vmem_report(8, 32, 32, 224, 1),
             "floors": {"cpu": 100.0},
         },
         "skipped_cfg": {"ok": True, "skipped": "no devices",
@@ -337,13 +297,10 @@ def test_save_baseline_roundtrip(tmp_path):
 
 def test_audit_config_real_phold():
     # one real lowering end-to-end: the phold engine's window loop
-    # parses, the merge shapes are recorded off the trace, and the
-    # committed baseline accepts the result
+    # parses and the committed baseline accepts the result
     rep = T.audit_config("phold")
     rep.pop("_module")
     assert rep["hosts"] == 8
-    assert rep["vmem"] is not None and rep["vmem"]["calls"] >= 1
-    assert rep["vmem"]["per_chip"]["v5e"]["fits"]
     assert rep["placement"]["scatter"]["hot"] == 0  # ROADMAP invariant
     bl = T.load_baseline()
     assert T.check_config("phold", rep, bl["configs"]["phold"]) == []
