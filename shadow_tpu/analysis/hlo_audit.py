@@ -179,23 +179,25 @@ CONTRACTS: dict[str, HloContract] = {
     # The SPMD lowering of the raw PHOLD window loop over an 8-device
     # mesh. Every count is structural (per traced site x per Events
     # leaf), none scale with hosts or events:
-    # - scatter 14: the exchange's [S, R] route-bucket build
-    #   (`.at[row, col].set(mode="drop")` over the 6 Events leaves)
-    #   plus the sent-mask update — per exchange ROUND, outside the
-    #   per-event path. The drain itself stays sort-based.
+    # - scatter 0: like the raw engine on one device, the sharded loop
+    #   is scatter-free. The exchange groups each push by destination
+    #   shard in one stable sort and cuts every round's [S, R] route
+    #   bucket as contiguous slices (`Engine._route_bucket`).
     # - all_to_all 12: one per Events leaf per traced exchange site
     #   (the bucketed cross-shard delivery).
-    # - all_reduce 10: the carried drain/exchange flags — computed in
-    #   loop BODIES; the companion test (test_spmd.py) asserts none
-    #   sits in a while predicate.
+    # - all_reduce 8: the carried drain flags — computed in loop
+    #   BODIES; the companion test (test_spmd.py) asserts none sits in
+    #   a while predicate — plus, per exchange site, the pmax of the
+    #   longest run that fixes the round count before the round loop
+    #   and the psum of the crossings.
     # - all_gather 2: the i64 window barrier (`Engine._gmin`), one per
     #   traced site — the TPU lowers a 64-bit all-reduce only as a sum.
     # A count above budget means a new collective or scatter entered
     # the sharded hot path; below budget, re-pin with a comment.
     "phold_sharded": HloContract(
         "phold_sharded",
-        {"scatter": 14, "select_and_scatter": 0,
-         "all_to_all": 12, "all_reduce": 10,
+        {"scatter": 0, "select_and_scatter": 0,
+         "all_to_all": 12, "all_reduce": 8,
          "collective_permute": 0, "all_gather": 2},
         custom_call_allow=(
             "Sharding", "SPMDFullToShardShape", "SPMDShardToFullShape",

@@ -56,7 +56,6 @@ from shadow_tpu.core.events import (
     N_ARGS,
     EventQueue,
     Events,
-    group_run_starts,
     pack_srcseq,
     queue_push_counted,
 )
@@ -656,6 +655,60 @@ class Engine:
             self.cfg.n_shards, self._xchg_r, self.cfg.n_args
         ), stats
 
+    def _route_sort(self, ev: Events, mask: jax.Array):
+        """Group a flat batch [M] by destination shard in one stable sort.
+
+        Every masked-in event whose destination lies on the mesh keys to
+        its shard; the rest, and R rows of padding appended so that a
+        round's slice of R rows never runs past the end
+        (`_route_bucket`), key to S. Every field rides the sort as an
+        operand, so nothing is gathered after it, and each destination's
+        run keeps the batch's order.
+
+        Returns (cols, start, count): `cols` the sorted fields, each
+        [M + R]: time, dst, src, seq, kind, then one column per arg;
+        `start`, `count` i32[S], where each destination's run begins and
+        how many events it holds.
+        """
+        s, r = self.cfg.n_shards, self._xchg_r
+        dshard = ev.dst // jnp.int32(self.cfg.n_hosts)
+        key = jnp.where(mask & (dshard >= 0) & (dshard < s), dshard, s)
+        fields = [ev.time, ev.dst, ev.src, ev.seq, ev.kind] + [
+            ev.args[:, i] for i in range(self.cfg.n_args)]
+        fills = [TIME_INVALID] + [0] * (len(fields) - 1)
+        key, *cols = jax.lax.sort(
+            [jnp.concatenate([x, jnp.full((r,), f, x.dtype)])
+             for x, f in zip([key] + fields, [s] + fills)],
+            num_keys=1, is_stable=True,
+        )
+        # S + 1 queries: one broadcast compare, not a binary-search loop
+        bounds = jnp.searchsorted(
+            key, jnp.arange(s + 1, dtype=key.dtype), side="left",
+            method="compare_all",
+        ).astype(jnp.int32)
+        return cols, bounds[:s], bounds[1:] - bounds[:s]
+
+    def _route_bucket(self, cols: list, start: jax.Array,
+                      count: jax.Array, k: jax.Array) -> Events:
+        """Round k's [S, R] bucket from `_route_sort`'s columns: row d
+        holds ranks [kR, (k+1)R) of destination d's run, read as one
+        contiguous slice per column; a slot past the run's end (so every
+        slot of a row whose count is 0) is empty: time TIME_INVALID,
+        every other field 0."""
+        s, r = self.cfg.n_shards, self._xchg_r
+        # a start past the batch only ever reads a fully masked slice;
+        # capping it at the padding keeps every slice unclamped
+        at = jnp.minimum(start + k * r, cols[0].shape[0] - r)
+        at = [at[d] for d in range(s)]
+        ok = (k * r + jnp.arange(r, dtype=jnp.int32))[None, :] < count[:, None]
+        time, dst, src, seq, kind, *args = (
+            jnp.where(ok, jnp.stack([jax.lax.dynamic_slice_in_dim(c, a, r)
+                                     for a in at]), fill)
+            for c, fill in zip(cols, [TIME_INVALID] + [0] * (len(cols) - 1))
+        )
+        return Events(time=time, dst=dst, src=src, seq=seq, kind=kind,
+                      args=jnp.stack(args, axis=-1))
+
     @phase("exchange")
     def _exchange_push(self, q: EventQueue, xchg, stats: Stats, ev: Events,
                        mask: jax.Array, host0):
@@ -663,71 +716,54 @@ class Engine:
         bucketed all_to_all.
 
         Hosts are block-partitioned over the mesh axis (gid // n_hosts is
-        the owning shard), so same-shard events push directly. Cross-shard
-        events are grouped by destination shard into a [S, R] bucket and
-        exchanged with `lax.all_to_all`; if any destination's load exceeds
-        the R bucket slots, the loop runs another round with the remainder
-        — lossless, and traffic scales with the cross-shard packet count
-        rather than total packets (the TPU-native replacement for the
-        reference's shared-memory scheduler_push across threads,
+        the owning shard). One stable sort groups the batch by
+        destination shard (`_route_sort`); this shard's own run pushes
+        directly. Round k sends ranks [kR, (k+1)R) of every other
+        destination's run as contiguous slices of the sorted columns,
+        one [S, R] bucket exchanged with `lax.all_to_all`
+        (`_route_bucket`): no scatter and no gather. The rounds repeat
+        until the longest run anywhere on the mesh has been sent, a
+        count one `pmax` takes before the loop: lossless, and traffic
+        scales with the cross-shard packet count rather than total
+        packets (the TPU-native replacement for the reference's
+        shared-memory scheduler_push across threads,
         scheduler.c:342-360; SURVEY.md §2.4).
 
         Each round's received bucket is NOT pushed in that round: it
         lands in `xchg` and is merged at the top of the NEXT round's
         body — and the final round's recv rides out in the returned
         ExchangeBuf to the next sweep or window (double buffering). The
-        loop predicate reads a carried flag; the psum deciding another
-        round runs in the body (see `_drain_flag`). Returns (q, xchg,
-        stats), stats counting the rounds, crossings and merges.
+        loop predicate reads a carried flag, computed in the body from
+        the count taken before the loop (see `_drain_flag`). Returns
+        (q, xchg, stats), stats counting the rounds, crossings and
+        merges.
         """
         if self.cfg.axis_name is None:
             q, stats = self._push(q, stats, ev, mask, host0)
             return q, xchg, stats
-        cfg = self.cfg
-        ax = cfg.axis_name
-        h, s = cfg.n_hosts, cfg.n_shards
-        my = jax.lax.axis_index(ax).astype(jnp.int32)
-        m = ev.time.shape[0]
+        ax = self.cfg.axis_name
         # engine-level static bucket width (see __init__): a quarter of
         # the widest uniform-traffic case — small enough that lightly-
         # coupled shards don't pay Θ(batch) ICI traffic every iteration,
         # large enough that uniform workloads rarely need a second round
         # (overflow just loops, lossless)
         r = self._xchg_r
-
-        dshard = ev.dst // jnp.int32(h)
-        in_range = (dshard >= 0) & (dshard < s)
+        my = jax.lax.axis_index(ax).astype(jnp.int32)
+        dshard = ev.dst // jnp.int32(self.cfg.n_hosts)
         is_local = mask & (dshard == my)
         q, stats = self._push(q, stats, ev, is_local, host0)
-        remaining = mask & in_range & ~is_local
-
-        pos = jnp.arange(m, dtype=jnp.int32)
-
-        def cond(carry):
-            return carry[0]
+        # the sort waits for the local push, so its [M] output is never
+        # live beside the push's temporaries; the batch dies at the sort
+        q, ev = jax.lax.optimization_barrier((q, ev))
+        # this shard's own events key past every run, with those that go
+        # nowhere: its row of every bucket stays empty
+        cols, start, count = self._route_sort(ev, mask & ~is_local)
+        longest = jax.lax.pmax(jnp.max(count), ax)
 
         def body(carry):
-            _, q, xchg, stats, rem, rounds = carry
+            _, k, q, xchg, stats = carry
             q, xchg, stats = self._xchg_deliver(q, xchg, stats, host0)
-            dkey = jnp.where(rem, dshard, s)
-            order = jnp.argsort(dkey, stable=True)
-            sd = dkey[order]
-            rank = pos - group_run_starts(sd)
-            sel = (sd < s) & (rank < r)
-
-            brow = jnp.where(sel, sd, s)
-            bcol = jnp.minimum(rank, r - 1)
-            evo = ev.at(order)
-            bucket = Events(
-                time=jnp.full((s, r), TIME_INVALID, jnp.int64)
-                .at[brow, bcol].set(evo.time, mode="drop"),
-                dst=jnp.zeros((s, r), jnp.int32).at[brow, bcol].set(evo.dst, mode="drop"),
-                src=jnp.zeros((s, r), jnp.int32).at[brow, bcol].set(evo.src, mode="drop"),
-                seq=jnp.zeros((s, r), jnp.int32).at[brow, bcol].set(evo.seq, mode="drop"),
-                kind=jnp.zeros((s, r), jnp.int32).at[brow, bcol].set(evo.kind, mode="drop"),
-                args=jnp.zeros((s, r, cfg.n_args), jnp.int32)
-                .at[brow, bcol].set(evo.args, mode="drop"),
-            )
+            bucket = self._route_bucket(cols, start, count, k)
             recv = jax.tree.map(
                 lambda x: jax.lax.all_to_all(x, ax, split_axis=0, concat_axis=0),
                 bucket,
@@ -738,19 +774,14 @@ class Engine:
             xchg = ExchangeBuf(
                 bucket=recv, sent_min=jnp.min(bucket.time).reshape((1,))
             )
-            sent = jnp.zeros((m,), bool).at[order].set(sel)
-            rem = rem & ~sent
-            return self._gany(jnp.any(rem)), q, xchg, stats, rem, rounds + 1
+            return (k + 1) * r < longest, k + 1, q, xchg, stats
 
         # global count (each shard only sees its own outbound packets;
         # the replicated stats scalar needs the psum'd total)
-        n_cross = jax.lax.psum(
-            jnp.sum(remaining, dtype=jnp.int64), ax
-        )
-        _, q, xchg, stats, _, rounds = jax.lax.while_loop(
-            cond, body,
-            (self._gany(jnp.any(remaining)), q, xchg, stats, remaining,
-             jnp.zeros((), jnp.int64)),
+        n_cross = jax.lax.psum(jnp.sum(count, dtype=jnp.int64), ax)
+        _, rounds, q, xchg, stats = jax.lax.while_loop(
+            lambda carry: carry[0], body,
+            (longest > 0, jnp.zeros((), jnp.int32), q, xchg, stats),
         )
         return q, xchg, dataclasses.replace(
             stats,

@@ -218,19 +218,6 @@ class EventQueue:
         return jnp.min(self.time, axis=1)
 
 
-def group_run_starts(sorted_group_ids: jax.Array) -> jax.Array:
-    """Index where each position's group run begins, for a group-sorted
-    1-D array (associative max-scan over run boundaries). Subtracting it
-    from the position index yields each element's rank within its group.
-    """
-    n = sorted_group_ids.shape[0]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    boundary = jnp.concatenate(
-        [jnp.ones((1,), bool), sorted_group_ids[1:] != sorted_group_ids[:-1]]
-    )
-    return jax.lax.associative_scan(jnp.maximum, jnp.where(boundary, pos, 0))
-
-
 def pack_srcseq(src: jax.Array, seq: jax.Array) -> jax.Array:
     """Pack (src, seq) into one i64 preserving lexicographic order.
 

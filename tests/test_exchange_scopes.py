@@ -4,6 +4,8 @@ devices: the placements a device profile by phase relies on to split
 the exchange, the delivery and the barrier apart.
 
 - every `all-to-all` counts under `exchange`;
+- the exchange's route sort and the slices that cut each round's
+  bucket count under `exchange` itself, and no scatter does;
 - every `all-gather` (the barrier's `_gmin`) counts under `barrier`;
 - the delivery of a received bucket (`Engine._xchg_deliver`) runs its
   queue push as `merge` ops inside `deliver`, both at the top of a
@@ -53,3 +55,18 @@ def test_delivery_push_is_merge_inside_deliver(ops, inside_exchange):
     merges = [scopes for _, scopes in ops
               if scopes[-1:] == ["merge"] and "deliver" in scopes]
     assert any(("exchange" in s) == inside_exchange for s in merges)
+
+
+@pytest.mark.parametrize("opcode", ["sort", "dynamic-slice"])
+def test_route_build_counts_under_exchange(ops, opcode):
+    """`Engine._route_sort`'s one sort per push and `_route_bucket`'s
+    per-round slices sit directly under `exchange` (not under the
+    `merge` or `deliver` nested in it)."""
+    assert any(op == opcode and scopes[-1:] == ["exchange"]
+               for op, scopes in ops)
+
+
+def test_no_scatter_under_exchange(ops):
+    found = [scopes for op, scopes in ops
+             if op == "scatter" and "exchange" in scopes]
+    assert not found, found

@@ -189,8 +189,9 @@ def test_roundtrip_sharded_phold_gspmd():
     assert targets <= allow  # ...and any custom_call on the allowlist
     hist = m.histogram()
     # the sharded contract, structurally: counts come from the
-    # reachable graph (shmap_body and its callees), not regex text
-    assert hist["all_to_all"] == 12 and hist["scatter"] == 14
+    # reachable graph (shmap_body and its callees), not regex text;
+    # the exchange cuts its route buckets as slices, with no scatter
+    assert hist["all_to_all"] == 12 and hist.get("scatter", 0) == 0
 
 
 def test_roundtrip_harvest_program():
